@@ -42,8 +42,6 @@ def run(quick: bool = False) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    jax.config.update("jax_platform_name", "cpu")
-
     from repro.common.tree import TaskVectorSpace, tree_add
     from repro.configs.base import SHAPES, load_arch
     from repro.core.client import ClientUpload

@@ -16,10 +16,6 @@ from benchmarks.common import save_detail
 
 
 def run(quick: bool = False) -> dict:
-    import jax
-
-    jax.config.update("jax_platform_name", "cpu")
-
     from repro.data.dirichlet import FedSplit
     from repro.data.synthetic import make_constellation
     from repro.fed.simulator import FedConfig, FedSimulator

@@ -94,6 +94,9 @@ def main() -> None:
             f"--xla_force_host_platform_device_count={args.devices} "
             + os.environ.get("XLA_FLAGS", ""))
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     all_benches = (BENCHES + (["bench_zoo"] if args.zoo else [])
                    + (["bench_serving"] if args.serving else []))
     benches = [b for b in all_benches

@@ -23,6 +23,7 @@ from repro.configs.base import SHAPES, load_arch
 from repro.core.client import ClientUpload
 from repro.core.server import MaTUServer, MaTUServerConfig
 from repro.core.unify import modulate, unify_with_modulators
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import adamw
 from repro.train.trainer import make_train_step
 
@@ -54,6 +55,7 @@ def main():
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--seq", type=int, default=48)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = load_arch("qwen2-0.5b").reduced()
     model = cfg.build(SHAPES["train_4k"])

@@ -15,9 +15,11 @@ from repro.data.synthetic import make_constellation
 from repro.fed.simulator import FedConfig, FedSimulator, individual_baseline
 from repro.fed.strategies import FedAvgStrategy, MaTUStrategy
 from repro.fed.testbed import MLPBackbone
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     n_tasks = 6
     con = make_constellation(n_tasks=n_tasks, n_groups=3, feat_dim=32,
                              n_classes=8, conflict_pairs=[(0, 1)], seed=0)

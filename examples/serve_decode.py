@@ -26,6 +26,7 @@ from repro.configs.base import SHAPES, load_arch
 from repro.core.client import ClientUpload
 from repro.core.server import MaTUServer, MaTUServerConfig
 from repro.core.unify import unify_with_modulators
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import adamw
 from repro.serve import GenerationConfig, ModulatorStore, MultiTenantDecoder
 from repro.train.trainer import make_train_step
@@ -75,6 +76,7 @@ def main():
     ap.add_argument("--tasks", type=int, default=4)
     ap.add_argument("--local-steps", type=int, default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     local_steps = args.local_steps or (2 if args.quick else 6)
     reps = 2 if args.quick else 8
 

@@ -300,7 +300,6 @@ from typing import (Callable, Dict, Iterable, List, NamedTuple, Optional,
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.aggregation import EPS_DEFAULT, KAPPA_DEFAULT, RHO_DEFAULT
@@ -722,8 +721,8 @@ def _round_impl(unified, slot_masks, slot_lams, slot_sizes, slot_valid,
     if slot_weights is not None:
         in_specs += (rep,)
         operands += (slot_weights,)
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)(*operands)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*operands)
 
 
 def _assemble_downlinks(client_ids: List[int], task_ids: List[List[int]],
@@ -820,8 +819,8 @@ def _merge_chunk_impl(unified, slot_masks, slot_lams, slot_sizes, slot_valid,
     if slot_weights is not None:
         in_specs += (rep,)
         operands += (slot_weights,)
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=(s2, s2), check_rep=False)(*operands)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=(s2, s2), check_vma=False)(*operands)
 
 
 def _finish_impl(a_acc, tau_acc, nt_acc, *, cfg: EngineConfig, mode: str,
@@ -854,9 +853,9 @@ def _finish_impl(a_acc, tau_acc, nt_acc, *, cfg: EngineConfig, mode: str,
         return ops.matu_finish(a, ta, nt, d=d_local, **kw)
 
     # (tv, τ̂, α_num | m̂, n_t, sim, num_t)
-    return shard_map(body, mesh=mesh, in_specs=(s2, s2, rep),
-                     out_specs=(s2, s2, s2, rep, rep, rep),
-                     check_rep=False)(a_acc, tau_acc, nt_acc)
+    return jax.shard_map(body, mesh=mesh, in_specs=(s2, s2, rep),
+                         out_specs=(s2, s2, s2, rep, rep, rep),
+                         check_vma=False)(a_acc, tau_acc, nt_acc)
 
 
 def _downlink_chunk_impl(task_vectors, slot_valid, slot_tasks, num_t, *,
@@ -896,9 +895,9 @@ def _downlink_chunk_impl(task_vectors, slot_valid, slot_tasks, num_t, *,
 
     in_specs = (P(None, ax), P(rx, None), P(rx, None), rep)
     out_specs = (P(rx, ax), P(rx, None, ax), P(rx, None))
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)(
-                         task_vectors, slot_valid, slot_tasks, num_t)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(
+                             task_vectors, slot_valid, slot_tasks, num_t)
 
 
 class RoundEngine:
@@ -1344,8 +1343,8 @@ def _client_unify_sharded_jit(mode: str, packed: bool, mesh: Mesh,
         num, den = jax.lax.psum((num, den), axes)
         return uni, masks, num / jnp.maximum(den, eps)
 
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=(s3, rep),
-                             out_specs=(s2, s3, rep), check_rep=False))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(s3, rep),
+                                 out_specs=(s2, s3, rep), check_vma=False))
 
 
 def batched_client_unify(task_vectors: jax.Array, valid: jax.Array, *,

@@ -114,20 +114,57 @@ def unpack_bits_np(words: np.ndarray, d: int) -> np.ndarray:
 def unpack_tile(words: jax.Array, dtype=jnp.float32) -> jax.Array:
     """(R, W) uint32 -> (R, W*32) tile unpack for Pallas kernel bodies:
     uses ``broadcasted_iota`` (TPU needs ≥2-D iota) and no tail slicing
-    — kernel tiles are always word-aligned."""
+    — kernel tiles are always word-aligned.  Bits become values through
+    a select: Mosaic has no uint32 -> float cast."""
     r, w = words.shape
     iota = jax.lax.broadcasted_iota(jnp.uint32, (1, 1, WORD_BITS), 2)
     bits = (words[:, :, None] >> iota) & jnp.uint32(1)
-    return bits.reshape(r, w * WORD_BITS).astype(dtype)
+    return jnp.where(bits.reshape(r, w * WORD_BITS) != 0,
+                     jnp.ones((), dtype), jnp.zeros((), dtype))
 
 
 def pack_tile(bits: jax.Array) -> jax.Array:
-    """(R, D) bool/{0,1} -> (R, D/32) uint32 tile pack for Pallas kernel
-    bodies (D must be a multiple of 32)."""
+    """(R, D) bool/{0,1} -> (R, D/128, 4) uint32 tile pack for Pallas
+    kernel bodies (D must be a multiple of 128).
+
+    Row r's D/32 words come out in groups of four: word ``4·i + q`` is
+    at ``[r, i, q]``, so a row-major reshape to (R, D/32) is the wire
+    row.  Mosaic cannot split the lane axis into 32-bit groups, so each
+    128-lane row is reduced one 32-lane quarter at a time — an int32 sum
+    of distinct powers of two, which is their bitwise OR (bit 31 wraps
+    to the sign bit and is bitcast back)."""
     r, dd = bits.shape
-    iota = jax.lax.broadcasted_iota(jnp.uint32, (1, 1, WORD_BITS), 2)
-    b = bits.reshape(r, dd // WORD_BITS, WORD_BITS).astype(jnp.uint32)
-    return jnp.sum(b << iota, axis=-1, dtype=jnp.uint32)
+    rows = dd // 128
+    lane = jax.lax.broadcasted_iota(jnp.int32, (r * rows, 128), 1)
+    shifted = jnp.where(bits.reshape(r * rows, 128) != 0,
+                        jnp.left_shift(jnp.int32(1), lane % WORD_BITS), 0)
+    words = jnp.concatenate(
+        [jnp.sum(shifted[:, q * WORD_BITS:(q + 1) * WORD_BITS], axis=-1,
+                 keepdims=True, dtype=jnp.int32) for q in range(4)], axis=-1)
+    return jax.lax.bitcast_convert_type(words, jnp.uint32).reshape(r, rows, 4)
+
+
+def row_words(words: jax.Array, rows: int, cols: int) -> jax.Array:
+    """(..., ceil(rows·cols/32)) packed row-major (rows, cols) mask ->
+    (..., rows, ceil(cols/32)) with every matrix row re-aligned to start
+    at bit 0 of its own word (zero tail bits): :func:`slice_bits`
+    applied to all rows at once.  Kernels unpack each row's words into
+    its own lanes, which needs no in-kernel reshape across rows."""
+    wc = packed_width(cols)
+    if cols % WORD_BITS == 0:       # rows already start on word boundaries
+        return words.reshape(words.shape[:-1] + (rows, wc))
+    start = np.arange(rows) * cols
+    sh = (start % WORD_BITS).astype(np.uint32)[:, None]          # (rows, 1)
+    idx = (start // WORD_BITS)[:, None] + np.arange(wc)[None, :]  # (rows, wc)
+    need = int(idx.max()) + 2 - words.shape[-1]
+    if need > 0:      # zero-pad so the shifted neighbour read is safe
+        words = jnp.pad(words, [(0, 0)] * (words.ndim - 1) + [(0, need)])
+    lo, hi = words[..., idx], words[..., idx + 1]
+    out = (lo >> sh) | jnp.where(sh > 0, hi << ((WORD_BITS - sh) % WORD_BITS),
+                                 jnp.uint32(0))
+    keep = np.full(wc, 0xFFFFFFFF, np.uint32)
+    keep[-1] = (1 << (cols % WORD_BITS)) - 1
+    return out & keep
 
 
 def scatter_bits_np(positions: np.ndarray, n_bytes: int) -> np.ndarray:

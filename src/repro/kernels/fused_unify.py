@@ -35,30 +35,93 @@ BLOCK_D = 2048
 BLOCK_D_PACKED = 4096       # 128 uint32 words per tile (one lane tile)
 
 
-def _fused_unify_kernel(tv_ref, valid_ref, uni_ref, mask_ref, num_ref, den_ref):
+def _unify_tile(tv_ref, valid_ref, num_ref, den_ref):
+    """Shared tile body: returns (τ (1, BD), mask (K, BD) fp32 {0,1})
+    and folds this tile's λ partial sums into the (K, 1) num/den
+    blocks.  Every operand is 2-D (K, ·) or (·, 1), the layouts Mosaic
+    tiles without moving data between lanes and sublanes."""
     x = tv_ref[0].astype(jnp.float32)               # (K, BD)
-    v = valid_ref[0].astype(jnp.float32)            # (K,)
-    xm = x * v[:, None]
-    sigma = jnp.sign(jnp.sum(xm, axis=0))
-    aligned = (xm * sigma[None, :]) > 0.0
-    mu = jnp.max(jnp.where(aligned, jnp.abs(xm), 0.0), axis=0)
+    v = valid_ref[0]                                # (K, 1) fp32 {0,1}
+    xm = x * v
+    sigma = jnp.sign(jnp.sum(xm, axis=0, keepdims=True))
+    aligned = (xm * sigma) > 0.0
+    mu = jnp.max(jnp.where(aligned, jnp.abs(xm), 0.0), axis=0, keepdims=True)
     tau = sigma * mu
-    uni_ref[0] = tau.astype(uni_ref.dtype)
-    mask = ((x * tau[None, :]) > 0.0).astype(jnp.float32) * v[:, None]
-    mask_ref[0] = mask.astype(mask_ref.dtype)
+    mask = ((x * tau) > 0.0).astype(jnp.float32) * v
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
         num_ref[...] = jnp.zeros_like(num_ref)
         den_ref[...] = jnp.zeros_like(den_ref)
 
-    num_ref[0] += jnp.sum(jnp.abs(xm), axis=1)
-    den_ref[0] += jnp.sum(mask * jnp.abs(tau)[None, :], axis=1)
+    num_ref[0] += jnp.sum(jnp.abs(xm), axis=1, keepdims=True)
+    den_ref[0] += jnp.sum(mask * jnp.abs(tau), axis=1, keepdims=True)
+    return tau, mask
+
+
+def _fused_unify_kernel(tv_ref, valid_ref, uni_ref, mask_ref, num_ref, den_ref):
+    tau, mask = _unify_tile(tv_ref, valid_ref, num_ref, den_ref)
+    uni_ref[0] = tau.astype(uni_ref.dtype)
+    mask_ref[0] = mask.astype(mask_ref.dtype)
+
+
+def _fused_unify_packed_kernel(tv_ref, valid_ref, uni_ref, mask_ref,
+                               num_ref, den_ref):
+    # mask bits decided on the fp32 tau BEFORE the bf16 rounding of the
+    # emitted unified vector — bit-identical to the bool/fp32 kernel
+    tau, mask = _unify_tile(tv_ref, valid_ref, num_ref, den_ref)
+    uni_ref[0] = tau.astype(uni_ref.dtype)
+    mask_ref[0] = bitpack.pack_tile(mask)
+
+
+def _fused_unify_call(task_vectors, valid, *, block_d, interpret, packed):
+    """Grid (B, d/BD) launch shared by both variants.  Per-client rows
+    carry a unit axis — unified (B, 1, dp), valid/num/den (B, K, 1) — and
+    packed words are emitted as (B, K, dp/128, 4) (see
+    ``bitpack.pack_tile``), so the last two block dims always equal the
+    array's or tile (8, 128).  Returns the padded-d outputs."""
+    b, k, d = task_vectors.shape
+    pad = (-d) % block_d
+    if pad:
+        task_vectors = jnp.pad(task_vectors, ((0, 0), (0, 0), (0, pad)))
+    dp = d + pad
+    if packed:
+        kernel, uni_dtype = _fused_unify_packed_kernel, jnp.bfloat16
+        mask_spec = pl.BlockSpec((1, k, block_d // 128, 4),
+                                 lambda i, j: (i, 0, j, 0))
+        mask_out = jax.ShapeDtypeStruct((b, k, dp // 128, 4), jnp.uint32)
+    else:
+        kernel, uni_dtype = _fused_unify_kernel, jnp.float32
+        mask_spec = pl.BlockSpec((1, k, block_d), lambda i, j: (i, 0, j))
+        mask_out = jax.ShapeDtypeStruct((b, k, dp), jnp.float32)
+    scalar_spec = pl.BlockSpec((1, k, 1), lambda i, j: (i, 0, 0))
+    unified, masks, num, den = pl.pallas_call(
+        kernel,
+        grid=(b, dp // block_d),
+        in_specs=[
+            pl.BlockSpec((1, k, block_d), lambda i, j: (i, 0, j)),
+            scalar_spec,
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, block_d), lambda i, j: (i, 0, j)),
+            mask_spec,
+            scalar_spec,
+            scalar_spec,
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, 1, dp), uni_dtype),
+            mask_out,
+            jax.ShapeDtypeStruct((b, k, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, k, 1), jnp.float32),
+        ],
+        interpret=interpret,
+    )(task_vectors, valid.astype(jnp.float32).reshape(b, k, 1))
+    return unified[:, 0], masks, num[:, :, 0], den[:, :, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def fused_unify_pallas(task_vectors: jax.Array, valid: jax.Array, *,
-                       block_d: int = BLOCK_D, interpret: bool = True):
+                       block_d: int = BLOCK_D, interpret: bool):
     """task_vectors (B, K, d); valid (B, K) bool/{0,1}.
 
     Returns (unified (B, d), masks (B, K, d) fp32 {0,1}, num (B, K),
@@ -67,63 +130,17 @@ def fused_unify_pallas(task_vectors: jax.Array, valid: jax.Array, *,
     Zero-padding d is safe: padded lanes contribute nothing to num/den
     and are sliced off the streamed outputs.
     """
-    b, k, d = task_vectors.shape
-    pad = (-d) % block_d
-    if pad:
-        task_vectors = jnp.pad(task_vectors, ((0, 0), (0, 0), (0, pad)))
-    dp = d + pad
-    unified, masks, num, den = pl.pallas_call(
-        _fused_unify_kernel,
-        grid=(b, dp // block_d),
-        in_specs=[
-            pl.BlockSpec((1, k, block_d), lambda i, j: (i, 0, j)),
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_d), lambda i, j: (i, j)),
-            pl.BlockSpec((1, k, block_d), lambda i, j: (i, 0, j)),
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, dp), jnp.float32),
-            jax.ShapeDtypeStruct((b, k, dp), jnp.float32),
-            jax.ShapeDtypeStruct((b, k), jnp.float32),
-            jax.ShapeDtypeStruct((b, k), jnp.float32),
-        ],
-        interpret=interpret,
-    )(task_vectors, valid.astype(jnp.float32))
+    d = task_vectors.shape[-1]
+    unified, masks, num, den = _fused_unify_call(
+        task_vectors, valid, block_d=block_d, interpret=interpret,
+        packed=False)
     return unified[:, :d], masks[:, :, :d], num, den
-
-
-def _fused_unify_packed_kernel(tv_ref, valid_ref, uni_ref, mask_ref,
-                               num_ref, den_ref):
-    x = tv_ref[0].astype(jnp.float32)               # (K, BD)
-    v = valid_ref[0].astype(jnp.float32)            # (K,)
-    xm = x * v[:, None]
-    sigma = jnp.sign(jnp.sum(xm, axis=0))
-    aligned = (xm * sigma[None, :]) > 0.0
-    mu = jnp.max(jnp.where(aligned, jnp.abs(xm), 0.0), axis=0)
-    tau = sigma * mu
-    # mask bits decided on the fp32 tau BEFORE the bf16 rounding of the
-    # emitted unified vector — bit-identical to the bool/fp32 kernel
-    uni_ref[0] = tau.astype(uni_ref.dtype)
-    mask = ((x * tau[None, :]) > 0.0).astype(jnp.float32) * v[:, None]
-    mask_ref[0] = bitpack.pack_tile(mask)
-
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        num_ref[...] = jnp.zeros_like(num_ref)
-        den_ref[...] = jnp.zeros_like(den_ref)
-
-    num_ref[0] += jnp.sum(jnp.abs(xm), axis=1)
-    den_ref[0] += jnp.sum(mask * jnp.abs(tau)[None, :], axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def fused_unify_packed_pallas(task_vectors: jax.Array, valid: jax.Array, *,
                               block_d: int = BLOCK_D_PACKED,
-                              interpret: bool = True):
+                              interpret: bool):
     """Wire-format variant of :func:`fused_unify_pallas`: consumes bf16
     (or fp32) slot stacks and emits the wire tensors directly — bf16
     unified vectors and bit-packed uint32 mask words, packed 32 lanes
@@ -139,32 +156,8 @@ def fused_unify_packed_pallas(task_vectors: jax.Array, valid: jax.Array, *,
     accumulation tolerance, not bitwise, for d > 2048.
     """
     b, k, d = task_vectors.shape
-    pad = (-d) % block_d
-    if pad:
-        task_vectors = jnp.pad(task_vectors, ((0, 0), (0, 0), (0, pad)))
-    dp = d + pad
-    bw = block_d // 32
-    unified, mask_words, num, den = pl.pallas_call(
-        _fused_unify_packed_kernel,
-        grid=(b, dp // block_d),
-        in_specs=[
-            pl.BlockSpec((1, k, block_d), lambda i, j: (i, 0, j)),
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_d), lambda i, j: (i, j)),
-            pl.BlockSpec((1, k, bw), lambda i, j: (i, 0, j)),
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, dp), jnp.bfloat16),
-            jax.ShapeDtypeStruct((b, k, dp // 32), jnp.uint32),
-            jax.ShapeDtypeStruct((b, k), jnp.float32),
-            jax.ShapeDtypeStruct((b, k), jnp.float32),
-        ],
-        interpret=interpret,
-    )(task_vectors, valid.astype(jnp.float32))
-    return (unified[:, :d], mask_words[:, :, :bitpack.packed_width(d)],
-            num, den)
-
+    unified, words, num, den = _fused_unify_call(
+        task_vectors, valid, block_d=block_d, interpret=interpret,
+        packed=True)
+    words = words.reshape(b, k, -1)[:, :, :bitpack.packed_width(d)]
+    return unified[:, :d], words, num, den

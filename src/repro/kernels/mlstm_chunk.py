@@ -26,11 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; accept
-# either spelling (same version-tolerance pattern as launch/mesh._make_mesh).
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
 
 def _mlstm_chunk_kernel(q_ref, k_ref, v_ref, i_ref, f_ref, h_ref,
                         c_scr, n_scr, m_scr, *, chunk: int):
@@ -85,7 +80,7 @@ def _mlstm_chunk_kernel(q_ref, k_ref, v_ref, i_ref, f_ref, h_ref,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def mlstm_chunkwise_pallas(q, k, v, i_pre, f_pre, *, chunk: int = 64,
-                           interpret: bool = True):
+                           interpret: bool):
     """q,k (BH, S, Dk); v (BH, S, Dv); i_pre/f_pre (BH, S) -> h (BH, S, Dv).
 
     Zero initial state (block-local form used inside the LM); S padded
@@ -120,7 +115,7 @@ def mlstm_chunkwise_pallas(q, k, v, i_pre, f_pre, *, chunk: int = 64,
             pltpu.VMEM((dk,), jnp.float32),      # n carry
             pltpu.VMEM((1,), jnp.float32),       # m carry
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, i_pre, f_pre)

@@ -37,7 +37,8 @@ from repro.kernels.fused_unify import (fused_unify_packed_pallas,
 from repro.kernels.masked_agg import (masked_agg_batched_packed_pallas,
                                       masked_agg_batched_pallas,
                                       masked_agg_pallas)
-from repro.kernels.modulated_matmul import modulated_matmul_pallas
+from repro.kernels.modulated_matmul import (modulated_matmul_pallas,
+                                            routed_matmul_pallas)
 from repro.kernels.sign_sim import sign_sim_packed_pallas, sign_sim_pallas
 from repro.kernels.unify import unify_pallas
 
@@ -232,9 +233,10 @@ def modulated_matmul(x: jax.Array, base: jax.Array, tau: jax.Array,
     kept bit-packed until VMEM (fused word-unpack + λ-scale + matmul —
     no per-request effective weight in HBM).
 
-    x (B, S, K); base/tau (K, N) fp32; words (B, ceil(K·N/32)) uint32
-    row-major (K, N) mask bits in the LSB-first wire layout; lam (B,)
-    fp32.  Returns (B, S, N) fp32.  ``K · N`` must be word-aligned
+    x (B, S, K); base/tau (K, N) in the adapter leaf dtype; words
+    (B, ceil(K·N/32)) uint32 row-major (K, N) mask bits in the LSB-first
+    wire layout; lam (B,) fp32.  Returns (B, S, N) in
+    ``result_type(x, base)``.  ``K · N`` must be word-aligned
     (% 32 == 0) — the serve router only routes qualifying leaves here.
     The "ref" dispatch is the unpack-then-matmul oracle; all modes are
     bit-identical (see tests/test_serve_multitenant.py).
@@ -248,6 +250,20 @@ def modulated_matmul(x: jax.Array, base: jax.Array, tau: jax.Array,
         return ref.modulated_matmul_ref(x, base, tau, words, lam)
     return modulated_matmul_pallas(x, base, tau, words, lam,
                                    interpret=(mode == "pallas_interpret"))
+
+
+def routed_matmul(x: jax.Array, w: jax.Array, *,
+                  mode: Optional[str] = None) -> jax.Array:
+    """Serving: per-request LoRA matmul over materialised weights,
+    ``y_b = x_b @ w_b`` — the dense-routed form of
+    :func:`modulated_matmul`.  x (B, S, K); w (B, K, N).  The kernel
+    modes contract exactly as the fused kernel does, so the two routed
+    forms agree bitwise on the chip; "ref" is the batched einsum.
+    """
+    mode = _norm(mode)
+    if mode == "ref":
+        return jnp.einsum("bsk,bkn->bsn", x, w)
+    return routed_matmul_pallas(x, w, interpret=(mode == "pallas_interpret"))
 
 
 def _slot_scalars_to_dense(slot_lams, slot_sizes, slot_valid, slot_tasks,

@@ -1140,26 +1140,40 @@ def matu_downlink_chunk_ref(task_vectors: jax.Array, slot_valid: jax.Array,
 # ---------------------------------------------------------------------------
 
 
+def modulated_weight(base: jax.Array, tau: jax.Array, bits: jax.Array,
+                     lam: jax.Array) -> jax.Array:
+    """Effective adapter leaf ``base + lam * m * tau``, rounded the way
+    the materialised adapter ``tree_add(lora0, unflatten(modulate(...)))``
+    rounds it: the delta is formed in fp32 and cast to the leaf dtype
+    (``unflatten``), then added in fp32 and cast back (``tree_add`` —
+    XLA adds bf16 through fp32).  ``(lam * bits) * tau`` is bitwise
+    ``lam * where(m, tau, 0)`` for bits in {0, 1}.  The single
+    definition shared by the fused kernel and this module's oracle."""
+    delta = (lam * bits * tau.astype(jnp.float32)).astype(base.dtype)
+    return (base.astype(jnp.float32)
+            + delta.astype(jnp.float32)).astype(base.dtype)
+
+
 def modulated_matmul_ref(x: jax.Array, base: jax.Array, tau: jax.Array,
                          words: jax.Array, lam: jax.Array) -> jax.Array:
     """Per-request modulated LoRA matmul, the unpack-then-matmul oracle.
 
-    x (B, ..., K); base/tau (K, N) fp32 (the base adapter leaf and the
-    unified-vector slice reshaped to the leaf); words (B, W) uint32
-    bit-packed modulator bits of the leaf, row-major over (K, N); lam
-    (B,) fp32 per-request scalers.  Returns (B, ..., N):
+    x (B, ..., K); base/tau (K, N) in the adapter leaf dtype (the base
+    adapter leaf and the unified-vector slice reshaped to the leaf);
+    words (B, W) uint32 bit-packed modulator bits of the leaf, row-major
+    over (K, N); lam (B,) fp32 per-request scalers.  Returns
+    (B, ..., N) in ``result_type(x, base)``:
 
         y_b = x_b @ (base + lam_b * m_b * tau)
 
     The effective weight is materialised per request here (the extra
-    HBM pass the fused kernel removes); elementwise order matches
-    ``tree_add(lora0, unflatten(modulate(...)))`` exactly —
-    ``(lam * bits) * tau`` is bitwise ``lam * where(m, tau, 0)`` for
-    bits in {0, 1} — so serving paths built from either are
-    bit-identical.
+    HBM pass the fused kernel removes), rounded as
+    :func:`modulated_weight` says — so serving paths built from either
+    form compute the same adapter and the same contraction.
     """
     b = x.shape[0]
     k, n = base.shape
     bits = bitpack.unpack_bits(words, k * n, jnp.float32).reshape(b, k, n)
-    w_eff = base[None] + lam[:, None, None] * bits * tau[None]
-    return jnp.einsum("b...k,bkn->b...n", x.astype(jnp.float32), w_eff)
+    w_eff = modulated_weight(base[None], tau[None], bits,
+                             lam[:, None, None])
+    return jnp.einsum("b...k,bkn->b...n", x, w_eff)

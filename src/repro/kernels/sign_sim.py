@@ -35,7 +35,7 @@ def _sign_sim_kernel(x_ref, acc_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def sign_sim_pallas(tau_hats: jax.Array, *, block_d: int = BLOCK_D,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool) -> jax.Array:
     """(T, d) -> (T, T) similarity in [0, 1]. Zero-padding d is safe:
     sgn(0)·sgn(0) = 0 contributes nothing."""
     t, d = tau_hats.shape
@@ -69,7 +69,7 @@ def _sign_sim_packed_kernel(pos_ref, nz_ref, acc_ref):
 @functools.partial(jax.jit, static_argnames=("block_w", "interpret"))
 def sign_sim_packed_pallas(pos: jax.Array, nz: jax.Array, *,
                            block_w: int = BLOCK_W,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool) -> jax.Array:
     """Eq. 5 sign dots from packed sign bit-planes (the wire-format
     form of :func:`sign_sim_pallas`): ``pos``/``nz`` are (T, w) uint32
     planes with bit j set iff τ̂_j > 0 / τ̂_j ≠ 0 (see

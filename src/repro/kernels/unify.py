@@ -35,7 +35,7 @@ def _unify_kernel(tv_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def unify_pallas(task_vectors: jax.Array, *, block_d: int = BLOCK_D,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: bool) -> jax.Array:
     """(K, d) -> (d,). Pads d to a lane multiple internally."""
     k, d = task_vectors.shape
     pad = (-d) % block_d

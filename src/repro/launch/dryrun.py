@@ -71,18 +71,6 @@ def _shape_bytes(sig: str) -> int:
     return total
 
 
-def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """Normalize ``compiled.cost_analysis()`` across jax versions.
-
-    Older jax returns one dict; newer jax returns a list with one entry
-    per module (possibly empty).  Always hand callers a plain dict so
-    ``cost.get("flops")`` works everywhere."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost) if cost else {}
-
-
 def collective_bytes(hlo_text: str) -> Dict[str, int]:
     """Sum output-shape bytes of every collective op, per kind.
 
@@ -192,7 +180,7 @@ def run_combo(arch: str, shape_name: str, mesh, *, verbose: bool = True,
             t_compile = time.time() - t0 - t_lower
 
         mem = compiled.memory_analysis()
-        cost = cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis()
         coll = collective_bytes(compiled.as_text())
 
     # memory_analysis numbers are PER DEVICE (verified empirically);
@@ -260,7 +248,7 @@ def run_matu_round(mesh, *, n_clients: int = 30, n_tasks: int = 30,
             lowered = fn.lower(unified, masks, lams, alloc, sizes)
             compiled = lowered.compile()
         mem = compiled.memory_analysis()
-        cost = cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis()
         coll = collective_bytes(compiled.as_text())
     res = {
         "arch": "matu-round", "shape": f"N{n_clients}_T{n_tasks}_d{d}",
@@ -324,7 +312,7 @@ def run_round_engine(mesh, *, n_clients: int = 32, n_tasks: int = 30,
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
     mem = compiled.memory_analysis()
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     coll = collective_bytes(compiled.as_text())
 
     # the wire slot buffers each shard holds (uplink; the downlink

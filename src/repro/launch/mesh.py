@@ -22,16 +22,9 @@ ICI_BW = 50e9                  # B/s per link
 
 
 def _make_mesh(shape, axes, devices=None):
-    """jax.make_mesh across jax versions: newer jaxes take axis_types
-    (pass Auto so GSPMD stays in charge); 0.4.x has neither the kwarg
-    nor the enum and defaults to the same behaviour."""
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        kwargs["axis_types"] = (axis_type.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, **kwargs)
+    """jax.make_mesh with Auto axis types, so GSPMD stays in charge."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -10,10 +10,11 @@ Two modes:
         --tasks 8 --clients 16 --rounds 40
 
 * ``lm`` — supervised LoRA fine-tuning steps of one assigned
-  architecture (reduced variant on CPU; the full configs are exercised
-  by the dry-run / on real TPU metal by the same code path).
+  architecture: the reduced variant by default, the published config
+  (full widths, bf16) with ``--no-reduced``.
 
     PYTHONPATH=src python -m repro.launch.train lm --arch qwen2-0.5b --steps 50
+    PYTHONPATH=src python -m repro.launch.train lm --arch qwen2-0.5b --no-reduced
 """
 
 from __future__ import annotations
@@ -24,6 +25,8 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def run_fed(args) -> None:
@@ -121,10 +124,14 @@ def main() -> None:
     l.add_argument("--batch", type=int, default=4)
     l.add_argument("--seq", type=int, default=64)
     l.add_argument("--lr", type=float, default=5e-3)
-    l.add_argument("--reduced", action="store_true", default=True)
+    l.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                   default=True,
+                   help="tiny-width smoke variant (--no-reduced builds the "
+                        "published config)")
     l.add_argument("--seed", type=int, default=0)
 
     args = ap.parse_args()
+    enable_compile_cache()
     if args.mode == "lm":
         run_lm(args)
     else:

@@ -83,20 +83,9 @@ class Dense(Module):
         y = jnp.einsum("...i,io->...o", x, w)
         if lora is not None and "a" in lora:
             a = lora["a"]
-            if isinstance(a, dict):
-                # fused multi-tenant form (repro.serve.router): each
-                # leaf is {"base", "tau", "words"} + per-request
-                # "lam"/"alpha" — the modulated weight is built in
-                # VMEM by the fused kernel, never materialised here
-                y = y + self._lora_routed_fused(x, lora)
-            elif a.ndim == 3:
-                # dense-routed multi-tenant form: leaves carry a
-                # leading per-request axis (B, in, r)/(B, r, out)/(B,)
-                r = a.shape[-1]
-                scaling = lora["alpha"].astype(x.dtype) / r
-                h = jnp.einsum("b...i,bir->b...r", x, a)
-                yl = jnp.einsum("b...r,bro->b...o", h, lora["b"])
-                y = y + yl * scaling.reshape((-1,) + (1,) * (yl.ndim - 1))
+            if isinstance(a, dict) or a.ndim == 3:
+                # multi-tenant routed forms (repro.serve.router)
+                y = y + self._lora_routed(x, lora)
             else:
                 # LoRA: y += (x @ A) @ B * (alpha / r); A:(in,r) B:(r,out)
                 r = a.shape[-1]
@@ -107,27 +96,35 @@ class Dense(Module):
         return y
 
     @staticmethod
-    def _lora_routed_fused(x, lora):
-        """Fused serving branch: both LoRA matmuls run through
-        ``ops.modulated_matmul`` so each request's modulator is applied
-        in VMEM (word-unpack + λ-scale fused into the dot).  ``x`` is
-        (B, in) or (B, S, in); per-request ``lam``/``alpha`` are (B,).
-        Elementwise ``base + lam·m⊙tau`` is bitwise the dense path's
-        ``lora0 + unflatten(modulate(...))`` leaf, so this branch is
-        bit-identical to the dense-routed one under jit."""
+    def _lora_routed(x, lora):
+        """Multi-tenant LoRA branch; ``x`` is (B, in) or (B, S, in) and
+        per-request ``alpha`` (and ``lam``) are (B,).  Two forms:
+
+        * dense-routed — ``a``/``b`` are materialised per-request
+          factors (B, in, r)/(B, r, out);
+        * fused — each factor is ``{"base", "tau", "words"}`` and the
+          ``ops.modulated_matmul`` kernel builds ``base + lam·m⊙tau``
+          in VMEM (word-unpack + λ-scale fused into the dot), rounded
+          as the dense form's ``lora0 + unflatten(modulate(...))`` leaf
+          is rounded.
+
+        Both contract through the same kernel dot and share every op
+        after it, so the two forms compute the same arithmetic."""
         from repro.kernels import ops as _kops  # local: keep nn dep-free
-        af, bf, lam = lora["a"], lora["b"], lora["lam"]
-        r = af["base"].shape[-1]
+        a, b = lora["a"], lora["b"]
         squeeze = x.ndim == 2
         x3 = x[:, None, :] if squeeze else x
-        h = _kops.modulated_matmul(x3.astype(jnp.float32), af["base"],
-                                   af["tau"], af["words"], lam)
-        yl = _kops.modulated_matmul(h, bf["base"], bf["tau"], bf["words"],
-                                    lam)
-        scaling = (lora["alpha"].astype(jnp.float32) / r)
-        yl = yl * scaling[:, None, None]
-        yl = yl[:, 0] if squeeze else yl
-        return yl.astype(x.dtype)
+        if isinstance(a, dict):
+            r = a["base"].shape[-1]
+            h = _kops.modulated_matmul(x3, a["base"], a["tau"], a["words"],
+                                       lora["lam"])
+            yl = _kops.modulated_matmul(h, b["base"], b["tau"], b["words"],
+                                        lora["lam"])
+        else:
+            r = a.shape[-1]
+            yl = _kops.routed_matmul(_kops.routed_matmul(x3, a), b)
+        yl = yl * (lora["alpha"].astype(x.dtype) / r)[:, None, None]
+        return yl[:, 0] if squeeze else yl
 
     # LoRA factory -------------------------------------------------------
     def lora_init(self, key, rank: int, *, alpha: Optional[float] = None, dtype=None):

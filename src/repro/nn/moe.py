@@ -40,19 +40,6 @@ from repro.nn.sharding import current_mesh
 
 PyTree = Any
 
-try:  # jax >= 0.6 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-# the "don't verify replication" kwarg was renamed check_rep -> check_vma
-import inspect as _inspect
-
-_SM_NOCHECK = ({"check_vma": False}
-               if "check_vma" in _inspect.signature(shard_map).parameters
-               else {"check_rep": False})
-
-
 def _round8(x: int) -> int:
     return max(8, ((x + 7) // 8) * 8)
 
@@ -284,11 +271,11 @@ class MoE(Module):
                 out, aux = self._local_moe(router_w, experts, xt, 0, self.n_experts, cap)
                 return out.reshape(xs.shape), jax.lax.pmean(aux, all_axes)
 
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             fn,
             mesh=mesh,
             in_specs=(P(), e_spec, x_spec),
             out_specs=(x_spec, P()),
-            **_SM_NOCHECK,
+            check_vma=False,
         )(params["router"]["w"], params["experts"], x)
         return y, aux

@@ -29,13 +29,18 @@ fused (``fused=True``)
 Dense-routed is bit-identical to single-tenant decode with the dense
 unpacked modulator: ``(λ·m)⊙τ`` is IEEE-exact ``λ·where(m, τ, 0)``
 for mask bits in {0, 1}, and the per-request batched einsum contracts
-identically to the broadcast one.  The fused form is bit-identical to
-unpack-then-matmul *within the same compiled program* and token-
-identical end to end; its effective weights can sit one rounding of
-the modulated delta off the dense path's because XLA contracts the
-in-jit ``base + λ·m⊙τ`` build into an fma (the product feeds the add
-unrounded) while a materialised adapter rounds it first —
-tests/test_serve_multitenant.py pins down all three contracts.
+identically to the broadcast one.  The fused form builds each
+effective weight with ``ref.modulated_weight`` — the materialised
+adapter's own rounding to the leaf dtype — and both forms contract
+through the same kernel dot (``ops.modulated_matmul`` /
+``ops.routed_matmul``), so the fused form is bit-identical to
+unpack-then-matmul *within the same compiled program* and
+token-identical end to end.  With fp32 leaves its weights can still
+sit one rounding of the modulated delta off the dense path's, because
+XLA contracts the in-jit build into an fma (the product feeds the add
+unrounded) while a materialised adapter rounds it first; a bf16 leaf's
+cast between the two prevents that — tests/test_serve_multitenant.py
+pins down all three contracts.
 
 ``MultiTenantDecoder`` is the serving front end: it routes a batch,
 runs :func:`repro.serve.generate.generate` through ONE jitted program
@@ -53,6 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import bitpack
+from repro.kernels.ref import modulated_weight
 from repro.serve.generate import GenerationConfig, generate
 from repro.serve.store import ModulatorStore
 
@@ -102,8 +108,7 @@ def _site_dense_routed(site0, tau_site, rows, lam, space, prefix):
             bitpack.slice_bits(rows, spec.offset, spec.size),
             spec.size, jnp.float32).reshape((rows.shape[0],) + spec.shape)
         lam_b = lam.reshape((-1,) + (1,) * len(spec.shape))
-        val = (leaf0.astype(jnp.float32)[None]
-               + lam_b * bits * tau_site[key][None])
+        val = modulated_weight(leaf0[None], tau_site[key][None], bits, lam_b)
         out[key] = jnp.moveaxis(val, 0, 1)  # (B, L, ...) -> (L, B, ...)
     return out
 
@@ -143,10 +148,10 @@ def route_batch(store: ModulatorStore, task_ids: Sequence[int], *,
             return _site_dense_routed(site0, tau_site, rows, lam, space,
                                       prefix)
         fusedsite = {
-            "a": {"base": site0["a"].astype(jnp.float32),
+            "a": {"base": site0["a"],
                   "tau": tau_site["a"],
                   "words": _layer_words(rows, a_spec.offset, a_sz, n_layers)},
-            "b": {"base": site0["b"].astype(jnp.float32),
+            "b": {"base": site0["b"],
                   "tau": tau_site["b"],
                   "words": _layer_words(rows, b_spec.offset, b_sz, n_layers)},
             "lam": jnp.broadcast_to(lam[None, :], (n_layers, len(ids))),
@@ -156,8 +161,9 @@ def route_batch(store: ModulatorStore, task_ids: Sequence[int], *,
             bits = bitpack.unpack_bits(
                 bitpack.slice_bits(rows, al_spec.offset, al_spec.size),
                 al_spec.size, jnp.float32)                    # (B, L)
-            alpha_eff = (site0["alpha"].astype(jnp.float32)[None, :]
-                         + lam[:, None] * bits * tau_site["alpha"][None, :])
+            alpha_eff = modulated_weight(site0["alpha"][None, :],
+                                         tau_site["alpha"][None, :], bits,
+                                         lam[:, None])
             fusedsite["alpha"] = alpha_eff.T                  # (L, B)
         return fusedsite
 

@@ -150,8 +150,9 @@ class ModulatorStore:
         return modulate(self.unified, self._words[t], self._lams[t])
 
     def tau_tree(self) -> PyTree:
-        """The unified vector as a model-space fp32 pytree (the fused
-        router's per-leaf τ operand), unflattened once per ingest."""
+        """The unified vector as a model-space pytree in the leaf
+        dtypes (the fused router's per-leaf τ operand), unflattened once
+        per ingest."""
         if self.unified is None:
             raise ValueError("store has no unified vector (ingest first)")
         if self._tau_tree is None:

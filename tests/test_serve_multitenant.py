@@ -33,7 +33,7 @@ from repro.configs.base import SHAPES, load_arch
 from repro.core.client import ClientDownlink, ClientUpload
 from repro.core.server import MaTUServer, MaTUServerConfig
 from repro.core.unify import modulate
-from repro.kernels import bitpack, ops
+from repro.kernels import bitpack, ops, ref
 from repro.serve import (GenerationConfig, ModulatorStore, MultiTenantDecoder,
                          generate, route_batch)
 from repro.serve.generate import _sample
@@ -126,6 +126,34 @@ def test_modulated_matmul_bitwise_vs_unpack_then_matmul(mode):
         x, base, tau, words, lam)
     want = jax.jit(oracle)(x, base, tau, words, lam)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("mode", ["ref", "pallas_interpret"])
+def test_routed_matmul_bitwise_vs_einsum_and_fused(mode):
+    """The dense-routed kernel over materialised bf16 weights == the
+    batched einsum, and == the fused kernel fed the packed modulator
+    those weights were built from: the two routed forms contract
+    alike."""
+    rng = np.random.default_rng(1)
+    B, S, K, N = 3, 5, 32, 16
+    x = jnp.asarray(rng.standard_normal((B, S, K)), jnp.bfloat16)
+    base = jnp.asarray(rng.standard_normal((K, N)), jnp.bfloat16)
+    tau = jnp.asarray(rng.standard_normal((K, N)), jnp.bfloat16)
+    m = rng.random((B, K, N)) < 0.6
+    words = jnp.asarray(bitpack.pack_bits_np(m.reshape(B, K * N)))
+    lam = jnp.asarray(rng.standard_normal(B), jnp.float32)
+    w = ref.modulated_weight(base[None], tau[None],
+                             jnp.asarray(m, jnp.float32), lam[:, None, None])
+
+    got = jax.jit(functools.partial(ops.routed_matmul, mode=mode))(x, w)
+    want = jax.jit(lambda x, w: jnp.einsum("bsk,bkn->bsn", x, w))(x, w)
+    fused = jax.jit(functools.partial(ops.modulated_matmul, mode=mode))(
+        x, base, tau, words, lam)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(fused, np.float32))
 
 
 def test_modulated_matmul_rejects_misaligned():

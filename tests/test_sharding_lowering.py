@@ -20,7 +20,7 @@ SCRIPT = textwrap.dedent("""
     import jax.numpy as jnp
     from repro.configs.base import SHAPES, input_specs, load_arch
     from repro.launch.dryrun import (batch_shardings, collective_bytes,
-                                     cost_analysis_dict, opt_state_shardings)
+                                     opt_state_shardings)
     from repro.launch.mesh import arch_rules, make_debug_mesh
     from repro.nn.sharding import logical_to_sharding, mesh_context
     from repro.optim import adamw
@@ -44,7 +44,7 @@ SCRIPT = textwrap.dedent("""
         with mesh:
             compiled = fn.lower(params_struct, lora_struct, opt_struct,
                                 batch_struct).compile()
-        cost = cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis()
         print(json.dumps({{"flops": cost.get("flops", -1),
                           "coll": collective_bytes(compiled.as_text())}}))
 """)

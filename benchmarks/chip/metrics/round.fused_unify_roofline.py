@@ -1,0 +1,23 @@
+"""round.fused_unify_roofline: required work of the downlink
+re-unification kernel (``work/fused_unify.py``) per call, times its
+calls in the trace, over the device time of its events."""
+
+from benchlib import load
+from benchlib.roofline import share_pct
+
+KERNEL = "fused_unify_packed"
+
+
+def read(obs):
+    t = obs.trace
+    if t is None:
+        return None
+    if not t.kernel_calls(KERNEL):
+        raise LookupError(f"no {KERNEL} kernel in the trace; kernels seen: "
+                          f"{sorted(t.custom_calls)}")
+    w = obs.work
+    flops, nbytes = load("work", "fused_unify").required(
+        w["clients"], w["tasks"], w["tasks_per_client"], w["d"])
+    calls = t.kernel_calls(KERNEL)
+    return share_pct(flops * calls, nbytes * calls, t.kernel_s(KERNEL),
+                     obs.peaks)

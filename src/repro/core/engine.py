@@ -151,7 +151,9 @@ round r+1's uploads.  The contract:
 * **timings** — each yielded round carries a ``phase_us`` dict
   (``pack`` / ``decode`` / ``encode`` / ``device`` microseconds;
   ``device`` is dispatch→ready wall, which under the pipeline
-  overlaps the host phases of its neighbours).
+  overlaps the host phases of its neighbours).  ``pack`` / ``decode``
+  / ``encode`` are the self times of the ``repro.common.trace`` spans
+  of the same names, which a profiler trace also records.
 
 ``round_stream`` pulls upload round r+1 before yielding round r, so
 the input iterable must not depend on the previous round's downlinks —
@@ -302,6 +304,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.common.trace import span
 from repro.core.aggregation import EPS_DEFAULT, KAPPA_DEFAULT, RHO_DEFAULT
 from repro.core.client import ClientDownlink, ClientUpload
 from repro.kernels import bitpack, ops
@@ -515,104 +518,102 @@ def pack_uploads(uploads: Sequence[ClientUpload], n_tasks: int, *,
     if not uploads:
         raise ValueError("pack_uploads: empty round (no uploads) — "
                          "sample at least one client or skip the round")
-    t_pack = time.perf_counter()
-    n = len(uploads)
-    d = int(uploads[0].unified.shape[0])
-    _, _, n_shards = _mesh_layout(mesh)
-    d_pad = pad_d_for_shards(d, n_shards)
-    n_max = n_max or _round_up_pow2(n)
-    k_max = k_max or _round_up_pow2(max(len(u.task_ids) for u in uploads))
-    if n_max < n:
-        raise ValueError(f"n_max={n_max} < round size {n}")
+    with span("round.pack", phase_us, "pack"):
+        n = len(uploads)
+        d = int(uploads[0].unified.shape[0])
+        _, _, n_shards = _mesh_layout(mesh)
+        d_pad = pad_d_for_shards(d, n_shards)
+        n_max = n_max or _round_up_pow2(n)
+        k_max = k_max or _round_up_pow2(max(len(u.task_ids) for u in uploads))
+        if n_max < n:
+            raise ValueError(f"n_max={n_max} < round size {n}")
 
-    # one batched host-edge decode for ALL coded clients: streams
-    # concatenate (records self-delimit) and split back by row count
-    ks = [len(u.task_ids) for u in uploads]
-    masks_np = [np.asarray(u.masks) for u in uploads]
-    coded = [i for i, m in enumerate(masks_np) if m.dtype == np.uint8]
-    dec_s = 0.0
-    if coded:
-        from repro.fed.compression import decode_mask_rows
-        t0 = time.perf_counter()
-        rows = decode_mask_rows(
-            masks_np[coded[0]] if len(coded) == 1
-            else np.concatenate([masks_np[i] for i in coded]),
-            d, sum(ks[i] for i in coded))
-        off = 0
-        for i in coded:
-            masks_np[i] = rows[off:off + ks[i]]
-            off += ks[i]
-        dec_s = time.perf_counter() - t0
+        # one batched host-edge decode for ALL coded clients: streams
+        # concatenate (records self-delimit) and split back by row count
+        ks = [len(u.task_ids) for u in uploads]
+        masks_np = [np.asarray(u.masks) for u in uploads]
+        coded = [i for i, m in enumerate(masks_np) if m.dtype == np.uint8]
+        if coded:
+            from repro.fed.compression import decode_mask_rows
+            with span("round.decode", phase_us, "decode"):
+                rows = decode_mask_rows(
+                    masks_np[coded[0]] if len(coded) == 1
+                    else np.concatenate([masks_np[i] for i in coded]),
+                    d, sum(ks[i] for i in coded))
+                off = 0
+                for i in coded:
+                    masks_np[i] = rows[off:off + ks[i]]
+                    off += ks[i]
+        elif phase_us is not None:
+            phase_us.setdefault("decode", 0.0)
 
-    # np.empty + zero only the padding: the valid region is fully
-    # overwritten below, so a full np.zeros would write the big
-    # mask/vector buffers twice for nothing.  With a stage the same
-    # (possibly dirty) buffers come back each round — the explicit
-    # padding writes below are exactly the re-zeroing reuse needs.
-    # host-side bf16 fill for the wire layout (ml_dtypes ships with
-    # jax): halves the host→device transfer and skips the device cast
-    vec_dtype = np.float32
-    if packed:
-        import ml_dtypes
-        vec_dtype = ml_dtypes.bfloat16
-    alloc = stage.alloc if stage is not None else (
-        lambda _name, shape, dtype: np.empty(shape, dtype))
-    unified = alloc("unified", (n_max, d_pad), vec_dtype)
-    unified[n:] = 0.0
-    unified[:, d:] = 0.0
-    if packed:
-        dw = bitpack.packed_width(d)
-        wpad = bitpack.packed_width(d_pad)
-        slot_masks = alloc("slot_masks", (n_max, k_max, wpad), np.uint32)
-        slot_masks[n:] = 0
-        if wpad > dw:
-            slot_masks[:n, :, dw:] = 0
-    else:
-        slot_masks = alloc("slot_masks", (n_max, k_max, d_pad), bool)
-        slot_masks[n:] = False
-        slot_masks[:, :, d:] = False
-    slot_lams = np.zeros((n_max, k_max), np.float32)
-    slot_sizes = np.zeros((n_max, k_max), np.float32)
-    slot_tasks = np.full((n_max, k_max), n_tasks, np.int32)
-    slot_valid = np.zeros((n_max, k_max), bool)
-
-    for i, up in enumerate(uploads):
-        k = ks[i]
-        unified[i, :d] = np.asarray(up.unified)
-        m = masks_np[i]
+        # np.empty + zero only the padding: the valid region is fully
+        # overwritten below, so a full np.zeros would write the big
+        # mask/vector buffers twice for nothing.  With a stage the same
+        # (possibly dirty) buffers come back each round — the explicit
+        # padding writes below are exactly the re-zeroing reuse needs.
+        # host-side bf16 fill for the wire layout (ml_dtypes ships with
+        # jax): halves the host→device transfer and skips the device cast
+        vec_dtype = np.float32
         if packed:
-            # accept either bool masks (legacy clients — packed here at
-            # the wire boundary) or already-packed words
-            slot_masks[i, :k, :dw] = (m if m.dtype == np.uint32
-                                      else bitpack.pack_bits_np(m))
-            slot_masks[i, k:, :dw] = 0
+            import ml_dtypes
+            vec_dtype = ml_dtypes.bfloat16
+        alloc = stage.alloc if stage is not None else (
+            lambda _name, shape, dtype: np.empty(shape, dtype))
+        unified = alloc("unified", (n_max, d_pad), vec_dtype)
+        unified[n:] = 0.0
+        unified[:, d:] = 0.0
+        if packed:
+            dw = bitpack.packed_width(d)
+            wpad = bitpack.packed_width(d_pad)
+            slot_masks = alloc("slot_masks", (n_max, k_max, wpad), np.uint32)
+            slot_masks[n:] = 0
+            if wpad > dw:
+                slot_masks[:n, :, dw:] = 0
         else:
-            slot_masks[i, :k, :d] = (bitpack.unpack_bits_np(m, d)
-                                     if m.dtype == np.uint32 else m)
-            slot_masks[i, k:] = False
-        slot_lams[i, :k] = np.asarray(up.lams, np.float32)
-        slot_sizes[i, :k] = np.asarray(up.data_sizes, np.float32)
-        slot_tasks[i, :k] = up.task_ids
-        slot_valid[i, :k] = True
-    if phase_us is not None:
-        phase_us["decode"] = phase_us.get("decode", 0.0) + dec_s * 1e6
-        phase_us["pack"] = (phase_us.get("pack", 0.0)
-                            + (time.perf_counter() - t_pack - dec_s) * 1e6)
+            slot_masks = alloc("slot_masks", (n_max, k_max, d_pad), bool)
+            slot_masks[n:] = False
+            slot_masks[:, :, d:] = False
+        slot_lams = np.zeros((n_max, k_max), np.float32)
+        slot_sizes = np.zeros((n_max, k_max), np.float32)
+        slot_tasks = np.full((n_max, k_max), n_tasks, np.int32)
+        slot_valid = np.zeros((n_max, k_max), bool)
 
-    arrays = (unified, slot_masks, slot_lams, slot_sizes, slot_tasks,
-              slot_valid)
-    if n_shards > 1:
-        rep = NamedSharding(mesh, P())
-        put = (taskvec_sharding(mesh, 2), taskvec_sharding(mesh, 3),
-               rep, rep, rep, rep)
-        uni, masks, lams, sizes, tasks, valid = (
-            jax.device_put(a, s) for a, s in zip(arrays, put))
-    else:
-        uni, masks, lams, sizes, tasks, valid = map(jnp.asarray, arrays)
-    return PackedRound([u.client_id for u in uploads],
-                       [list(u.task_ids) for u in uploads],
-                       uni, masks, lams, sizes, tasks, valid,
-                       n_tasks, d, d_pad if n_shards > 1 else None)
+        for i, up in enumerate(uploads):
+            k = ks[i]
+            unified[i, :d] = np.asarray(up.unified)
+            m = masks_np[i]
+            if packed:
+                # accept either bool masks (legacy clients — packed here at
+                # the wire boundary) or already-packed words
+                slot_masks[i, :k, :dw] = (m if m.dtype == np.uint32
+                                          else bitpack.pack_bits_np(m))
+                slot_masks[i, k:, :dw] = 0
+            else:
+                slot_masks[i, :k, :d] = (bitpack.unpack_bits_np(m, d)
+                                         if m.dtype == np.uint32 else m)
+                slot_masks[i, k:] = False
+            slot_lams[i, :k] = np.asarray(up.lams, np.float32)
+            slot_sizes[i, :k] = np.asarray(up.data_sizes, np.float32)
+            slot_tasks[i, :k] = up.task_ids
+            slot_valid[i, :k] = True
+
+        arrays = (unified, slot_masks, slot_lams, slot_sizes, slot_tasks,
+                  slot_valid)
+        with span("round.h2d"):
+            if n_shards > 1:
+                rep = NamedSharding(mesh, P())
+                put = (taskvec_sharding(mesh, 2), taskvec_sharding(mesh, 3),
+                       rep, rep, rep, rep)
+                uni, masks, lams, sizes, tasks, valid = (
+                    jax.device_put(a, s) for a, s in zip(arrays, put))
+            else:
+                uni, masks, lams, sizes, tasks, valid = map(jnp.asarray,
+                                                            arrays)
+        return PackedRound([u.client_id for u in uploads],
+                           [list(u.task_ids) for u in uploads],
+                           uni, masks, lams, sizes, tasks, valid,
+                           n_tasks, d, d_pad if n_shards > 1 else None)
 
 
 def pack_from_slots(client_ids: List[int], task_ids: List[List[int]],
@@ -736,34 +737,32 @@ def _assemble_downlinks(client_ids: List[int], task_ids: List[List[int]],
     mask rows of ALL the given clients are entropy-coded in one batched
     call and split back by per-row record sizes (records self-delimit,
     so each slice is byte-identical to encoding that client alone)."""
-    streams: Optional[List[jax.Array]] = None
-    if code_masks:
-        from repro.fed.compression import encode_mask_rows_with_sizes
-        t0 = time.perf_counter()
-        dm = np.asarray(down_masks)
-        if dm.dtype != np.uint32:     # bool A/B layout
-            dm = bitpack.pack_bits_np(dm)
-        ks = [len(t) for t in task_ids]
-        rows = dm[np.repeat(np.arange(len(ks)), ks),
-                  np.concatenate([np.arange(k, dtype=np.int64)
-                                  for k in ks])]
-        stream, sizes = encode_mask_rows_with_sizes(rows, d)
-        ends = np.cumsum(sizes)
-        streams, b0, r0 = [], 0, 0
-        for k in ks:
-            b1 = int(ends[r0 + k - 1]) if k else b0
-            streams.append(jnp.asarray(stream[b0:b1]))
-            b0, r0 = b1, r0 + k
-        if phase_us is not None:
-            phase_us["encode"] = (phase_us.get("encode", 0.0)
-                                  + (time.perf_counter() - t0) * 1e6)
-    result: Dict[int, ClientDownlink] = {}
-    for i, cid in enumerate(client_ids):
-        k = len(task_ids[i])
-        rows_i = streams[i] if code_masks else down_masks[i, :k]
-        result[cid] = ClientDownlink(down_unified[i], rows_i,
-                                     down_lams[i, :k])
-    return result
+    with span("round.assemble"):
+        streams: Optional[List[jax.Array]] = None
+        if code_masks:
+            from repro.fed.compression import encode_mask_rows_with_sizes
+            with span("round.encode", phase_us, "encode"):
+                dm = np.asarray(down_masks)
+                if dm.dtype != np.uint32:     # bool A/B layout
+                    dm = bitpack.pack_bits_np(dm)
+                ks = [len(t) for t in task_ids]
+                rows = dm[np.repeat(np.arange(len(ks)), ks),
+                          np.concatenate([np.arange(k, dtype=np.int64)
+                                          for k in ks])]
+                stream, sizes = encode_mask_rows_with_sizes(rows, d)
+                ends = np.cumsum(sizes)
+                streams, b0, r0 = [], 0, 0
+                for k in ks:
+                    b1 = int(ends[r0 + k - 1]) if k else b0
+                    streams.append(jnp.asarray(stream[b0:b1]))
+                    b0, r0 = b1, r0 + k
+        result: Dict[int, ClientDownlink] = {}
+        for i, cid in enumerate(client_ids):
+            k = len(task_ids[i])
+            rows_i = streams[i] if code_masks else down_masks[i, :k]
+            result[cid] = ClientDownlink(down_unified[i], rows_i,
+                                         down_lams[i, :k])
+        return result
 
 
 # -- chunked-round jit bodies (population-scale contract) --------------------
@@ -948,7 +947,8 @@ class RoundEngine:
             # the weighted trace is a separate jit entry (extra operand)
             # — the synchronous program is never re-traced or perturbed
             args += (packed.slot_weights,)
-        out = self._impl(mode, packed.d)(*args)
+        with span("round.dispatch"):
+            out = self._impl(mode, packed.d)(*args)
         if d_pad != packed.d:
             out = _slice_outputs(out, packed.d, packed.packed)
         if packed.packed:
@@ -998,21 +998,22 @@ class RoundEngine:
         ``staleness`` (one int per upload, async buffered rounds)
         attaches the per-slot discount ``staleness_discount**s`` to the
         round — see "Async & fault model" in the module docstring."""
-        batch = pack_uploads(uploads, self.cfg.n_tasks, packed=packed,
-                             mesh=self.mesh)
-        if staleness is not None:
-            n_max, k_max = batch.slot_valid.shape
-            w = np.ones((n_max, k_max), np.float32)
-            w[:len(uploads)] = (np.float32(staleness_discount)
-                                ** np.asarray(staleness,
-                                              np.float32))[:, None]
-            if self.n_shards > 1:
-                batch.slot_weights = jax.device_put(
-                    w, NamedSharding(self.mesh, P()))
-            else:
-                batch.slot_weights = jnp.asarray(w)
-        out = self.run_packed(batch, mode=mode)
-        return self.downlinks(batch, out, code_masks=code_masks), out
+        with span("round"):
+            batch = pack_uploads(uploads, self.cfg.n_tasks, packed=packed,
+                                 mesh=self.mesh)
+            if staleness is not None:
+                n_max, k_max = batch.slot_valid.shape
+                w = np.ones((n_max, k_max), np.float32)
+                w[:len(uploads)] = (np.float32(staleness_discount)
+                                    ** np.asarray(staleness,
+                                                  np.float32))[:, None]
+                if self.n_shards > 1:
+                    batch.slot_weights = jax.device_put(
+                        w, NamedSharding(self.mesh, P()))
+                else:
+                    batch.slot_weights = jnp.asarray(w)
+            out = self.run_packed(batch, mode=mode)
+            return self.downlinks(batch, out, code_masks=code_masks), out
 
     def _chunk_impls(self, mode: str, d: int, packed: bool,
                      n_for_dtype: int):
@@ -1082,167 +1083,178 @@ class RoundEngine:
         ``uplink_bits`` / ``downlink_bits`` (identical to the
         monolithic round's accounting), ``n_clients`` and ``n_chunks``.
         """
-        mode = mode or ops.resolve_mode()
-        C = int(chunk_clients)
-        if C < 1:
-            raise ValueError(f"round_chunked: chunk_clients={C} < 1")
-        make_iter = (uploads if callable(uploads)
-                     else (lambda: iter(uploads)))
+        with span("round"):
+            mode = mode or ops.resolve_mode()
+            C = int(chunk_clients)
+            if C < 1:
+                raise ValueError(f"round_chunked: chunk_clients={C} < 1")
+            make_iter = (uploads if callable(uploads)
+                         else (lambda: iter(uploads)))
 
-        # -- pass 0: chunk metadata (client ids / task ids / sizes only —
-        # O(N·k) host scalars, no d-axis tensor touched)
-        metas: List[tuple] = []
-        cur_ids: List[int] = []
-        cur_tasks: List[List[int]] = []
-        cur_sizes: List[np.ndarray] = []
-        cur_stal: List[float] = []
-        stal_it = iter(staleness) if staleness is not None else None
-        d = None
-        k_seen, n_clients = 1, 0
+            # -- pass 0: chunk metadata (client ids / task ids / sizes
+            # only — O(N·k) host scalars, no d-axis tensor touched)
+            metas: List[tuple] = []
+            cur_ids: List[int] = []
+            cur_tasks: List[List[int]] = []
+            cur_sizes: List[np.ndarray] = []
+            cur_stal: List[float] = []
+            stal_it = iter(staleness) if staleness is not None else None
+            d = None
+            k_seen, n_clients = 1, 0
 
-        def _flush():
-            metas.append((list(cur_ids), list(cur_tasks), list(cur_sizes),
-                          list(cur_stal) if stal_it is not None else None))
-            cur_ids.clear(), cur_tasks.clear()
-            cur_sizes.clear(), cur_stal.clear()
+            def _flush():
+                metas.append((list(cur_ids), list(cur_tasks), list(cur_sizes),
+                              list(cur_stal) if stal_it is not None else None))
+                cur_ids.clear(), cur_tasks.clear()
+                cur_sizes.clear(), cur_stal.clear()
 
-        for up in make_iter():
-            if d is None:
-                d = int(up.unified.shape[0])
-            tids = list(up.task_ids)
-            k_seen = max(k_seen, len(tids))
-            cur_ids.append(up.client_id)
-            cur_tasks.append(tids)
-            cur_sizes.append(np.asarray(up.data_sizes, np.float32))
-            if stal_it is not None:
-                cur_stal.append(next(stal_it))
-            n_clients += 1
-            if len(cur_ids) == C:
-                _flush()
-        if cur_ids:
-            _flush()
-        if n_clients == 0:
-            raise ValueError("round_chunked: empty round (no uploads) — "
-                             "sample at least one client or skip the round")
-        if k_max is None:
-            k_max = _round_up_pow2(k_seen)
-        elif k_max < k_seen:
-            raise ValueError(f"round_chunked: k_max={k_max} < max client "
-                             f"task count {k_seen}")
-        # pow2 chunk rows, ≥ the slot-shard count so phase-C row
-        # sharding always divides evenly
-        c_pad = max(_round_up_pow2(C), self.slot_shards)
-        n_seg = self.cfg.n_tasks + 1
-        d_pad = pad_d_for_shards(d, self.n_shards)
-        # accumulator width: the sharded padding, or the monolithic
-        # round's own CHUNK_D streaming-grid padding — identical grids
-        # are what make chunked ≡ monolithic bitwise
-        dp = d_pad if self.n_shards > 1 else _chunked(d, CHUNK_D)[1]
-        # same α-numerator dtype decision as the monolithic round
-        # (keyed on its default n_max = next pow2 ≥ N)
-        n_for_dtype = _round_up_pow2(n_clients)
-        scal, merge, finish, down = self._chunk_impls(
-            mode, d, packed, n_for_dtype if packed else 0)
+            with span("round.meta"):
+                for up in make_iter():
+                    if d is None:
+                        d = int(up.unified.shape[0])
+                    tids = list(up.task_ids)
+                    k_seen = max(k_seen, len(tids))
+                    cur_ids.append(up.client_id)
+                    cur_tasks.append(tids)
+                    cur_sizes.append(np.asarray(up.data_sizes, np.float32))
+                    if stal_it is not None:
+                        cur_stal.append(next(stal_it))
+                    n_clients += 1
+                    if len(cur_ids) == C:
+                        _flush()
+                if cur_ids:
+                    _flush()
+            if n_clients == 0:
+                raise ValueError("round_chunked: empty round (no uploads) — "
+                                 "sample at least one client or skip the "
+                                 "round")
+            if k_max is None:
+                k_max = _round_up_pow2(k_seen)
+            elif k_max < k_seen:
+                raise ValueError(f"round_chunked: k_max={k_max} < max client "
+                                 f"task count {k_seen}")
+            # pow2 chunk rows, ≥ the slot-shard count so phase-C row
+            # sharding always divides evenly
+            c_pad = max(_round_up_pow2(C), self.slot_shards)
+            n_seg = self.cfg.n_tasks + 1
+            d_pad = pad_d_for_shards(d, self.n_shards)
+            # accumulator width: the sharded padding, or the monolithic
+            # round's own CHUNK_D streaming-grid padding — identical grids
+            # are what make chunked ≡ monolithic bitwise
+            dp = d_pad if self.n_shards > 1 else _chunked(d, CHUNK_D)[1]
+            # same α-numerator dtype decision as the monolithic round
+            # (keyed on its default n_max = next pow2 ≥ N)
+            n_for_dtype = _round_up_pow2(n_clients)
+            scal, merge, finish, down = self._chunk_impls(
+                mode, d, packed, n_for_dtype if packed else 0)
 
-        def _scalar_chunk(ids_, tasks_, sizes_, stal_):
-            sz = np.zeros((c_pad, k_max), np.float32)
-            tk = np.full((c_pad, k_max), self.cfg.n_tasks, np.int32)
-            vd = np.zeros((c_pad, k_max), bool)
-            for i, (tl, sl) in enumerate(zip(tasks_, sizes_)):
-                k = len(tl)
-                sz[i, :k] = sl
-                tk[i, :k] = tl
-                vd[i, :k] = True
-            w = None
-            if stal_ is not None:
-                w = np.ones((c_pad, k_max), np.float32)
-                w[:len(ids_)] = (np.float32(staleness_discount)
-                                 ** np.asarray(stal_,
-                                               np.float32))[:, None]
-                w = jnp.asarray(w)
-            return jnp.asarray(sz), jnp.asarray(tk), jnp.asarray(vd), w
+            def _scalar_chunk(ids_, tasks_, sizes_, stal_):
+                sz = np.zeros((c_pad, k_max), np.float32)
+                tk = np.full((c_pad, k_max), self.cfg.n_tasks, np.int32)
+                vd = np.zeros((c_pad, k_max), bool)
+                for i, (tl, sl) in enumerate(zip(tasks_, sizes_)):
+                    k = len(tl)
+                    sz[i, :k] = sl
+                    tk[i, :k] = tl
+                    vd[i, :k] = True
+                w = None
+                if stal_ is not None:
+                    w = np.ones((c_pad, k_max), np.float32)
+                    w[:len(ids_)] = (np.float32(staleness_discount)
+                                     ** np.asarray(stal_,
+                                                   np.float32))[:, None]
+                    w = jnp.asarray(w)
+                return jnp.asarray(sz), jnp.asarray(tk), jnp.asarray(vd), w
 
-        # -- phase A: fold the (T+1,) size totals / membership counts
-        totals = jnp.zeros((n_seg,), jnp.float32)
-        nt_acc = jnp.zeros((n_seg,), jnp.float32)
-        w_chunks: List[Optional[jax.Array]] = []
-        for ids_, tasks_, sizes_, stal_ in metas:
-            sz, tk, vd, w = _scalar_chunk(ids_, tasks_, sizes_, stal_)
-            w_chunks.append(w)
-            args = (sz, vd, tk, totals, nt_acc)
-            totals, nt_acc = scal(*args, w) if w is not None else scal(*args)
+            # -- phase A: fold the (T+1,) size totals / membership counts
+            totals = jnp.zeros((n_seg,), jnp.float32)
+            nt_acc = jnp.zeros((n_seg,), jnp.float32)
+            w_chunks: List[Optional[jax.Array]] = []
+            with span("round.meta"):
+                for ids_, tasks_, sizes_, stal_ in metas:
+                    sz, tk, vd, w = _scalar_chunk(ids_, tasks_, sizes_, stal_)
+                    w_chunks.append(w)
+                    args = (sz, vd, tk, totals, nt_acc)
+                    totals, nt_acc = (scal(*args, w) if w is not None
+                                      else scal(*args))
 
-        # -- phase B: second pass over the stream, fold merge partials
-        a_acc = jnp.zeros((n_seg, dp),
-                          jnp.int32 if packed else jnp.float32)
-        tau_acc = jnp.zeros((n_seg, dp), jnp.float32)
-        stage = SlotStage()
-        stream = make_iter()
-        uplink_bits = 0
-        for ci, (ids_, tasks_, sizes_, stal_) in enumerate(metas):
-            ups = list(itertools.islice(stream, len(ids_)))
-            if [u.client_id for u in ups] != ids_:
-                raise ValueError(
-                    "round_chunked: the upload factory returned a "
-                    "different round on the second pass — it must be "
-                    "deterministic (same clients, same order)")
-            batch = pack_uploads(ups, self.cfg.n_tasks, n_max=c_pad,
-                                 k_max=k_max, packed=packed, mesh=self.mesh,
-                                 stage=stage, phase_us=phase_us)
-            uplink_bits += batch.wire_bits()
-            args = (batch.unified, batch.slot_masks, batch.slot_lams,
-                    batch.slot_sizes, batch.slot_valid, batch.slot_tasks,
-                    totals, a_acc, tau_acc)
-            if w_chunks[ci] is not None:
-                args += (w_chunks[ci],)
-            a_acc, tau_acc = merge(*args)
-            # the dispatched step may alias the staged host buffers
-            # zero-copy (CPU jnp.asarray) — block before the refill
-            jax.block_until_ready(tau_acc)
+            # -- phase B: second pass over the stream, fold merge partials
+            a_acc = jnp.zeros((n_seg, dp),
+                              jnp.int32 if packed else jnp.float32)
+            tau_acc = jnp.zeros((n_seg, dp), jnp.float32)
+            stage = SlotStage()
+            stream = make_iter()
+            uplink_bits = 0
+            for ci, (ids_, tasks_, sizes_, stal_) in enumerate(metas):
+                ups = list(itertools.islice(stream, len(ids_)))
+                if [u.client_id for u in ups] != ids_:
+                    raise ValueError(
+                        "round_chunked: the upload factory returned a "
+                        "different round on the second pass — it must be "
+                        "deterministic (same clients, same order)")
+                batch = pack_uploads(ups, self.cfg.n_tasks, n_max=c_pad,
+                                     k_max=k_max, packed=packed,
+                                     mesh=self.mesh,
+                                     stage=stage, phase_us=phase_us)
+                uplink_bits += batch.wire_bits()
+                args = (batch.unified, batch.slot_masks, batch.slot_lams,
+                        batch.slot_sizes, batch.slot_valid, batch.slot_tasks,
+                        totals, a_acc, tau_acc)
+                if w_chunks[ci] is not None:
+                    args += (w_chunks[ci],)
+                with span("round.dispatch"):
+                    a_acc, tau_acc = merge(*args)
+                # the dispatched step may alias the staged host buffers
+                # zero-copy (CPU jnp.asarray) — block before the refill
+                with span("round.wait"):
+                    jax.block_until_ready(tau_acc)
 
-        # -- finish: Eq. 3/5/6/7 + λ numerator from the accumulators
-        tv, tau_hats, third, n_t, sim, num_t = finish(a_acc, tau_acc, nt_acc)
-        tv_run = tv                    # keeps the shard padding for phase C
-        if self.n_shards > 1 and d_pad != d:
-            tv, tau_hats, third = tv[:, :d], tau_hats[:, :d], third[:, :d]
-        if packed:
-            out = EngineOutput(tv, tau_hats, sim, None, None, None,
-                               alpha_num=third, n_held=n_t,
-                               rho=self.cfg.rho)
-        else:
-            out = EngineOutput(tv, tau_hats, sim, None, None, None,
-                               rho=self.cfg.rho, m_hats_dense=third)
-
-        # -- phase C: per-chunk downlink re-unification, streamed out
-        dw = bitpack.packed_width(d)
-        downlinks: Dict[int, ClientDownlink] = {}
-        downlink_bits = 0
-        for ids_, tasks_, sizes_, stal_ in metas:
-            tk = np.full((c_pad, k_max), self.cfg.n_tasks, np.int32)
-            vd = np.zeros((c_pad, k_max), bool)
-            for i, tl in enumerate(tasks_):
-                tk[i, :len(tl)] = tl
-                vd[i, :len(tl)] = True
-            du, dm, dl = down(tv_run, jnp.asarray(vd), jnp.asarray(tk),
-                              num_t)
+            # -- finish: Eq. 3/5/6/7 + λ numerator from the accumulators
+            with span("round.dispatch"):
+                tv, tau_hats, third, n_t, sim, num_t = finish(a_acc, tau_acc,
+                                                              nt_acc)
+            tv_run = tv                # keeps the shard padding for phase C
             if self.n_shards > 1 and d_pad != d:
-                du = du[:, :d]
-                dm = dm[:, :, :dw] if packed else dm[:, :, :d]
-            links = _assemble_downlinks(ids_, tasks_, d, du, dm, dl,
-                                        code_masks=code_masks,
-                                        phase_us=phase_us)
-            downlink_bits += sum(link.downlink_bits()
-                                 for link in links.values())
-            if sink is not None:
-                sink(links)
+                tv, tau_hats, third = tv[:, :d], tau_hats[:, :d], third[:, :d]
+            if packed:
+                out = EngineOutput(tv, tau_hats, sim, None, None, None,
+                                   alpha_num=third, n_held=n_t,
+                                   rho=self.cfg.rho)
             else:
-                downlinks.update(links)
+                out = EngineOutput(tv, tau_hats, sim, None, None, None,
+                                   rho=self.cfg.rho, m_hats_dense=third)
 
-        stats = {"uplink_bits": uplink_bits,
-                 "downlink_bits": downlink_bits,
-                 "n_clients": n_clients, "n_chunks": len(metas),
-                 "chunk_clients": C}
-        return downlinks, out, stats
+            # -- phase C: per-chunk downlink re-unification, streamed out
+            dw = bitpack.packed_width(d)
+            downlinks: Dict[int, ClientDownlink] = {}
+            downlink_bits = 0
+            for ids_, tasks_, sizes_, stal_ in metas:
+                tk = np.full((c_pad, k_max), self.cfg.n_tasks, np.int32)
+                vd = np.zeros((c_pad, k_max), bool)
+                for i, tl in enumerate(tasks_):
+                    tk[i, :len(tl)] = tl
+                    vd[i, :len(tl)] = True
+                with span("round.dispatch"):
+                    du, dm, dl = down(tv_run, jnp.asarray(vd), jnp.asarray(tk),
+                                      num_t)
+                if self.n_shards > 1 and d_pad != d:
+                    du = du[:, :d]
+                    dm = dm[:, :, :dw] if packed else dm[:, :, :d]
+                links = _assemble_downlinks(ids_, tasks_, d, du, dm, dl,
+                                            code_masks=code_masks,
+                                            phase_us=phase_us)
+                downlink_bits += sum(link.downlink_bits()
+                                     for link in links.values())
+                if sink is not None:
+                    sink(links)
+                else:
+                    downlinks.update(links)
+
+            stats = {"uplink_bits": uplink_bits,
+                     "downlink_bits": downlink_bits,
+                     "n_clients": n_clients, "n_chunks": len(metas),
+                     "chunk_clients": C}
+            return downlinks, out, stats
 
     def round_stream(self, rounds, *, mode: Optional[str] = None,
                      packed: bool = True, code_masks: bool = False,

@@ -87,11 +87,13 @@ def _fused_unify_call(task_vectors, valid, *, block_d, interpret, packed):
     dp = d + pad
     if packed:
         kernel, uni_dtype = _fused_unify_packed_kernel, jnp.bfloat16
+        name = "fused_unify_packed_pallas"
         mask_spec = pl.BlockSpec((1, k, block_d // 128, 4),
                                  lambda i, j: (i, 0, j, 0))
         mask_out = jax.ShapeDtypeStruct((b, k, dp // 128, 4), jnp.uint32)
     else:
         kernel, uni_dtype = _fused_unify_kernel, jnp.float32
+        name = "fused_unify_pallas"
         mask_spec = pl.BlockSpec((1, k, block_d), lambda i, j: (i, 0, j))
         mask_out = jax.ShapeDtypeStruct((b, k, dp), jnp.float32)
     scalar_spec = pl.BlockSpec((1, k, 1), lambda i, j: (i, 0, 0))
@@ -115,6 +117,7 @@ def _fused_unify_call(task_vectors, valid, *, block_d, interpret, packed):
             jax.ShapeDtypeStruct((b, k, 1), jnp.float32),
         ],
         interpret=interpret,
+        name=name,
     )(task_vectors, valid.astype(jnp.float32).reshape(b, k, 1))
     return unified[:, 0], masks, num[:, :, 0], den[:, :, 0]
 

@@ -120,6 +120,7 @@ def masked_agg_batched_pallas(unified: jax.Array, masks: jax.Array,
         out_specs=out_specs,
         out_shape=[jax.ShapeDtypeStruct((t, 1, dp), jnp.float32)] * 2,
         interpret=interpret,
+        name="masked_agg_batched_pallas",
     )(unified, masks.astype(unified.dtype).reshape(n, t * dp),
       *_task_major_scalars(lams, gammas, members))
     return tau[:, 0, :d], m_hat[:, 0, :d]
@@ -196,6 +197,7 @@ def masked_agg_batched_packed_pallas(unified: jax.Array, mask_words: jax.Array,
         out_specs=out_specs,
         out_shape=[jax.ShapeDtypeStruct((t, 1, dp), jnp.float32)] * 2,
         interpret=interpret,
+        name="masked_agg_batched_packed_pallas",
     )(unified, mask_words.reshape(n, t * dwp), pos_w, neg_w,
       *_task_major_scalars(lams, gammas, members))
     return tau[:, 0, :d], anum[:, 0, :d]
@@ -236,5 +238,6 @@ def masked_agg_pallas(unified: jax.Array, masks: jax.Array, lams: jax.Array,
             jax.ShapeDtypeStruct((dp,), jnp.float32),
         ],
         interpret=interpret,
+        name="masked_agg_pallas",
     )(unified, masks.astype(unified.dtype), lams, gammas)
     return tau[:d], m_hat[:d]

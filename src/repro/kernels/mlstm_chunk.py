@@ -118,5 +118,6 @@ def mlstm_chunkwise_pallas(q, k, v, i_pre, f_pre, *, chunk: int = 64,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="mlstm_chunkwise_pallas",
     )(q, k, v, i_pre, f_pre)
     return h[:, :s]

@@ -105,6 +105,7 @@ def modulated_matmul_pallas(x: jax.Array, base: jax.Array, tau: jax.Array,
         out_shape=jax.ShapeDtypeStruct((b, s, n),
                                        jnp.result_type(x.dtype, base.dtype)),
         interpret=interpret,
+        name="modulated_matmul_pallas",
     )(x, base, tau, rw, lam.astype(jnp.float32).reshape(b, 1, 1))
     return out
 
@@ -128,4 +129,5 @@ def routed_matmul_pallas(x: jax.Array, w: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((b, s, n),
                                        jnp.result_type(x.dtype, w.dtype)),
         interpret=interpret,
+        name="routed_matmul_pallas",
     )(x, w)

@@ -50,6 +50,7 @@ def sign_sim_pallas(tau_hats: jax.Array, *, block_d: int = BLOCK_D,
         out_specs=pl.BlockSpec((t, t), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((t, t), jnp.float32),
         interpret=interpret,
+        name="sign_sim_pallas",
     )(tau_hats)
     return 0.5 * (dots / d + 1.0)
 
@@ -96,4 +97,5 @@ def sign_sim_packed_pallas(pos: jax.Array, nz: jax.Array, *,
         out_specs=pl.BlockSpec((t, t), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((t, t), jnp.float32),
         interpret=interpret,
+        name="sign_sim_packed_pallas",
     )(pos, nz)

@@ -49,5 +49,6 @@ def unify_pallas(task_vectors: jax.Array, *, block_d: int = BLOCK_D,
         out_specs=pl.BlockSpec((block_d,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((dp,), jnp.float32),
         interpret=interpret,
+        name="unify_pallas",
     )(task_vectors)
     return out[:d]

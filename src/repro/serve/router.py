@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common.trace import span
 from repro.kernels import bitpack
 from repro.kernels.ref import modulated_weight
 from repro.serve.generate import GenerationConfig, generate
@@ -196,15 +197,18 @@ class MultiTenantDecoder:
                  max_len: Optional[int] = None) -> jax.Array:
         """prompts (B, S) int32 + per-request task ids (len B) ->
         (B, S + max_new_tokens) through the routed decode program."""
-        b = int(prompts.shape[0])
-        if len(task_ids) != b:
-            raise ValueError(f"{len(task_ids)} task ids for batch {b}")
-        lora = route_batch(self.store, task_ids, fused=self.fused)
-        rng = rng if rng is not None else jax.random.PRNGKey(0)
-        max_len = max_len or (int(prompts.shape[1])
-                              + self.cfg.max_new_tokens + 8)
-        return self._gen(self.params, lora, prompts, self.cfg, rng=rng,
-                         max_len=max_len)
+        with span("serve.generate"):
+            b = int(prompts.shape[0])
+            if len(task_ids) != b:
+                raise ValueError(f"{len(task_ids)} task ids for batch {b}")
+            with span("serve.route"):
+                lora = route_batch(self.store, task_ids, fused=self.fused)
+            rng = rng if rng is not None else jax.random.PRNGKey(0)
+            max_len = max_len or (int(prompts.shape[1])
+                                  + self.cfg.max_new_tokens + 8)
+            with span("serve.decode"):
+                return self._gen(self.params, lora, prompts, self.cfg,
+                                 rng=rng, max_len=max_len)
 
     def compile_count(self) -> int:
         """Number of compiled decode programs behind this decoder."""

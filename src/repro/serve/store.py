@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common.trace import span
 from repro.common.tree import (TaskVectorLayoutError, TaskVectorSpace,
                                tree_add)
 from repro.core.client import ClientDownlink
@@ -74,7 +75,6 @@ class ModulatorStore:
         self._tau_tree: Optional[PyTree] = None        # fp32 unflatten cache
         self.hits = 0
         self.misses = 0
-        self.materializations = 0
 
     # -- ingest ---------------------------------------------------------
     def ingest(self, downlink: ClientDownlink,
@@ -170,11 +170,12 @@ class ModulatorStore:
             self._lru.move_to_end(t)
             return self._lru[t]
         self.misses += 1
-        self.materializations += 1
-        adapter = tree_add(self.lora0, self.space.unflatten(self.delta(t)))
-        self._lru[t] = adapter
-        while len(self._lru) > self.capacity:
-            self._lru.popitem(last=False)
+        with span("serve.rebuild"):
+            adapter = tree_add(self.lora0,
+                               self.space.unflatten(self.delta(t)))
+            self._lru[t] = adapter
+            while len(self._lru) > self.capacity:
+                self._lru.popitem(last=False)
         return adapter
 
     def cached_task_ids(self) -> List[int]:
@@ -214,5 +215,4 @@ class ModulatorStore:
             "lru_capacity": self.capacity,
             "lru_hits": self.hits,
             "lru_misses": self.misses,
-            "materializations": self.materializations,
         }

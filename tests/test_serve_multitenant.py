@@ -223,7 +223,7 @@ def test_store_lru_eviction_and_rebuild():
     store.adapter(0)
     a0_again = store.adapter(1)                  # rebuilt after eviction
     rebuilt = store.adapter(1)
-    assert store.materializations == 4 and store.hits == 3
+    assert store.misses == 4 and store.hits == 3
     for l1, l2 in zip(jax.tree_util.tree_leaves(a0_again),
                       jax.tree_util.tree_leaves(rebuilt)):
         np.testing.assert_array_equal(np.asarray(l1), np.asarray(l2))

@@ -23,7 +23,8 @@ benchmark's per-layer metrics (``benchmarks/chip/metrics``):
   ``round.decode`` and ``round.h2d``), ``round.meta`` (the chunked
   round's metadata pass and scalar folds), ``round.dispatch`` (each
   jitted engine call), ``round.wait`` (the chunked round's per-chunk
-  block) and ``round.assemble`` (``_assemble_downlinks``; its child
+  block) and ``round.assemble`` (``_assemble_downlinks``; its
+  children ``round.dispatch``, one per distinct task count, and
   ``round.encode``);
 * ``serve.generate``: ``MultiTenantDecoder.generate``; children
   ``serve.route`` (``route_batch``, with ``serve.rebuild`` once per LRU

@@ -137,9 +137,11 @@ round r+1's uploads.  The contract:
   per-slot tensors, and everything in the ``pipeline=False`` path —
   need no discipline: they are never reused.
 * **block_until_ready** — the ONLY sync points are the per-round drain
-  (block on round r−1's outputs before encoding its downlinks) and
-  the implicit ``np.asarray`` of downlink tensors inside
-  ``downlinks``.  Dispatch order on a single device serialises the
+  (block on round r−1's outputs before encoding its downlinks) and,
+  with ``code_masks``, the ``np.asarray`` of the downlink mask words
+  the host encoder reads.  ``downlinks`` itself only dispatches: one
+  jitted split per distinct task count hands every client its rows as
+  device arrays.  Dispatch order on a single device serialises the
   steps, so draining r−1 after dispatching r leaves the device busy
   throughout.
 * **escape hatch** — ``pipeline=False`` runs pack → block → downlink
@@ -726,18 +728,71 @@ def _round_impl(unified, slot_masks, slot_lams, slot_sizes, slot_valid,
                          out_specs=out_specs, check_vma=False)(*operands)
 
 
+def _split_rows_impl(down_unified, down_masks, down_lams, rows, *, k: int):
+    """Rows ``rows`` of the batched downlink tensors, one client each:
+    ``(unified (d,), mask rows (k, ·) or None, λ (k,))`` per row.  The
+    outputs are copies of the same elements, nothing is recomputed, so
+    they are bit-identical to eager per-row slicing.  ``rows`` is
+    traced: one program serves every group of clients holding ``k``
+    tasks, whichever rows they sit in.  ``down_masks=None`` (the coded
+    branch, which streams its masks from the host) leaves the mask
+    rows out."""
+    def row(x, r, n=None):
+        x = jax.lax.dynamic_index_in_dim(x, r, 0, keepdims=False)
+        return x if n is None else jax.lax.slice_in_dim(x, 0, n)
+
+    return tuple((row(down_unified, rows[j]),
+                  None if down_masks is None else row(down_masks, rows[j], k),
+                  row(down_lams, rows[j], k))
+                 for j in range(rows.shape[0]))
+
+
+# Module-level (not a closure) so tests can monkeypatch it, like the
+# engine's other jit bodies.  Its compile key is (k, len(rows), tensor
+# shapes / shardings): never the round's task-count mix.
+_split_downlinks = jax.jit(_split_rows_impl, static_argnames="k")
+
+
 def _assemble_downlinks(client_ids: List[int], task_ids: List[List[int]],
                         d: int, down_unified, down_masks, down_lams, *,
                         code_masks: bool = False,
                         phase_us: Optional[Dict[str, float]] = None
                         ) -> Dict[int, ClientDownlink]:
-    """Slice batched downlink tensors back to ragged per-client
+    """Split batched downlink tensors back to ragged per-client
     ClientDownlinks — the shared back half of ``RoundEngine.downlinks``
-    and each ``round_chunked`` phase-C chunk.  With ``code_masks`` the
-    mask rows of ALL the given clients are entropy-coded in one batched
-    call and split back by per-row record sizes (records self-delimit,
-    so each slice is byte-identical to encoding that client alone)."""
+    and each ``round_chunked`` phase-C chunk.  Row i of the tensors
+    belongs to ``client_ids[i]``.
+
+    One call dispatches one jitted split (``_split_downlinks``) per
+    distinct task count k among the clients: the rows of the clients
+    holding k tasks go in as a traced index vector padded to a power of
+    two (the padded outputs are dropped here).  So a uniform-K round is
+    ONE dispatch for any n, and the compiled programs number at most
+    k_max × (log2 n_max + 1) per tensor shape, whatever the mix of task
+    counts round by round.  Nothing blocks on the device and no host
+    copy of the batched tensors is made: every field stays a device
+    ``jax.Array`` (with a mesh, ``unified`` keeps its taskvec sharding).
+
+    With ``code_masks`` the mask rows of ALL the given clients are
+    entropy-coded in one batched call and split back by per-row record
+    sizes (records self-delimit, so each slice is byte-identical to
+    encoding that client alone); the split then hands out only the
+    unified vectors and λs."""
     with span("round.assemble"):
+        ks = [len(t) for t in task_ids]
+        groups: Dict[int, List[int]] = {}
+        for i, k in enumerate(ks):
+            groups.setdefault(k, []).append(i)
+        parts: List[tuple] = [()] * len(ks)
+        for k, idx in groups.items():
+            rows = np.full(_round_up_pow2(len(idx)), idx[0], np.int32)
+            rows[:len(idx)] = idx
+            with span("round.dispatch"):
+                got = _split_downlinks(down_unified,
+                                       None if code_masks else down_masks,
+                                       down_lams, rows, k=k)
+            for i, part in zip(idx, got):
+                parts[i] = part
         streams: Optional[List[jax.Array]] = None
         if code_masks:
             from repro.fed.compression import encode_mask_rows_with_sizes
@@ -745,7 +800,6 @@ def _assemble_downlinks(client_ids: List[int], task_ids: List[List[int]],
                 dm = np.asarray(down_masks)
                 if dm.dtype != np.uint32:     # bool A/B layout
                     dm = bitpack.pack_bits_np(dm)
-                ks = [len(t) for t in task_ids]
                 rows = dm[np.repeat(np.arange(len(ks)), ks),
                           np.concatenate([np.arange(k, dtype=np.int64)
                                           for k in ks])]
@@ -758,10 +812,9 @@ def _assemble_downlinks(client_ids: List[int], task_ids: List[List[int]],
                     b0, r0 = b1, r0 + k
         result: Dict[int, ClientDownlink] = {}
         for i, cid in enumerate(client_ids):
-            k = len(task_ids[i])
-            rows_i = streams[i] if code_masks else down_masks[i, :k]
-            result[cid] = ClientDownlink(down_unified[i], rows_i,
-                                         down_lams[i, :k])
+            uni, mask_rows, lams = parts[i]
+            result[cid] = ClientDownlink(
+                uni, streams[i] if code_masks else mask_rows, lams)
         return result
 
 
@@ -964,9 +1017,12 @@ class RoundEngine:
                   code_masks: bool = False,
                   phase_us: Optional[Dict[str, float]] = None
                   ) -> Dict[int, ClientDownlink]:
-        """Slice the batched downlink tensors back to ragged per-client
-        ClientDownlinks (views, no compute).  Mask rows stay in the
-        packed wire format; clients unpack on use (``modulate``).
+        """Split the batched downlink tensors back to ragged per-client
+        ClientDownlinks: one jitted split per distinct task count, which
+        copies each client's rows on the device and returns them as
+        device arrays (see ``_assemble_downlinks``; no host copy, no
+        block).  Mask rows stay in the packed wire format; clients
+        unpack on use (``modulate``).
 
         With ``code_masks`` every client's mask rows are entropy-coded
         at this host edge in ONE batched ``encode_mask_rows_with_sizes``

@@ -7,10 +7,12 @@
 * the round and serving paths open the spans the chip benchmark's
   per-layer metrics read, read back here from a ``jax.profiler`` trace
   recorded on the CPU (its ``/host:CPU`` plane carries the ``repro.*``
-  events): one ``round`` / ``round.pack`` / ``round.dispatch`` /
-  ``round.assemble`` per monolithic round, one ``round.pack`` /
-  ``round.wait`` / ``round.assemble`` per chunk of ``round_chunked``,
-  and one ``serve.rebuild`` per LRU miss of the modulator store.
+  events): one ``round`` / ``round.pack`` / ``round.assemble`` per
+  monolithic round, a ``round.dispatch`` for the round's jit and one
+  per downlink split (one split per distinct task count), one
+  ``round.pack`` / ``round.wait`` / ``round.assemble`` per chunk of
+  ``round_chunked``, and one ``serve.rebuild`` per LRU miss of the
+  modulator store.
 """
 
 import collections
@@ -112,8 +114,10 @@ def test_round_spans(tmp_path, coded):
     ups = _uploads(5, 300, coded=coded)
     eng.round(ups, code_masks=coded)                 # compile outside
     got = _traced_spans(lambda: eng.round(ups, code_masks=coded), tmp_path)
+    # the round's jit, then one downlink split per distinct task count
+    splits = len({len(u.task_ids) for u in ups})
     want = {"round": 1, "round.pack": 1, "round.h2d": 1,
-            "round.dispatch": 1, "round.assemble": 1}
+            "round.dispatch": 1 + splits, "round.assemble": 1}
     if coded:
         want.update({"round.decode": 1, "round.encode": 1})
     assert dict(got) == want
@@ -130,7 +134,10 @@ def test_round_chunked_spans(tmp_path):
     assert got["round.pack"] == got["round.h2d"] == 3
     assert got["round.wait"] == got["round.assemble"] == 3
     assert got["round.meta"] == 2                    # pass 0, phase A
-    assert got["round.dispatch"] == 3 + 1 + 3        # merge, finish, down
+    # merge, finish, down; then the splits of each chunk's downlinks
+    splits = sum(len({len(u.task_ids) for u in ups[c:c + 2]})
+                 for c in range(0, len(ups), 2))
+    assert got["round.dispatch"] == 3 + 1 + 3 + splits
 
 
 def test_generate_rebuild_spans_count_misses(tmp_path):

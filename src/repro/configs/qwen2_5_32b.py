@@ -1,4 +1,4 @@
-"""qwen2.5-32b — dense GQA with QKV bias [hf:Qwen/Qwen2.5-0.5B family]."""
+"""qwen2.5-32b — dense GQA with QKV bias, untied head [hf:Qwen/Qwen2.5-32B]."""
 
 from repro.configs.base import ArchConfig
 
@@ -11,7 +11,7 @@ CONFIG = ArchConfig(
     n_kv_heads=8,
     d_ff=27648,
     vocab=152064,
-    source="hf:Qwen/Qwen2.5-0.5B",
+    source="hf:Qwen/Qwen2.5-32B",
     qkv_bias=True,
     rope_base=1_000_000.0,
 )

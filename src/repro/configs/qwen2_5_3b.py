@@ -1,4 +1,4 @@
-"""qwen2.5-3b — dense GQA with QKV bias [hf:Qwen/Qwen2.5-0.5B family]."""
+"""qwen2.5-3b — dense GQA with QKV bias [hf:Qwen/Qwen2.5-3B]."""
 
 from repro.configs.base import ArchConfig
 
@@ -11,7 +11,7 @@ CONFIG = ArchConfig(
     n_kv_heads=2,
     d_ff=11008,
     vocab=151936,
-    source="hf:Qwen/Qwen2.5-0.5B",
+    source="hf:Qwen/Qwen2.5-3B",
     qkv_bias=True,
     rope_base=1_000_000.0,
 )

@@ -137,9 +137,13 @@ round r+1's uploads.  The contract:
   per-slot tensors, and everything in the ``pipeline=False`` path —
   need no discipline: they are never reused.
 * **block_until_ready** — the ONLY sync points are the per-round drain
-  (block on round r−1's outputs before encoding its downlinks) and,
+  (block on round r−1's outputs before encoding its downlinks),
   with ``code_masks``, the ``np.asarray`` of the downlink mask words
-  the host encoder reads.  ``downlinks`` itself only dispatches: one
+  the host encoder reads, and, on a mesh whose padding leaves
+  d_pad ≠ d, each output of ``run_packed`` over ``SLICE_BLOCK_BYTES``
+  (``_slice_outputs``: so a round that large runs to its end inside
+  ``run_packed``, and pipelined rounds of it do not overlap).
+  ``downlinks`` itself only dispatches: one
   jitted split per distinct task count hands every client its rows as
   device arrays.  Dispatch order on a single device serialises the
   steps, so draining r−1 after dispatching r leaves the device busy
@@ -184,7 +188,9 @@ host devices on the CI debug mesh):
   never split mid-word, the wire layout stays the single source of
   truth) and one λ reduction block (``ref.LAMBDA_BLOCK``).  Padded
   coords carry zero masks/vectors and drop out of every reduction;
-  outputs are sliced back to d.
+  outputs are sliced back to d (outside the round program, one at a
+  time: every shard's padding sits at its end, so each slice moves
+  data between the devices, as collective-permutes).
 * **collectives** — ``_round_impl`` runs ``ops.matu_round_slots`` /
   ``_packed`` under ``shard_map``; per-coordinate math (Eq. 3, 4, 6, 7
   and the downlink re-unification) never crosses shards.  Exactly two
@@ -1003,7 +1009,8 @@ class RoundEngine:
         with span("round.dispatch"):
             out = self._impl(mode, packed.d)(*args)
         if d_pad != packed.d:
-            out = _slice_outputs(out, packed.d, packed.packed)
+            out = list(out)
+            _slice_outputs(out, packed.d, packed.packed)
         if packed.packed:
             (tv, tau, a_num, n_held, sim, du, dm, dl) = out
             return EngineOutput(tv, tau, sim, du, dm, dl,
@@ -1371,19 +1378,59 @@ class RoundEngine:
                                phase_us=phase), out, phase)
 
 
-def _slice_outputs(out: tuple, d: int, packed: bool) -> tuple:
+# Most bytes of a sharded output sliced back to d in one step: the
+# slice sends each shard's tail to the next device, and its send and
+# receive buffers grow with the rows it takes (the (30, 16.8 M) fp32
+# shards of qwen2.5-32b's task vectors need 2.95 GB of them at once, one
+# row 0.09 GB; a chip holding that round cannot reserve the former).
+# An output past it is also finished before the next one is sliced.
+SLICE_BLOCK_BYTES = 1 << 27
+
+
+@functools.partial(jax.jit, static_argnames=("d", "rows", "sharding"))
+def _cut(x: jax.Array, *, d: int, rows: int, sharding) -> jax.Array:
+    """``x[..., :d]`` of a sharded output, ``rows`` leading rows per
+    step of a ``fori_loop``, each step written in place into the
+    output: the rows first, on each device alone, then their columns
+    (one slice of both would move every row's tail at once).  The last
+    step's block is moved back to end at the last row, so it may
+    rewrite rows already written, with the same values."""
+    n = x.shape[0]
+    out = jax.lax.with_sharding_constraint(
+        jnp.zeros(x.shape[:-1] + (d,), x.dtype), sharding)
+
+    def step(i, out):
+        r0 = jnp.minimum(i * rows, n - rows)
+        block = jax.lax.dynamic_slice_in_dim(x, r0, rows, axis=0)
+        block = jax.lax.slice_in_dim(block, 0, d, axis=-1)
+        return jax.lax.dynamic_update_slice_in_dim(out, block, r0, axis=0)
+
+    return jax.lax.fori_loop(0, -(-n // rows), step, out)
+
+
+def _slice_outputs(out: list, d: int, packed: bool) -> None:
     """Slice a sharded round's padded d-axis outputs back to the true
-    feature count (mask words to ceil(d/32) — padded coords carry zero
-    bits, so the wire tail-bit convention holds).  Dispatched outside
-    the round jit, on the already-sharded device buffers."""
+    feature count, in place (mask words to ceil(d/32) — padded coords
+    carry zero bits, so the wire tail-bit convention holds).
+    Dispatched outside the round jit, on the already-sharded device
+    buffers, one program per output.  Each slice moves data between
+    shards (every shard's padding sits at its end), in blocks of rows
+    of at most ``SLICE_BLOCK_BYTES``; an output past that is finished
+    and its padded original dropped before the next is sliced, so a
+    chip holds one padded output and its slice besides the rest, never
+    every padded output and every slice at once."""
     dw = bitpack.packed_width(d)
-    if packed:
-        (tv, tau, a_num, n_held, sim, du, dm, dl) = out
-        return (tv[:, :d], tau[:, :d], a_num[:, :d], n_held, sim,
-                du[:, :d], dm[:, :, :dw], dl)
-    (tv, tau, m_hats, sim, du, dm, dl) = out
-    return (tv[:, :d], tau[:, :d], m_hats[:, :d], sim,
-            du[:, :d], dm[:, :, :d], dl)
+    vectors = (0, 1, 2, 5) if packed else (0, 1, 2, 4)
+    words = 6 if packed else 5
+    for i in vectors + (words,):
+        x = out[i]
+        n = x.shape[0]
+        rows = max(1, min(n, SLICE_BLOCK_BYTES * n // x.nbytes))
+        out[i] = _cut(x, d=dw if i == words and packed else d, rows=rows,
+                      sharding=x.sharding)
+        if x.nbytes > SLICE_BLOCK_BYTES:
+            del x
+            jax.block_until_ready(out[i])
 
 
 # -- batched client-side unification ----------------------------------------

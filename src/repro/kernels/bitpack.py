@@ -124,24 +124,38 @@ def unpack_tile(words: jax.Array, dtype=jnp.float32) -> jax.Array:
 
 
 def pack_tile(bits: jax.Array) -> jax.Array:
-    """(R, D) bool/{0,1} -> (R, D/128, 4) uint32 tile pack for Pallas
-    kernel bodies (D must be a multiple of 128).
+    """(R, D) bool/{0,1} -> (R, D/32) uint32 tile pack for Pallas kernel
+    bodies, the wire row's words along the lanes (D must be a multiple
+    of 4096, so each row's words fill whole 128-lane rows).
 
-    Row r's D/32 words come out in groups of four: word ``4·i + q`` is
-    at ``[r, i, q]``, so a row-major reshape to (R, D/32) is the wire
-    row.  Mosaic cannot split the lane axis into 32-bit groups, so each
-    128-lane row is reduced one 32-lane quarter at a time — an int32 sum
-    of distinct powers of two, which is their bitwise OR (bit 31 wraps
-    to the sign bit and is bitcast back)."""
+    Mosaic cannot split the lane axis into 32-bit groups, so each
+    128-lane row is reduced one 32-lane quarter at a time — an int32
+    sum of distinct powers of two, which is their bitwise OR (bit 31
+    wraps to the sign bit and is bitcast back).  That leaves four words
+    per 128-lane row; each group of 32 such rows is then spread onto
+    one row of 128 lanes — word ``4·k + q`` to lane ``4·k + q`` by a
+    select and a sum over the rows, exact since each lane receives one
+    word.  (Four words a row written out as they are would make an
+    array whose minor axis of 4 XLA pads to 128 lanes in HBM, 32x the
+    words' bytes.)"""
     r, dd = bits.shape
-    rows = dd // 128
-    lane = jax.lax.broadcasted_iota(jnp.int32, (r * rows, 128), 1)
-    shifted = jnp.where(bits.reshape(r * rows, 128) != 0,
+    rows = r * (dd // 128)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, 128), 1)
+    shifted = jnp.where(bits.reshape(rows, 128) != 0,
                         jnp.left_shift(jnp.int32(1), lane % WORD_BITS), 0)
-    words = jnp.concatenate(
-        [jnp.sum(shifted[:, q * WORD_BITS:(q + 1) * WORD_BITS], axis=-1,
-                 keepdims=True, dtype=jnp.int32) for q in range(4)], axis=-1)
-    return jax.lax.bitcast_convert_type(words, jnp.uint32).reshape(r, rows, 4)
+    quarters = [jnp.sum(shifted[:, q * WORD_BITS:(q + 1) * WORD_BITS],
+                        axis=-1, keepdims=True, dtype=jnp.int32)
+                for q in range(4)]                  # (R·D/128, 1) each
+    k = jax.lax.broadcasted_iota(jnp.int32, (32, 128), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (32, 128), 1)
+    out = []
+    for g in range(r * dd // 4096):                 # 32 rows -> 128 words
+        spread = sum(jnp.where(lane == 4 * k + q,
+                               quarters[q][32 * g:32 * (g + 1)], 0)
+                     for q in range(4))
+        out.append(jnp.sum(spread, axis=0, keepdims=True))
+    words = jnp.concatenate(out, axis=0).reshape(r, dd // WORD_BITS)
+    return jax.lax.bitcast_convert_type(words, jnp.uint32)
 
 
 def row_words(words: jax.Array, rows: int, cols: int) -> jax.Array:
@@ -222,11 +236,45 @@ def slice_bits(words: jax.Array, start: int, length: int) -> jax.Array:
     return out
 
 
+# Largest (..., d) uint32 bit array ``sign_bits`` packs in one piece.
+# XLA on a TPU lays the (..., d/32, 32) reshape of ``pack_bits`` out
+# with 128 lanes, so one pack holds several times this much: at 16.8 M
+# coords a row, the 30-32 rows of one taskvec shard of qwen2.5-32b's
+# round would hold 8 GB.  qwen2-0.5b's round (459 MB) packs whole.
+PACK_BLOCK_BYTES = 1 << 30
+# size of each column block once a pack is split
+PACK_PIECE_BYTES = 1 << 26
+
+
+def sign_bits(x: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """``(pack_bits(x > 0), pack_bits(x < 0))`` over the last axis, in
+    word-aligned column blocks, one block per step of a ``fori_loop``:
+    one block up to ``PACK_BLOCK_BYTES`` of bits (or when d is not a
+    whole number of words), past it blocks of at most
+    ``PACK_PIECE_BYTES``; packing is exact, so the words are the same."""
+    d = x.shape[-1]
+    size = int(np.prod(x.shape[:-1])) * d * 4
+    n_blocks = 1
+    while (size > PACK_BLOCK_BYTES and size > PACK_PIECE_BYTES * n_blocks
+           and d % (WORD_BITS * n_blocks * 2) == 0):
+        n_blocks *= 2
+    block = d // n_blocks
+    words = jnp.zeros(x.shape[:-1] + (packed_width(d),), jnp.uint32)
+
+    def step(i, planes):
+        xs = jax.lax.dynamic_slice_in_dim(x, i * block, block, axis=-1)
+        at = i * packed_width(block)
+        return tuple(jax.lax.dynamic_update_slice_in_dim(
+            p, pack_bits(b), at, axis=-1)
+            for p, b in zip(planes, (xs > 0, xs < 0)))
+
+    return jax.lax.fori_loop(0, n_blocks, step, (words, words))
+
+
 def sign_planes(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Pack ``sgn(x)`` over the last axis into (pos, nz) bit-planes:
     ``pos`` has bit j set iff x_j > 0, ``nz`` iff x_j != 0."""
-    pos = pack_bits(x > 0)
-    neg = pack_bits(x < 0)
+    pos, neg = sign_bits(x)
     return pos, pos | neg
 
 

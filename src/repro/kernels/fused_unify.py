@@ -28,6 +28,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import bitpack
 
@@ -35,32 +36,40 @@ BLOCK_D = 2048
 BLOCK_D_PACKED = 4096       # 128 uint32 words per tile (one lane tile)
 
 
-def _unify_tile(tv_ref, valid_ref, num_ref, den_ref):
-    """Shared tile body: returns (τ (1, BD), mask (K, BD) fp32 {0,1})
-    and folds this tile's λ partial sums into the (K, 1) num/den
-    blocks.  Every operand is 2-D (K, ·) or (·, 1), the layouts Mosaic
-    tiles without moving data between lanes and sublanes."""
-    x = tv_ref[0].astype(jnp.float32)               # (K, BD)
-    v = valid_ref[0]                                # (K, 1) fp32 {0,1}
+def _unify_tile(x, v):
+    """Shared tile body over one client's (K, BD) task-vector tile and
+    its (K, 1) fp32 {0,1} slot validity: returns (τ (1, BD), mask
+    (K, BD) fp32 {0,1}, and this tile's λ partial sums num, den (K, 1)).
+    Every operand is 2-D (K, ·) or (·, 1), the layouts Mosaic tiles
+    without moving data between lanes and sublanes."""
+    x = x.astype(jnp.float32)                       # (K, BD)
     xm = x * v
     sigma = jnp.sign(jnp.sum(xm, axis=0, keepdims=True))
     aligned = (xm * sigma) > 0.0
     mu = jnp.max(jnp.where(aligned, jnp.abs(xm), 0.0), axis=0, keepdims=True)
     tau = sigma * mu
     mask = ((x * tau) > 0.0).astype(jnp.float32) * v
+    num = jnp.sum(jnp.abs(xm), axis=1, keepdims=True)
+    den = jnp.sum(mask * jnp.abs(tau), axis=1, keepdims=True)
+    return tau, mask, num, den
 
+
+def _accumulate(num_ref, den_ref, num, den):
+    """Fold one tile's λ partial sums into the client's (1, K, 1)
+    num/den blocks, revisited across the d sweep (grid axis 1): zeroed
+    on the first step, accumulated after."""
     @pl.when(pl.program_id(1) == 0)
     def _init():
         num_ref[...] = jnp.zeros_like(num_ref)
         den_ref[...] = jnp.zeros_like(den_ref)
 
-    num_ref[0] += jnp.sum(jnp.abs(xm), axis=1, keepdims=True)
-    den_ref[0] += jnp.sum(mask * jnp.abs(tau), axis=1, keepdims=True)
-    return tau, mask
+    num_ref[0] += num
+    den_ref[0] += den
 
 
 def _fused_unify_kernel(tv_ref, valid_ref, uni_ref, mask_ref, num_ref, den_ref):
-    tau, mask = _unify_tile(tv_ref, valid_ref, num_ref, den_ref)
+    tau, mask, num, den = _unify_tile(tv_ref[0], valid_ref[0])
+    _accumulate(num_ref, den_ref, num, den)
     uni_ref[0] = tau.astype(uni_ref.dtype)
     mask_ref[0] = mask.astype(mask_ref.dtype)
 
@@ -69,7 +78,32 @@ def _fused_unify_packed_kernel(tv_ref, valid_ref, uni_ref, mask_ref,
                                num_ref, den_ref):
     # mask bits decided on the fp32 tau BEFORE the bf16 rounding of the
     # emitted unified vector — bit-identical to the bool/fp32 kernel
-    tau, mask = _unify_tile(tv_ref, valid_ref, num_ref, den_ref)
+    tau, mask, num, den = _unify_tile(tv_ref[0], valid_ref[0])
+    _accumulate(num_ref, den_ref, num, den)
+    uni_ref[0] = tau.astype(uni_ref.dtype)
+    mask_ref[0] = bitpack.pack_tile(mask)
+
+
+def _fused_unify_rows_kernel(tids_ref, tv_ref, valid_ref, uni_ref, mask_ref,
+                             num_ref, den_ref, *, k):
+    """Packed kernel reading each client's slot rows out of the (T, BD)
+    task-vector tile of its d-block; grid (d/BD, B), clients innermost,
+    so each tile is read from HBM once for all clients.  valid, num and
+    den are whole-array blocks, resident for the whole grid; client i's
+    λ sums accumulate over the d-blocks in the same order as the
+    (B, K, d) kernel's."""
+    i = pl.program_id(1)
+    x = jnp.concatenate([tv_ref[pl.ds(tids_ref[i * k + s], 1), :]
+                         for s in range(k)], axis=0)        # (K, BD)
+    tau, mask, num, den = _unify_tile(x, valid_ref[i])
+
+    @pl.when(pl.program_id(0) == 0)
+    def _init():
+        num_ref[i] = jnp.zeros_like(num)
+        den_ref[i] = jnp.zeros_like(den)
+
+    num_ref[i] += num
+    den_ref[i] += den
     uni_ref[0] = tau.astype(uni_ref.dtype)
     mask_ref[0] = bitpack.pack_tile(mask)
 
@@ -77,9 +111,10 @@ def _fused_unify_packed_kernel(tv_ref, valid_ref, uni_ref, mask_ref,
 def _fused_unify_call(task_vectors, valid, *, block_d, interpret, packed):
     """Grid (B, d/BD) launch shared by both variants.  Per-client rows
     carry a unit axis — unified (B, 1, dp), valid/num/den (B, K, 1) — and
-    packed words are emitted as (B, K, dp/128, 4) (see
-    ``bitpack.pack_tile``), so the last two block dims always equal the
-    array's or tile (8, 128).  Returns the padded-d outputs."""
+    packed words are emitted along the lanes, (B, K, dp/32) (see
+    ``bitpack.pack_tile``; ``block_d`` a multiple of 4096), so the last
+    two block dims always equal the array's or tile (8, 128).  Returns
+    the padded-d outputs."""
     b, k, d = task_vectors.shape
     pad = (-d) % block_d
     if pad:
@@ -88,9 +123,9 @@ def _fused_unify_call(task_vectors, valid, *, block_d, interpret, packed):
     if packed:
         kernel, uni_dtype = _fused_unify_packed_kernel, jnp.bfloat16
         name = "fused_unify_packed_pallas"
-        mask_spec = pl.BlockSpec((1, k, block_d // 128, 4),
-                                 lambda i, j: (i, 0, j, 0))
-        mask_out = jax.ShapeDtypeStruct((b, k, dp // 128, 4), jnp.uint32)
+        mask_spec = pl.BlockSpec((1, k, block_d // 32),
+                                 lambda i, j: (i, 0, j))
+        mask_out = jax.ShapeDtypeStruct((b, k, dp // 32), jnp.uint32)
     else:
         kernel, uni_dtype = _fused_unify_kernel, jnp.float32
         name = "fused_unify_pallas"
@@ -158,9 +193,57 @@ def fused_unify_packed_pallas(task_vectors: jax.Array, valid: jax.Array, *,
     4096-wide tiles (vs the bool kernel's 2048) so they match to fp32
     accumulation tolerance, not bitwise, for d > 2048.
     """
-    b, k, d = task_vectors.shape
+    d = task_vectors.shape[-1]
     unified, words, num, den = _fused_unify_call(
         task_vectors, valid, block_d=block_d, interpret=interpret,
         packed=True)
-    words = words.reshape(b, k, -1)[:, :, :bitpack.packed_width(d)]
-    return unified[:, :d], words, num, den
+    return unified[:, :d], words[:, :, :bitpack.packed_width(d)], num, den
+
+
+@functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
+def fused_unify_packed_rows_pallas(task_vectors: jax.Array,
+                                   slot_tasks: jax.Array, valid: jax.Array,
+                                   *, block_d: int = BLOCK_D_PACKED,
+                                   interpret: bool):
+    """:func:`fused_unify_packed_pallas` of the slot stack
+    ``task_vectors[slot_tasks]`` without materialising it: task_vectors
+    (T, d), slot_tasks (B, K) int (clamped to [0, T), as a ``take``
+    with ``mode="clip"``), valid (B, K).  Each client's tile holds the
+    same values as the gathered stack's, so the outputs are
+    bit-identical to gathering first; the kernel reads each slot's row
+    straight from the (T, d) array, and B·K·d·4 bytes of HBM (4.3 GB a
+    chip for one taskvec shard of qwen2.5-32b's round) are never
+    allocated."""
+    b, k = slot_tasks.shape
+    t, d = task_vectors.shape
+    pad = (-d) % block_d
+    if pad:
+        task_vectors = jnp.pad(task_vectors, ((0, 0), (0, pad)))
+    dp = d + pad
+    # valid, num and den: whole-array blocks, resident for the whole grid
+    whole = pl.BlockSpec((b, k, 1), lambda j, i, _: (0, 0, 0))
+    unified, words, num, den = pl.pallas_call(
+        functools.partial(_fused_unify_rows_kernel, k=k),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(dp // block_d, b),
+            in_specs=[pl.BlockSpec((t, block_d), lambda j, i, _: (0, j)),
+                      whole],
+            out_specs=[
+                pl.BlockSpec((1, 1, block_d), lambda j, i, _: (i, 0, j)),
+                pl.BlockSpec((1, k, block_d // 32), lambda j, i, _: (i, 0, j)),
+                whole,
+                whole,
+            ]),
+        out_shape=[
+            jax.ShapeDtypeStruct((b, 1, dp), jnp.bfloat16),
+            jax.ShapeDtypeStruct((b, k, dp // 32), jnp.uint32),
+            jax.ShapeDtypeStruct((b, k, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, k, 1), jnp.float32),
+        ],
+        interpret=interpret,
+        name="fused_unify_packed_rows_pallas",
+    )(jnp.clip(slot_tasks, 0, t - 1).reshape(b * k).astype(jnp.int32),
+      task_vectors, valid.astype(jnp.float32).reshape(b, k, 1))
+    return (unified[:, 0, :d], words[:, :, :bitpack.packed_width(d)],
+            num[:, :, 0], den[:, :, 0])

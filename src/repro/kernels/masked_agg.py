@@ -184,8 +184,7 @@ def masked_agg_batched_packed_pallas(unified: jax.Array, mask_words: jax.Array,
     # (tiny (N, dwp) words) instead of once per task row in-kernel.
     # The comparisons run on the wire dtype directly — bf16 > 0 decides
     # exactly like its fp32 upcast, so no dense fp32 copy is made.
-    pos_w = bitpack.pack_bits(unified > 0.0)
-    neg_w = bitpack.pack_bits(unified < 0.0)
+    pos_w, neg_w = bitpack.sign_bits(unified)
     kernel = functools.partial(_masked_agg_batched_packed_kernel, rho=rho)
     in_specs, out_specs = _batched_specs(n, block_d, nblk, bw)
     plane = pl.BlockSpec((n, bw), lambda i, j: (0, j))

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 
 from repro.kernels import bitpack, ref
 from repro.kernels.fused_unify import (fused_unify_packed_pallas,
+                                       fused_unify_packed_rows_pallas,
                                        fused_unify_pallas)
 from repro.kernels.masked_agg import (masked_agg_batched_packed_pallas,
                                       masked_agg_batched_pallas,
@@ -464,10 +465,10 @@ def _round_slots_dense_packed(unified, slot_mask_words, slot_lams, slot_sizes,
                                     uniform_cross=uniform_cross)
     task_vectors, _tau_tildes = ref.cross_task_combine_ref(tau_hats, m_hats,
                                                            weights)
-    # sentinel slot ids are clamped; the valid mask zeroes their output
-    tvs_slots = jnp.take(task_vectors, slot_tasks, axis=0, mode="clip")
-    uni, dwords, num, den = fused_unify_packed_pallas(
-        tvs_slots, slot_valid, interpret=interp)
+    # each client's slot rows are read straight from the task vectors
+    # (sentinel slot ids clamped; the valid mask zeroes their output)
+    uni, dwords, num, den = fused_unify_packed_rows_pallas(
+        task_vectors, slot_tasks, slot_valid, interpret=interp)
     if axis_name is not None:
         num, den = jax.lax.psum((num, den), axis_name)
     a_u8 = a_num.astype(ref.alpha_dtype(slot_valid.shape[0]))

@@ -21,6 +21,7 @@ except ImportError:
 
 from repro.kernels import bitpack, ref
 from repro.kernels.fused_unify import (fused_unify_packed_pallas,
+                                       fused_unify_packed_rows_pallas,
                                        fused_unify_pallas)
 from repro.kernels.masked_agg import (masked_agg_batched_pallas,
                                       masked_agg_pallas)
@@ -192,6 +193,50 @@ def test_fused_unify_packed_matches_bool(b, k, d):
     u_r, w_r, num_r, den_r = ref.fused_unify_packed_ref(tvs, valid)
     np.testing.assert_array_equal(np.asarray(u_r), np.asarray(u_p))
     np.testing.assert_array_equal(np.asarray(w_r), np.asarray(words))
+
+
+@pytest.mark.parametrize("b,k,t,d", [(8, 2, 6, 5000), (4, 3, 5, 8192),
+                                     (5, 4, 7, 300), (3, 1, 2, 4096)])
+def test_fused_unify_rows_matches_gathered_stack(b, k, t, d):
+    """The kernel that reads each client's slot rows straight from the
+    (T, d) task vectors is bit-identical to gathering the (B, K, d)
+    stack first (sentinel id T clamped, as ``take(mode="clip")``)."""
+    rng = np.random.default_rng(b * 100 + k * 10 + t)
+    tv = jnp.asarray(rng.standard_normal((t, d)), jnp.float32)
+    tv = tv.at[:, ::7].set(0.0)                  # exercise sgn = 0
+    tids = rng.integers(0, t + 1, size=(b, k)).astype(np.int32)
+    valid = jnp.asarray(tids < t)
+    want = fused_unify_packed_pallas(jnp.take(tv, tids, axis=0, mode="clip"),
+                                     valid, interpret=True)
+    got = fused_unify_packed_rows_pallas(tv, jnp.asarray(tids), valid,
+                                         interpret=True)
+    for w, g in zip(want, got):
+        assert w.shape == g.shape and w.dtype == g.dtype
+        np.testing.assert_array_equal(np.asarray(w).view(np.uint8),
+                                      np.asarray(g).view(np.uint8))
+
+
+@pytest.mark.parametrize("r,d", [(1, 4096), (3, 8192), (2, 4096 * 3)])
+def test_pack_tile_is_the_wire_layout(r, d):
+    bits = jax.random.uniform(jax.random.PRNGKey(r + d), (r, d)) > 0.5
+    np.testing.assert_array_equal(np.asarray(bitpack.pack_tile(bits)),
+                                  np.asarray(bitpack.pack_bits(bits)))
+
+
+@pytest.mark.parametrize("shape", [(3, 4096), (2, 5, 2048), (7, 96)])
+def test_sign_bits_in_blocks_equal_one_pack(shape, monkeypatch):
+    """Past ``PACK_BLOCK_BYTES`` the sign planes are packed in column
+    blocks; the words are the same."""
+    x = jax.random.normal(jax.random.PRNGKey(len(shape)), shape)
+    x = jnp.where(jnp.abs(x) < 0.1, 0.0, x).astype(jnp.bfloat16)
+    whole = bitpack.sign_bits(x)
+    monkeypatch.setattr(bitpack, "PACK_BLOCK_BYTES", 64)
+    monkeypatch.setattr(bitpack, "PACK_PIECE_BYTES", 256)
+    blocked = jax.jit(bitpack.sign_bits)(x)
+    for w, g in zip(whole, blocked):
+        np.testing.assert_array_equal(np.asarray(w), np.asarray(g))
+    np.testing.assert_array_equal(np.asarray(whole[0]),
+                                  np.asarray(bitpack.pack_bits(x > 0)))
 
 
 @pytest.mark.parametrize("t,d", [(2, 50), (8, 4096), (16, 2048), (30, 10000)])

@@ -137,6 +137,63 @@ _PARITY = textwrap.dedent("""
 """)
 
 
+_PALLAS = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    os.environ["REPRO_PALLAS_INTERPRET"] = "1"
+    import json
+    import numpy as np, jax, jax.numpy as jnp
+    from repro.core.client import ClientUpload
+    from repro.core import engine
+    from repro.core.engine import EngineConfig, RoundEngine
+    from repro.core.unify import unify_with_modulators
+    from repro.fed.compression import quantize_bf16_transport
+    from repro.kernels import bitpack
+    from repro.launch.mesh import make_round_mesh
+
+    rng = np.random.default_rng(7)
+    n, T, d = 6, 5, 20000      # each of 4 shards pads 5000 to 8192
+    ups = []
+    for cid in range(n):
+        tasks = sorted(rng.choice(T, size=2, replace=False).tolist())
+        tvs = jnp.asarray(rng.standard_normal((2, d)), jnp.float32)
+        uni, masks, lams = unify_with_modulators(tvs)
+        ups.append(ClientUpload(cid, tasks, quantize_bf16_transport(uni),
+                                masks, lams,
+                                rng.integers(10, 200, size=2).tolist()))
+    _, single = RoundEngine(EngineConfig(n_tasks=T)).round(ups)
+    # sign planes packed in column blocks inside every shard, and the
+    # outputs sliced back to d a few rows at a time
+    bitpack.PACK_BLOCK_BYTES, bitpack.PACK_PIECE_BYTES = 1024, 4096
+    engine.SLICE_BLOCK_BYTES = 2 * 32768 * 4
+    shard = RoundEngine(EngineConfig(n_tasks=T), mesh=make_round_mesh(4))
+    downs, out = shard.round(ups)
+    report = {"devices": len(jax.devices())}
+    for f in ("alpha_num", "down_unified", "down_masks", "similarity"):
+        a, b = np.asarray(getattr(single, f)), np.asarray(getattr(out, f))
+        report[f] = bool(a.shape == b.shape and np.array_equal(a, b))
+    for f in ("task_vectors", "down_lams"):
+        a, b = np.asarray(getattr(single, f)), np.asarray(getattr(out, f))
+        report[f] = float(np.max(np.abs(a - b)) / np.max(np.abs(a)))
+    print(json.dumps(report))
+""")
+
+
+def test_sharded_pallas_round_matches_single_device():
+    """The Pallas round under ``shard_map`` (interpreted, 4 devices,
+    d padded per shard, sign planes packed in blocks, outputs sliced
+    back to d in row blocks) hands out what
+    the one-device Pallas round hands out: downlinks, agreement and
+    similarity bit for bit; the (T, d) task vectors and λ to fp32
+    accumulation (the Eq. 7 mixing matmul runs per shard, the λ sums
+    are psum'd over the shards)."""
+    rep = _run_sub(_PALLAS)
+    assert rep.pop("devices") == 4
+    assert rep.pop("task_vectors") < 1e-6
+    assert rep.pop("down_lams") < 1e-5
+    assert all(rep.values()), rep
+
+
 def test_sharded_round_bit_identical_ref():
     """8-way sharded round ≡ single-device round, bit for bit, packed
     and bool layouts, ragged rounds, d % (devices·32) != 0."""

@@ -9,8 +9,10 @@ Interpret-mode tests cannot see any of that.
 Shapes are the real ones: the server round at N = 32 clients, T = 30
 tasks, K = 2 tasks per client, at d = 2^20 and at the task-vector d of
 qwen2-0.5b at its published widths (rank-16 LoRA on mixer/wq,
-mixer/wo, ffn/down: d = 3,588,168); the serving kernel on that
-model's LoRA leaves, fused and dense-routed.
+mixer/wo, ffn/down: d = 3,588,168), and for the row-reading downlink
+kernel also at one chip's shard of qwen2.5-32b's round over four
+chips (16,777,216 coordinates); the serving kernel on qwen2-0.5b's
+LoRA leaves, fused and dense-routed.
 
 The topology is described inside a module fixture, never at import:
 only one process at a time may load the TPU library, and under a
@@ -29,6 +31,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import bitpack
 from repro.kernels.fused_unify import (fused_unify_packed_pallas,
+                                       fused_unify_packed_rows_pallas,
                                        fused_unify_pallas)
 from repro.kernels.masked_agg import (masked_agg_batched_packed_pallas,
                                       masked_agg_batched_pallas)
@@ -95,6 +98,20 @@ def test_fused_unify_packed_compiles(one_chip, d):
     compile_for_chip(
         lambda tv, v: fused_unify_packed_pallas(tv, v, interpret=False),
         one_chip, ((N, K, d), jnp.float32), ((N, K), jnp.bool_))
+
+
+# one chip's taskvec shard of qwen2.5-32b's round over four chips
+# (d = 54,526,144 padded to 4 x 16,777,216)
+ROWS_D_CASES = dict(D_CASES, **{"d=qwen2.5-32b/4": 1 << 24})
+
+
+@pytest.mark.parametrize("d", ROWS_D_CASES.values(), ids=ROWS_D_CASES.keys())
+def test_fused_unify_packed_rows_compiles(one_chip, d):
+    compile_for_chip(
+        lambda tv, tid, v: fused_unify_packed_rows_pallas(tv, tid, v,
+                                                          interpret=False),
+        one_chip, ((T, d), jnp.float32), ((N, K), jnp.int32),
+        ((N, K), jnp.bool_))
 
 
 @pytest.mark.parametrize("d", D_CASES.values(), ids=D_CASES.keys())

@@ -108,10 +108,11 @@ streams.  ``code_masks=False`` (default) keeps the raw packed wire as
 the A/B toggle.
 
 The slot layout keeps the packed footprint and the round's work at
-O(Σ k_n · d) — the same asymptotics as the legacy ragged loop — while
-the dense (N, T, ·) tensors the Pallas kernels and ``matu_round``
-consume are derived on demand (``PackedRound.dense_tensors`` /
-scatter inside the kernel path).
+O(Σ k_n · d) — the same asymptotics as the legacy ragged loop; the
+packed Pallas round reads the slots as they are, and the dense
+(N, T, ·) tensors that ``matu_round`` and the bool kernel path consume
+are derived on demand (``PackedRound.dense_tensors`` / a scatter inside
+the bool kernel path).
 
 The jit cache is keyed on (shape signature, dispatch mode, d); the
 mode is resolved from the environment once per call (see

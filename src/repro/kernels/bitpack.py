@@ -111,16 +111,21 @@ def unpack_bits_np(words: np.ndarray, d: int) -> np.ndarray:
     return bits[..., :d].astype(bool)
 
 
-def unpack_tile(words: jax.Array, dtype=jnp.float32) -> jax.Array:
-    """(R, W) uint32 -> (R, W*32) tile unpack for Pallas kernel bodies:
-    uses ``broadcasted_iota`` (TPU needs ≥2-D iota) and no tail slicing
-    — kernel tiles are always word-aligned.  Bits become values through
-    a select: Mosaic has no uint32 -> float cast."""
+def tile_bits(words: jax.Array) -> jax.Array:
+    """(R, W) uint32 -> (R, W*32) bool tile unpack for Pallas kernel
+    bodies: uses ``broadcasted_iota`` (TPU needs ≥2-D iota) and no tail
+    slicing — kernel tiles are always word-aligned."""
     r, w = words.shape
     iota = jax.lax.broadcasted_iota(jnp.uint32, (1, 1, WORD_BITS), 2)
     bits = (words[:, :, None] >> iota) & jnp.uint32(1)
-    return jnp.where(bits.reshape(r, w * WORD_BITS) != 0,
-                     jnp.ones((), dtype), jnp.zeros((), dtype))
+    return bits.reshape(r, w * WORD_BITS) != 0
+
+
+def unpack_tile(words: jax.Array, dtype=jnp.float32) -> jax.Array:
+    """:func:`tile_bits` as {0, 1} values of ``dtype``, through a
+    select: Mosaic has no uint32 -> float cast."""
+    return jnp.where(tile_bits(words), jnp.ones((), dtype),
+                     jnp.zeros((), dtype))
 
 
 def pack_tile(bits: jax.Array) -> jax.Array:

@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: fused Eq. 3 + Eq. 4 (agreement mask + task merge).
+"""Pallas TPU kernels: fused Eq. 3 + Eq. 4 (agreement mask + task merge).
 
 Per task t the server computes, over the N_t member clients,
   α_j  = |Σ_n sgn(m_n ⊙ τ_n)_j| / N_t
@@ -7,9 +7,19 @@ Per task t the server computes, over the N_t member clients,
 
 A naive composition reads the (N, d) stack three times (sign-sum,
 agreement compare, weighted sum) and materialises two (N, d)
-intermediates in HBM.  The kernel streams each (N, BD) block through
-VMEM once, producing both outputs — HBM traffic drops from ~5·N·d to
-(N+2)·d words.
+intermediates in HBM.  The kernels stream each (N, BD) block through
+VMEM once, producing both outputs.
+
+The round's kernel, :func:`masked_agg_batched_packed_rows_pallas`,
+reads the wire slot layout as uploaded: (N, d) unified vectors and
+(N, K, d/32) mask words, K slots per client, each slot naming its task.
+Its grid runs over d alone.  A step unpacks the words of every slot of
+one d-block once — N·K rows, only the member work — and folds the
+slots into their tasks with a 0/1 slot → task matmul on the MXU, so
+HBM traffic is one read of the unified vectors and the slot words and
+one write of the two (T, d) outputs; no dense (N, T, ·) tensor is
+made.  The bool kernels below keep the dense (N, T, d) layout and
+serve as oracles.
 
 The per-client scalars (λ, γ) are small (N ≤ 64) and ride fully
 resident; ρ is compile-time static.
@@ -26,8 +36,8 @@ from jax.experimental import pallas as pl
 from repro.kernels import bitpack
 
 BLOCK_D = 2048
-# packed kernels tile 32 mask bits per word: 4096 elements = 128 words,
-# exactly one uint32 lane tile
+# the packed kernel tiles 32 mask bits per word: 4096 elements = 128
+# words, exactly one uint32 lane tile
 BLOCK_D_PACKED = 4096
 
 
@@ -71,21 +81,6 @@ def _task_major_scalars(*scalars):
     return [jnp.transpose(x.astype(jnp.float32))[:, :, None] for x in scalars]
 
 
-def _batched_specs(n, block_d, nblk, row_block):
-    """Block specs shared by both batched kernels, grid (T, dp/BD).  The
-    (N, T, ·) mask tensor is viewed as (N, T·w) — a free row-major
-    reshape — so task i's block j is column block ``i·nblk + j`` and
-    the last two block dims are (N, lane tile), never (1, ·)."""
-    scalar = pl.BlockSpec((1, n, 1), lambda i, j: (i, 0, 0))
-    in_specs = [
-        pl.BlockSpec((n, block_d), lambda i, j: (0, j)),
-        pl.BlockSpec((n, row_block), lambda i, j: (0, i * nblk + j)),
-        scalar, scalar, scalar,
-    ]
-    out = pl.BlockSpec((1, 1, block_d), lambda i, j: (i, 0, j))
-    return in_specs, [out, out]
-
-
 @functools.partial(jax.jit, static_argnames=("rho", "block_d", "interpret"))
 def masked_agg_batched_pallas(unified: jax.Array, masks: jax.Array,
                               lams: jax.Array, gammas: jax.Array,
@@ -112,12 +107,18 @@ def masked_agg_batched_pallas(unified: jax.Array, masks: jax.Array,
     dp = d + pad
     nblk = dp // block_d
     kernel = functools.partial(_masked_agg_batched_kernel, rho=rho)
-    in_specs, out_specs = _batched_specs(n, block_d, nblk, block_d)
+    # the (N, T, d) mask tensor is viewed as (N, T·dp), a free row-major
+    # reshape, so task i's block j is column block i·nblk + j and the
+    # last two block dims are (N, lane tile), never (1, ·)
+    scalar = pl.BlockSpec((1, n, 1), lambda i, j: (i, 0, 0))
+    out = pl.BlockSpec((1, 1, block_d), lambda i, j: (i, 0, j))
     tau, m_hat = pl.pallas_call(
         kernel,
         grid=(t, nblk),
-        in_specs=in_specs,
-        out_specs=out_specs,
+        in_specs=[pl.BlockSpec((n, block_d), lambda i, j: (0, j)),
+                  pl.BlockSpec((n, block_d), lambda i, j: (0, i * nblk + j)),
+                  scalar, scalar, scalar],
+        out_specs=[out, out],
         out_shape=[jax.ShapeDtypeStruct((t, 1, dp), jnp.float32)] * 2,
         interpret=interpret,
         name="masked_agg_batched_pallas",
@@ -126,80 +127,115 @@ def masked_agg_batched_pallas(unified: jax.Array, masks: jax.Array,
     return tau[:, 0, :d], m_hat[:, 0, :d]
 
 
-def _masked_agg_batched_packed_kernel(u_ref, mw_ref, pos_ref, neg_ref,
-                                      lam_ref, gam_ref, mem_ref,
-                                      tau_ref, anum_ref, *, rho):
-    u = u_ref[...].astype(jnp.float32)              # (N, BD)
-    w = mw_ref[...]                                 # (N, BW) uint32
-    lam = lam_ref[0]                                # (N, 1)
-    gam = gam_ref[0]
-    mem = mem_ref[0]
-    n_t = jnp.maximum(jnp.sum(mem), 1.0)
-    # sgn(m ⊙ τ_n) via word-wide ANDs against τ_n's sign bit-planes
-    # (packed ONCE per d-block outside the kernel — every task row of
-    # the grid reuses them): bit(m & pos) − bit(m & neg); the merge
-    # reuses the same planes — m ⊙ τ = τ·(bit(m&pos) + bit(m&neg))
-    # exactly (τ = 0 contributes 0)
-    sp = bitpack.unpack_tile(w & pos_ref[...])      # (N, BD) f32 {0,1}
-    sn = bitpack.unpack_tile(w & neg_ref[...])
-    a_num = jnp.abs(jnp.sum(mem * (sp - sn), axis=0, keepdims=True))
-    m_hat = jnp.where(a_num / n_t >= rho, 1.0, a_num / n_t)
-    weighted = jnp.sum((gam * lam) * (u * (sp + sn)), axis=0, keepdims=True)
-    tau_ref[0] = (weighted * m_hat).astype(tau_ref.dtype)
-    anum_ref[0] = a_num.astype(anum_ref.dtype)
+def _split3(x):
+    """fp32 (R, C) -> three bf16 pieces whose sum is ``x`` exactly: each
+    piece keeps the top 8 significant bits of what is left (the low 16
+    bits of a float's word cut away by a mask, so no rounding step can
+    be folded away), and 3 x 8 bits hold an fp32 significand."""
+    def top(v):
+        w = jax.lax.bitcast_convert_type(v, jnp.uint32)
+        return jax.lax.bitcast_convert_type(w & jnp.uint32(0xFFFF0000),
+                                            jnp.float32)
+    hi = top(x)
+    mid = top(x - hi)
+    lo = x - hi - mid
+    return [p.astype(jnp.bfloat16) for p in (hi, mid, lo)]
 
 
-@functools.partial(jax.jit, static_argnames=("rho", "block_d", "interpret"))
-def masked_agg_batched_packed_pallas(unified: jax.Array, mask_words: jax.Array,
-                                     lams: jax.Array, gammas: jax.Array,
-                                     members: jax.Array, *, rho: float = 0.4,
-                                     block_d: int = BLOCK_D_PACKED,
-                                     interpret: bool):
-    """Wire-format twin of :func:`masked_agg_batched_pallas`: the
-    (N, T, d) mask tensor arrives as bit-packed uint32 words
-    (N, T, ceil(d/32)) and is expanded 32-bits-per-word inside VMEM —
-    HBM mask traffic drops 8x vs the bool layout and 32x vs fp32.
-    ``unified`` may be bf16 (the uplink wire dtype); each tile is upcast
-    to fp32 in VMEM.
+def _masked_agg_rows_kernel(u_ref, w_ref, wt_ref, sel_ref, nt_ref,
+                            tau_ref, anum_ref, *, rho):
+    """One d-block of every task.  Each slot row's mask words are
+    unpacked once; its weighted values w·m·u (split into three exact
+    bf16 pieces) and its signed votes m·sgn(u) are folded into their
+    task on the MXU by the 0/1 slot → task selector, fp32 accumulation,
+    products exact."""
+    u = u_ref[...].astype(jnp.float32)                  # (N, BD)
+    sgn = jnp.sign(u)
+    acc = None
+    for k in range(w_ref.shape[0]):
+        bits = bitpack.tile_bits(w_ref[k])              # (N, BD) bool
+        x = jnp.where(bits, wt_ref[k] * u, 0.0)
+        rhs = jnp.concatenate(
+            _split3(x) + [jnp.where(bits, sgn, 0.0).astype(jnp.bfloat16)],
+            axis=0)                                     # (4N, BD)
+        part = jnp.dot(sel_ref[k], rhs, preferred_element_type=jnp.float32)
+        acc = part if acc is None else acc + part      # (2·Tp, BD)
+    t = tau_ref.shape[0]
+    tp = acc.shape[0] // 2
+    a_num = jnp.abs(acc[tp:tp + t])
+    alpha = a_num / nt_ref[:t]
+    m_hat = jnp.where(alpha >= rho, 1.0, alpha)
+    tau_ref[...] = acc[:t] * m_hat
+    anum_ref[...] = a_num
 
-    Instead of m̂ this kernel emits the Eq. 3 agreement *numerator*
-    |Σ_n sgn(m_n ⊙ τ_n)| — an exact small integer (≤ N) from which the
-    caller re-derives m̂ = 1[α ≥ ρ] ∨ α with the identical fp32 division
-    (and can store it at one byte per coordinate).
-    Returns (tau_hats (T, d) fp32, alpha_num (T, d) fp32).
+
+@functools.partial(jax.jit, static_argnames=("n_tasks", "rho", "interpret"))
+def masked_agg_batched_packed_rows_pallas(unified: jax.Array,
+                                          slot_mask_words: jax.Array,
+                                          slot_weights: jax.Array,
+                                          slot_tasks: jax.Array,
+                                          slot_valid: jax.Array, *,
+                                          n_tasks: int, rho: float = 0.4,
+                                          interpret: bool):
+    """Whole-round Eq. 3 + Eq. 4 straight from the wire slot layout.
+
+    unified (N, d) (bf16 on the wire; any float dtype); slot_mask_words
+    (N, K, ceil(d/32)) uint32, LSB-first; slot_weights (N, K) fp32, the
+    slot's γ·λ; slot_tasks (N, K) int (``n_tasks`` is the sentinel of an
+    empty slot); slot_valid (N, K).  Member slots are the valid ones
+    whose task is in range; only they are folded, and N_t counts them.
+
+    Grid (ceil(d/4096),): each step reads one (N, 4096) block of
+    ``unified`` and the (K, N, 128) words of every slot, once, and
+    writes the (T, 4096) blocks of both outputs, once.  The slot → task
+    fold is a (2·Tp, 4N) @ (4N, 4096) matmul per slot column; a task
+    nobody holds has an all-zero selector row, so it gets τ̂ = 0 and a
+    numerator of 0.  HBM traffic is N·d·2 + N·K·d/8 bytes read and
+    2·T·d·4 written; no (N, T, ·) tensor exists.  The last block may
+    run past d: its extra lanes read whatever lies there and are never
+    written back.
+
+    Returns (tau_hats (T, d) fp32, alpha_num (T, d) fp32): the Eq. 3
+    numerator |Σ_n sgn(m_n ⊙ τ_n)| is an exact small integer, from
+    which the caller re-derives m̂ with the same fp32 division.
     """
     n, d = unified.shape
-    t = mask_words.shape[1]
-    pad = (-d) % block_d
-    dp = d + pad
-    dwp = dp // 32
-    if pad:
-        unified = jnp.pad(unified, ((0, 0), (0, pad)))
-    if mask_words.shape[2] != dwp:
-        mask_words = jnp.pad(
-            mask_words, ((0, 0), (0, 0), (0, dwp - mask_words.shape[2])))
-    bw = block_d // 32
-    nblk = dp // block_d
-    # τ_n's sign bit-planes are task-independent: pack them once here
-    # (tiny (N, dwp) words) instead of once per task row in-kernel.
-    # The comparisons run on the wire dtype directly — bf16 > 0 decides
-    # exactly like its fp32 upcast, so no dense fp32 copy is made.
-    pos_w, neg_w = bitpack.sign_bits(unified)
-    kernel = functools.partial(_masked_agg_batched_packed_kernel, rho=rho)
-    in_specs, out_specs = _batched_specs(n, block_d, nblk, bw)
-    plane = pl.BlockSpec((n, bw), lambda i, j: (0, j))
-    in_specs[2:2] = [plane, plane]
-    tau, anum = pl.pallas_call(
-        kernel,
-        grid=(t, nblk),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=[jax.ShapeDtypeStruct((t, 1, dp), jnp.float32)] * 2,
+    k = slot_mask_words.shape[1]
+    t = n_tasks
+    tp = -(-t // 8) * 8             # vote rows start on a sublane tile
+    np_ = -(-n // 16) * 16          # bf16 sublane tile: aligned concat
+    onehot = ((slot_tasks[:, :, None] == jnp.arange(t))
+              & slot_valid[:, :, None])                 # (N, K, T)
+    nt = jnp.ones((tp, 1), jnp.float32).at[:t, 0].set(
+        jnp.maximum(jnp.sum(onehot, axis=(0, 1), dtype=jnp.float32), 1.0))
+    sel = jnp.zeros((k, tp, np_), jnp.bfloat16).at[:, :t, :n].set(
+        jnp.transpose(onehot, (1, 2, 0)).astype(jnp.bfloat16))
+    zero = jnp.zeros_like(sel)
+    # rows [0, Tp): Σ of the three value pieces; rows [Tp, 2Tp): votes
+    sel = jnp.concatenate([jnp.concatenate([sel, sel, sel, zero], axis=2),
+                           jnp.concatenate([zero, zero, zero, sel], axis=2)],
+                          axis=1)                       # (K, 2Tp, 4Np)
+    words = jnp.swapaxes(slot_mask_words, 0, 1)         # (K, N, dw)
+    weights = jnp.swapaxes(slot_weights.astype(jnp.float32), 0, 1)
+    if np_ != n:
+        unified = jnp.pad(unified, ((0, np_ - n), (0, 0)))
+        words = jnp.pad(words, ((0, 0), (0, np_ - n), (0, 0)))
+        weights = jnp.pad(weights, ((0, 0), (0, np_ - n)))
+    weights = weights[:, :, None]                       # (K, Np, 1)
+    bd = BLOCK_D_PACKED
+    whole = lambda a: pl.BlockSpec(a.shape, lambda j: (0,) * a.ndim)  # noqa: E731
+    out = pl.BlockSpec((t, bd), lambda j: (0, j))
+    return pl.pallas_call(
+        functools.partial(_masked_agg_rows_kernel, rho=rho),
+        grid=(pl.cdiv(d, bd),),
+        in_specs=[pl.BlockSpec((np_, bd), lambda j: (0, j)),
+                  pl.BlockSpec((k, np_, bd // 32), lambda j: (0, 0, j)),
+                  whole(weights), whole(sel), whole(nt)],
+        out_specs=[out, out],
+        out_shape=[jax.ShapeDtypeStruct((t, d), jnp.float32)] * 2,
         interpret=interpret,
-        name="masked_agg_batched_packed_pallas",
-    )(unified, mask_words.reshape(n, t * dwp), pos_w, neg_w,
-      *_task_major_scalars(lams, gammas, members))
-    return tau[:, 0, :d], anum[:, 0, :d]
+        name="masked_agg_batched_packed_rows_pallas",
+    )(unified, words, weights, sel, nt)
 
 
 @functools.partial(jax.jit, static_argnames=("rho", "block_d", "interpret"))

@@ -35,7 +35,7 @@ from repro.kernels import bitpack, ref
 from repro.kernels.fused_unify import (fused_unify_packed_pallas,
                                        fused_unify_packed_rows_pallas,
                                        fused_unify_pallas)
-from repro.kernels.masked_agg import (masked_agg_batched_packed_pallas,
+from repro.kernels.masked_agg import (masked_agg_batched_packed_rows_pallas,
                                       masked_agg_batched_pallas,
                                       masked_agg_pallas)
 from repro.kernels.modulated_matmul import (modulated_matmul_pallas,
@@ -176,26 +176,37 @@ def fused_unify_packed(task_vectors: jax.Array, valid: jax.Array, *,
     return uni, words, lams
 
 
-def masked_agg_batched_packed(unified, mask_words, lams, gammas, members,
-                              d: int, *, rho: float = 0.4,
+def masked_agg_batched_packed(unified, slot_mask_words, slot_lams, slot_sizes,
+                              slot_valid, slot_tasks, n_tasks: int, d: int,
+                              *, rho: float = 0.4,
                               mode: Optional[str] = None):
-    """Whole-round Eq. 3 + Eq. 4 over packed (N, T, ceil(d/32)) mask
-    words (+ bf16-capable unified).  Returns (tau_hats, alpha_num) —
-    m̂ is derivable as ``where(alpha_num/max(N_t,1) >= rho, 1, ·)``.
-    The "ref" dispatch unpacks and delegates to the bool oracle
-    (validation path); the Pallas modes expand words in VMEM only."""
+    """Whole-round Eq. 3 + Eq. 4 over the wire slot layout: unified
+    (N, d) bf16 (fp32 tolerated), slot_mask_words (N, K, ceil(d/32))
+    uint32, per-slot scalars (N, K) as :func:`matu_round_slots`.
+    Returns (tau_hats (T, d), alpha_num (T, d)) — m̂ is derivable as
+    ``where(alpha_num/max(N_t,1) >= rho, 1, ·)``.  The "ref" dispatch
+    unpacks, scatters to the dense (N, T, d) layout and delegates to
+    the bool oracle (validation path); the Pallas modes fold each
+    member slot into its task straight from the slot words."""
     mode = _norm(mode)
     if mode == "ref":
-        masks = bitpack.unpack_bits(mask_words, d, jnp.float32)
-        tau, m_hat = ref.masked_agg_batched_ref(
-            unified.astype(jnp.float32), masks, lams, gammas, members, rho)
-        memf = members.astype(jnp.float32)
+        masks, lams, member, sizes = slots_to_dense(
+            bitpack.unpack_bits(slot_mask_words, d), slot_lams, slot_sizes,
+            slot_valid, slot_tasks, n_tasks)
+        masks = masks.astype(jnp.float32)
+        memf = member.astype(jnp.float32)
+        gam = sizes * memf
+        gam = gam / jnp.maximum(jnp.sum(gam, axis=0, keepdims=True), 1e-12)
+        tau, _ = ref.masked_agg_batched_ref(
+            unified.astype(jnp.float32), masks, lams, gam, member, rho)
         sign_u = jnp.sign(unified.astype(jnp.float32))
         a_num = jnp.abs(jnp.einsum("nt,ntd->td", memf,
                                    masks * sign_u[:, None, :]))
         return tau, a_num
-    return masked_agg_batched_packed_pallas(
-        unified, mask_words, lams, gammas, members, rho=rho,
+    return masked_agg_batched_packed_rows_pallas(
+        unified, slot_mask_words,
+        _slot_gammas(slot_sizes, slot_valid, slot_tasks, n_tasks) * slot_lams,
+        slot_tasks, slot_valid, n_tasks=n_tasks, rho=rho,
         interpret=(mode == "pallas_interpret"))
 
 
@@ -267,50 +278,37 @@ def routed_matmul(x: jax.Array, w: jax.Array, *,
     return routed_matmul_pallas(x, w, interpret=(mode == "pallas_interpret"))
 
 
-def _slot_scalars_to_dense(slot_lams, slot_sizes, slot_valid, slot_tasks,
-                           n_tasks: int):
-    """Scatter the per-slot scalars to the dense (N, T) layout (shared
-    by the bool and packed slot→dense contracts)."""
-    n = slot_lams.shape[0]
-    rows = jnp.arange(n)[:, None]
-    lams_d = jnp.zeros((n, n_tasks), jnp.float32).at[rows, slot_tasks].set(
-        jnp.where(slot_valid, slot_lams, 0.0), mode="drop")
-    member_d = jnp.zeros((n, n_tasks), bool).at[rows, slot_tasks].set(
-        slot_valid, mode="drop")
-    sizes_d = jnp.zeros((n, n_tasks), jnp.float32).at[rows, slot_tasks].set(
-        jnp.where(slot_valid, slot_sizes, 0.0), mode="drop")
-    return lams_d, member_d, sizes_d
-
-
 def slots_to_dense(slot_masks, slot_lams, slot_sizes, slot_valid, slot_tasks,
                    n_tasks: int):
     """Scatter slot-packed round tensors to the dense per-task layout
     ((N, T, d) masks, (N, T) lams/member/sizes).  Sentinel task ids
     (== n_tasks) are scatter-dropped.  The single definition of the
-    slot→dense contract — used by the kernel round path and by
+    slot→dense contract — used by the bool kernel round path, the "ref"
+    dispatch of :func:`masked_agg_batched_packed` and
     ``PackedRound.dense_tensors``."""
-    n, k, d = slot_masks.shape
+    n = slot_masks.shape[0]
     rows = jnp.arange(n)[:, None]
-    masks_d = jnp.zeros((n, n_tasks, d), bool).at[rows, slot_tasks].set(
-        jnp.where(slot_valid[:, :, None], slot_masks, False), mode="drop")
-    lams_d, member_d, sizes_d = _slot_scalars_to_dense(
-        slot_lams, slot_sizes, slot_valid, slot_tasks, n_tasks)
-    return masks_d, lams_d, member_d, sizes_d
+
+    def scatter(x, dtype):
+        return jnp.zeros((n, n_tasks) + x.shape[2:], dtype).at[
+            rows, slot_tasks].set(x, mode="drop")
+
+    valid3 = slot_valid[:, :, None]
+    return (scatter(jnp.where(valid3, slot_masks, False), bool),
+            scatter(jnp.where(slot_valid, slot_lams, 0.0), jnp.float32),
+            scatter(slot_valid, bool),
+            scatter(jnp.where(slot_valid, slot_sizes, 0.0), jnp.float32))
 
 
-def slots_to_dense_packed(slot_mask_words, slot_lams, slot_sizes, slot_valid,
-                          slot_tasks, n_tasks: int):
-    """Packed twin of :func:`slots_to_dense`: the mask scatter moves
-    uint32 words, 8x less data than the bool layout."""
-    n, k, dw = slot_mask_words.shape
-    rows = jnp.arange(n)[:, None]
-    words_d = jnp.zeros((n, n_tasks, dw), jnp.uint32).at[
-        rows, slot_tasks].set(
-        jnp.where(slot_valid[:, :, None], slot_mask_words, jnp.uint32(0)),
-        mode="drop")
-    lams_d, member_d, sizes_d = _slot_scalars_to_dense(
-        slot_lams, slot_sizes, slot_valid, slot_tasks, n_tasks)
-    return words_d, lams_d, member_d, sizes_d
+def _slot_gammas(slot_sizes, slot_valid, slot_tasks, n_tasks: int):
+    """Per-slot γ: a valid slot's size over its task's total (0 for
+    invalid slots; the sentinel task id gathers its own total)."""
+    n, k = slot_tasks.shape
+    ids = slot_tasks.reshape(n * k)
+    sizes = slot_sizes.reshape(n * k).astype(jnp.float32) * slot_valid.reshape(
+        n * k)
+    totals = jax.ops.segment_sum(sizes, ids, num_segments=n_tasks + 1)
+    return (sizes / jnp.maximum(totals[ids], 1e-12)).reshape(n, k)
 
 
 def _round_slots_dense(unified, slot_masks, slot_lams, slot_sizes, slot_valid,
@@ -431,24 +429,23 @@ def _round_slots_dense_packed(unified, slot_mask_words, slot_lams, slot_sizes,
                               slot_valid, slot_tasks, n_tasks, d, *, rho, eps,
                               kappa, cross_task, uniform_cross, mode,
                               axis_name=None, d_norm=0):
-    """Packed kernel-path round: scatter the uint32 mask words to the
-    dense (N, T, d/32) layout, then compose the packed batched
-    masked-agg, popcount sign-sim, and packed fused-unify kernels.  The
-    mask tensor stays 1 bit/element in HBM end to end; words are
-    expanded to lanes only inside VMEM tiles.  With ``axis_name`` set
-    this is a ``shard_map`` body on the local d-slice: the popcount
-    dots (exact integers) and the λ num/den partial sums are psum'd."""
-    words_d, lams_d, member_d, sizes_d = slots_to_dense_packed(
-        slot_mask_words, slot_lams, slot_sizes, slot_valid, slot_tasks,
-        n_tasks)
-
-    memf = member_d.astype(jnp.float32)
-    gam = sizes_d * memf
-    gam = gam / jnp.maximum(jnp.sum(gam, axis=0, keepdims=True), 1e-12)
+    """Packed kernel-path round: compose the packed Eq. 3–4 kernel,
+    the popcount sign-sim and the row-reading fused-unify kernels over
+    the wire slot layout.  The Eq. 3–4 kernel reads each d-block of the
+    (N, d) unified vectors and of the (N, K, d/32) slot words once and
+    folds every member slot into its task on the MXU, so no dense
+    per-task mask tensor is made.  The mask stays 1 bit/element in HBM
+    end to end; words are expanded to lanes only inside VMEM tiles.
+    With ``axis_name`` set this is a ``shard_map`` body on the local
+    d-slice: the popcount dots (exact integers) and the λ num/den
+    partial sums are psum'd."""
     interp = (mode == "pallas_interpret")
-    tau_hats, a_num = masked_agg_batched_packed_pallas(
-        unified, words_d, lams_d, gam, member_d, rho=rho, interpret=interp)
-    n_t = jnp.sum(memf, axis=0)
+    tau_hats, a_num = masked_agg_batched_packed(
+        unified, slot_mask_words, slot_lams, slot_sizes, slot_valid,
+        slot_tasks, n_tasks, d, rho=rho, mode=mode)
+    n_t = jax.ops.segment_sum(
+        slot_valid.reshape(-1).astype(jnp.float32), slot_tasks.reshape(-1),
+        num_segments=n_tasks + 1)[:n_tasks]
     held = n_t > 0
     heldf = held.astype(jnp.float32)
     alpha = a_num / jnp.maximum(n_t, 1.0)[:, None]
@@ -494,9 +491,9 @@ def matu_round_slots_packed(unified, slot_mask_words, slot_lams, slot_sizes,
     express it).
 
     "ref" runs the two-pass cache-blocked packed streaming round; the
-    Pallas modes scatter words to the dense packed layout and compose
-    the packed kernels.  Returns (task_vectors fp32, tau_hats fp32,
-    alpha_num uint8, n_held, similarity, down_unified bf16,
+    Pallas modes compose the packed kernels over the slot layout as it
+    is (:func:`_round_slots_dense_packed`).  Returns (task_vectors fp32,
+    tau_hats fp32, alpha_num uint8, n_held, similarity, down_unified bf16,
     down_mask_words uint32, down_lams) — m̂ is re-derivable from
     (alpha_num, n_held, ρ) and never materialised in fp32 on the hot
     path; τ̃ as before is (2τ − τ̂) on rows with donors.

@@ -129,8 +129,10 @@ def test_fused_unify_sweep(b, k, d):
 
 @pytest.mark.parametrize("n,t,d", [(3, 2, 100), (5, 4, 4096), (8, 6, 3333)])
 def test_masked_agg_batched_packed_matches_bool(n, t, d):
-    """Packed-mask kernel ≡ bool kernel: same τ̂, and m̂ re-derived from
-    the emitted agreement numerator matches bit for bit."""
+    """Packed-mask kernel ≡ bool kernel on the K = T slot layout (slot k
+    of every client holds task k, so the slot words are the dense
+    (N, T, d/32) tensor): same τ̂, and m̂ re-derived from the emitted
+    agreement numerator matches bit for bit."""
     key = jax.random.PRNGKey(n * 13 + t * 7 + d)
     k1, k2, k3, k4 = jax.random.split(key, 4)
     u = jax.random.normal(k1, (n, d), jnp.float32)
@@ -140,17 +142,18 @@ def test_masked_agg_batched_packed_matches_bool(n, t, d):
     lam = jax.random.uniform(k4, (n, t)) + 0.5
     sizes = jnp.where(member, 50.0, 0.0)
     gam = sizes / jnp.maximum(jnp.sum(sizes, 0, keepdims=True), 1e-12)
+    tasks = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (n, t))
 
     from repro.kernels import ops
 
     words = bitpack.pack_bits(m)
     # both dispatch modes of the packed op, through the ops contract
     tau_p, anum = ops.masked_agg_batched_packed(
-        u.astype(jnp.bfloat16), words, lam, gam, member, d, rho=0.4,
-        mode="pallas_interpret")
+        u.astype(jnp.bfloat16), words, lam, sizes, member, tasks, t, d,
+        rho=0.4, mode="pallas_interpret")
     tau_r, anum_r = ops.masked_agg_batched_packed(
-        u.astype(jnp.bfloat16), words, lam, gam, member, d, rho=0.4,
-        mode="ref")
+        u.astype(jnp.bfloat16), words, lam, sizes, member, tasks, t, d,
+        rho=0.4, mode="ref")
     np.testing.assert_allclose(tau_p, tau_r, rtol=1e-5, atol=1e-6)
     np.testing.assert_array_equal(np.asarray(anum), np.asarray(anum_r))
     # the bool comparator consumes the identical bf16-quantised values
@@ -166,6 +169,53 @@ def test_masked_agg_batched_packed_matches_bool(n, t, d):
     a = np.asarray(anum)
     np.testing.assert_array_equal(a, np.round(a))
     assert (a <= np.asarray(n_t)[:, None]).all()
+
+
+# (N, K_max, T, d, every client holds K_max tasks, unified dtype);
+# K_max = T is the dense case, T > N·K_max leaves tasks nobody holds
+ROWS_CASES = {
+    "ragged": (5, 2, 6, 5000, False, jnp.bfloat16),
+    "unheld-tasks": (7, 1, 9, 300, False, jnp.bfloat16),
+    "k-max-3": (3, 3, 4, 4096 * 2 + 7, False, jnp.bfloat16),
+    "k-eq-t": (6, 4, 4, 4096, True, jnp.bfloat16),
+    "n-not-pow2": (20, 2, 30, 9000, False, jnp.bfloat16),
+    "n-aligned": (32, 2, 30, 4096 * 2 + 100, True, jnp.bfloat16),
+    "fp32-unified": (9, 3, 5, 4096 + 1, False, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("n,k,t,d,full,dtype", ROWS_CASES.values(),
+                         ids=ROWS_CASES.keys())
+def test_masked_agg_rows_matches_ref(n, k, t, d, full, dtype):
+    """The Eq. 3–4 kernel over the wire slot layout against the dense
+    oracle: clients hold 1..K_max distinct tasks, empty slots carry the
+    sentinel task id T, no words and no weight, and d need not be a
+    multiple of the kernel's block."""
+    from repro.kernels import ops
+
+    rng = np.random.default_rng(n * 1000 + k * 100 + t)
+    tasks = np.full((n, k), t, np.int32)
+    valid = np.zeros((n, k), bool)
+    for i in range(n):
+        kn = k if full else int(rng.integers(1, k + 1))
+        tasks[i, :kn] = rng.permutation(t)[:kn]
+        valid[i, :kn] = True
+    masks = (rng.random((n, k, d)) > 0.5) & valid[:, :, None]
+    u = jnp.asarray(rng.standard_normal((n, d)), dtype)
+    u = u.at[:, ::11].set(0.0)                   # exercise sgn = 0
+    lams = jnp.asarray((rng.random((n, k)) + 0.5) * valid, jnp.float32)
+    sizes = jnp.asarray(rng.integers(1, 100, (n, k)) * valid, jnp.float32)
+    args = (u, bitpack.pack_bits(jnp.asarray(masks)), lams, sizes,
+            jnp.asarray(valid), jnp.asarray(tasks), t, d)
+    tau, anum = ops.masked_agg_batched_packed(*args, mode="pallas_interpret")
+    tau_r, anum_r = ops.masked_agg_batched_packed(*args, mode="ref")
+    assert tau.shape == anum.shape == (t, d)
+    np.testing.assert_array_equal(np.asarray(anum), np.asarray(anum_r))
+    np.testing.assert_allclose(tau, tau_r, rtol=1e-5, atol=1e-6)
+    if not full:
+        unheld = ~np.isin(np.arange(t), tasks[valid])
+        assert not np.asarray(tau)[unheld].any()
+        assert not np.asarray(anum)[unheld].any()
 
 
 @pytest.mark.parametrize("b,k,d", [(2, 1, 64), (4, 3, 2048), (6, 4, 5000)])

@@ -9,10 +9,10 @@ Interpret-mode tests cannot see any of that.
 Shapes are the real ones: the server round at N = 32 clients, T = 30
 tasks, K = 2 tasks per client, at d = 2^20 and at the task-vector d of
 qwen2-0.5b at its published widths (rank-16 LoRA on mixer/wq,
-mixer/wo, ffn/down: d = 3,588,168), and for the row-reading downlink
-kernel also at one chip's shard of qwen2.5-32b's round over four
-chips (16,777,216 coordinates); the serving kernel on qwen2-0.5b's
-LoRA leaves, fused and dense-routed.
+mixer/wo, ffn/down: d = 3,588,168), and for the slot-reading Eq. 3–4
+and downlink kernels also at one chip's shard of qwen2.5-32b's round
+over four chips (16,777,216 coordinates); the serving kernel on
+qwen2-0.5b's LoRA leaves, fused and dense-routed.
 
 The topology is described inside a module fixture, never at import:
 only one process at a time may load the TPU library, and under a
@@ -33,7 +33,7 @@ from repro.kernels import bitpack
 from repro.kernels.fused_unify import (fused_unify_packed_pallas,
                                        fused_unify_packed_rows_pallas,
                                        fused_unify_pallas)
-from repro.kernels.masked_agg import (masked_agg_batched_packed_pallas,
+from repro.kernels.masked_agg import (masked_agg_batched_packed_rows_pallas,
                                       masked_agg_batched_pallas)
 from repro.kernels.modulated_matmul import (modulated_matmul_pallas,
                                             routed_matmul_pallas)
@@ -121,14 +121,14 @@ def test_fused_unify_compiles(one_chip, d):
         one_chip, ((N, K, d), jnp.float32), ((N, K), jnp.bool_))
 
 
-@pytest.mark.parametrize("d", D_CASES.values(), ids=D_CASES.keys())
-def test_masked_agg_batched_packed_compiles(one_chip, d):
+@pytest.mark.parametrize("d", ROWS_D_CASES.values(), ids=ROWS_D_CASES.keys())
+def test_masked_agg_batched_packed_rows_compiles(one_chip, d):
     compile_for_chip(
-        lambda u, w, lam, gam, mem: masked_agg_batched_packed_pallas(
-            u, w, lam, gam, mem, interpret=False),
+        lambda u, w, wt, tid, v: masked_agg_batched_packed_rows_pallas(
+            u, w, wt, tid, v, n_tasks=T, interpret=False),
         one_chip, ((N, d), jnp.bfloat16),
-        ((N, T, bitpack.packed_width(d)), jnp.uint32),
-        ((N, T), jnp.float32), ((N, T), jnp.float32), ((N, T), jnp.bool_))
+        ((N, K, bitpack.packed_width(d)), jnp.uint32),
+        ((N, K), jnp.float32), ((N, K), jnp.int32), ((N, K), jnp.bool_))
 
 
 @pytest.mark.parametrize("d", D_CASES.values(), ids=D_CASES.keys())

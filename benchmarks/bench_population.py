@@ -14,8 +14,7 @@ baseline).
 Client uploads are wire-format twins cycled from a small pre-built
 pool (P distinct bf16 vectors + packed uint32 mask words): the bench
 measures SERVER-side ingest/fold/downlink throughput, so client-side
-RNG is excluded from both the timed region and the memory budget the
-same way bench_round_engine excludes its wire-twin construction.  Task
+RNG is excluded from both the timed region and the memory budget.  Task
 assignment, data sizes, and sampling still come from the lazy split,
 exercised per client per pass (the engine's two-pass contract).
 

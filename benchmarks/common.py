@@ -27,13 +27,10 @@ def timed(fn, *args, **kw):
 def save_detail(name: str, detail: Dict) -> None:
     """Persist a bench's detail dict, merging into any existing
     ``results/bench/<name>.json`` instead of clobbering it — a re-run
-    of one leg (say the sharded A/B under ``--devices``) must not drop
-    the rows another leg wrote earlier (the
-    ``engine_sharded``/``speedup_sharded_vs_single`` regression).  The
-    merge is one level deep: legs share top-level grid keys (e.g.
-    ``N32_T30_d1048576``) but each writes its own sub-keys, so dict
-    values merge per sub-key (new leg wins on conflicts) while scalar
-    values replace."""
+    of one leg must not drop the rows another leg wrote earlier.  The
+    merge is one level deep: legs share top-level grid keys but each
+    writes its own sub-keys, so dict values merge per sub-key (new leg
+    wins on conflicts) while scalar values replace."""
     os.makedirs(RESULTS_DIR, exist_ok=True)
     path = os.path.join(RESULTS_DIR, f"{name}.json")
     merged: Dict = {}

@@ -1,18 +1,16 @@
 """Batched, kernel-backed MaTU round engine (paper §3.2, Eq. 3–7).
 
-One jit-compiled pipeline replaces the three divergent server paths the
-repo used to carry (the Python-loop ``MaTUServer.round``, the dense
-``matu_round`` reference, and the unused Pallas kernels):
+One jit-compiled pipeline over the wire layout:
 
   pack  →  Eq. 3+4 batched agreement/merge  →  Eq. 5 sign similarity
         →  Eq. 6+7 cross-task transfer      →  batched downlink
            re-unification (fused unify + mask + λ kernel)
 
 All tensor math dispatches through
-:func:`repro.kernels.ops.matu_round_slots_packed` (packed Pallas
-kernels on TPU; the two-pass cache-blocked packed streaming round on
-CPU/GPU); ``matu_round`` in :mod:`repro.core.aggregation` remains the
-dense reference semantics the engine is tested against.
+:func:`repro.kernels.ops.matu_round_slots_packed` (Pallas kernels on
+TPU; the two-pass cache-blocked streaming round on CPU/GPU);
+``matu_round`` in :mod:`repro.core.aggregation` is the dense reference
+semantics the engine is tested against.
 
 Padding contract
 ----------------
@@ -29,10 +27,9 @@ A round's ragged ``List[ClientUpload]`` is packed into fixed-shape
   T of T+1 segments) swallows all padding; downlink gathers clamp the
   sentinel and the slot-valid mask zeroes its output.
 * task axis: always the full registry size T.  Tasks with no member
-  this round produce τ̂ = 0, m̂ = 0 (``matu_round`` semantics — the
-  legacy server reported m̂ = 1 for unheld tasks, which is unobservable
-  downstream) and are masked out of the similarity matrix so
-  cross-task transfer never mixes in zero vectors.
+  this round produce τ̂ = 0, m̂ = 0 (``matu_round`` semantics) and
+  are masked out of the similarity matrix so cross-task transfer never
+  mixes in zero vectors.
 
 Wire format
 -----------
@@ -51,16 +48,11 @@ simulated:
   all round *compute* is fp32 — kernels upcast one cache/VMEM tile at
   a time, and every sign-derived quantity (modulator mask bits, m̂,
   similarity) plus λ num/den is computed from fp32 values *before* the
-  outgoing bf16 rounding.  Consequently packed↔bool parity is exact
-  on identical (already bf16-quantised) inputs: masks, m̂, and
-  similarity are bit-identical in every mode (per-coordinate
-  decisions, independent of tile/chunk grouping), bf16 vector outputs
-  are the bf16 rounding of the fp32 ones, and λs are bit-identical on
-  the streaming ref round (same CHUNK_D accumulation grouping as the
-  bool round).  On the Pallas paths the packed kernels tile d at 4096
-  (128 uint32 lanes) vs the bool kernels' 2048, so the λ num/den
-  partial sums group differently across tiles — λ agrees to fp32
-  accumulation tolerance (~1e-6 relative) there, not bitwise.
+  outgoing bf16 rounding.  Masks, m̂ and similarity are per-coordinate
+  decisions, identical in every mode; the λ num/den partial sums
+  group by the Pallas kernels' 4096-wide tiles or the ref round's
+  CHUNK_D chunks, so λ agrees across modes to fp32 accumulation
+  tolerance (~1e-6 relative), not bitwise.
 * **m̂** is not part of the wire and is not materialised in fp32:
   the engine carries the Eq. 3 agreement numerator (an exact integer
   ≤ N_t) at one byte per coordinate and re-derives
@@ -83,13 +75,8 @@ the server's expectation for any task it holds.  Mixed-architecture
 rounds zero-pad every client's vector to a common d that is a multiple
 of 256 coordinates (``8 × bitpack.WORD_BITS`` = one ``LAMBDA_BLOCK``),
 so shorter manifests end exactly on a packed-word AND λ-block
-boundary: pad coordinates are zero in every row, contribute nothing to
-any reduction, and the packed/bool parity guarantees above carry over
-to padded rounds unchanged.
-
-The bool/fp32 slot layout is retained behind ``pack_uploads(...,
-packed=False)`` as the A/B baseline and parity oracle
-(``benchmarks/bench_round_engine.py`` measures both).
+boundary: pad coordinates are zero in every row and contribute nothing
+to any reduction.
 
 **Entropy-coded layer (optional, host edge only).**  On top of the
 packed words sits an invertible Golomb-Rice coder
@@ -104,15 +91,12 @@ the bytes.  The coded layer never enters the jitted round:
 host edge, and ``RoundEngine.downlinks(code_masks=True)`` encodes the
 downlink rows back to streams; biased modulator masks (P(1) ≈ 0.75 on
 own tasks) go out at ~0.82 bits/coord, measured off the actual byte
-streams.  ``code_masks=False`` (default) keeps the raw packed wire as
-the A/B toggle.
+streams.  ``code_masks=False`` (default) keeps the raw packed wire.
 
 The slot layout keeps the packed footprint and the round's work at
-O(Σ k_n · d) — the same asymptotics as the legacy ragged loop; the
-packed Pallas round reads the slots as they are, and the dense
-(N, T, ·) tensors that ``matu_round`` and the bool kernel path consume
-are derived on demand (``PackedRound.dense_tensors`` / a scatter inside
-the bool kernel path).
+O(Σ k_n · d); both the Pallas and the ref round read the slots as they
+are, and the dense (N, T, ·) tensors that the ``matu_round`` oracle
+consumes are derived on demand (``PackedRound.dense_tensors``).
 
 The jit cache is keyed on (shape signature, dispatch mode, d); the
 mode is resolved from the environment once per call (see
@@ -192,8 +176,8 @@ host devices on the CI debug mesh):
   outputs are sliced back to d (outside the round program, one at a
   time: every shard's padding sits at its end, so each slice moves
   data between the devices, as collective-permutes).
-* **collectives** — ``_round_impl`` runs ``ops.matu_round_slots`` /
-  ``_packed`` under ``shard_map``; per-coordinate math (Eq. 3, 4, 6, 7
+* **collectives** — ``_round_impl`` runs ``ops.matu_round_slots_packed``
+  under ``shard_map``; per-coordinate math (Eq. 3, 4, 6, 7
   and the downlink re-unification) never crosses shards.  Exactly two
   reductions do: one integer psum of the Eq. 5 (T, T) popcount dots
   (exact under any order), and one psum of the λ numerator/denominator
@@ -203,9 +187,9 @@ host devices on the CI debug mesh):
 * **parity** — the λ reductions run on a fixed 256-coord block grid
   combined by a shard-count-invariant binary tree, so the sharded
   round is **bit-identical** to the single-device round in "ref" mode
-  for both the packed and bool layouts (power-of-two shard counts).
-  On the Pallas paths masks/m̂/similarity stay bit-identical and λ
-  agrees to fp32 accumulation tolerance (the PR 2 tile caveat).
+  (power-of-two shard counts).  On the Pallas paths masks/m̂/similarity
+  stay bit-identical and λ agrees to fp32 accumulation tolerance (the
+  per-shard kernels' tile sums are psum'd).
 
 Population-scale contract
 -------------------------
@@ -246,8 +230,8 @@ applies the same adds in the same global row order as the monolithic
 round's whole-round segment-sum, for ANY contiguous chunking; the
 integer votes/dots are order-free; and every d-axis reduction keeps
 the monolithic grid (``CHUNK_D`` streaming blocks, ``LAMBDA_BLOCK`` λ
-tree).  Hence chunked ≡ monolithic **bit for bit** in ref mode for
-both layouts — masks, λ, vectors, and the measured wire bits
+tree).  Hence chunked ≡ monolithic **bit for bit** in ref mode —
+masks, λ, vectors, and the measured wire bits
 (tests/test_chunked_engine.py), for chunk sizes 1, non-divisors of N,
 and > N alike.
 
@@ -338,17 +322,14 @@ class EngineConfig:
 
 @dataclass
 class PackedRound:
-    """Fixed-shape slot tensors for one round + host-side metadata.
-
-    In the default wire layout ``unified`` is bf16 and ``slot_masks``
-    holds bit-packed uint32 words (``packed`` is True); the legacy
-    bool/fp32 layout (``pack_uploads(..., packed=False)``) is kept for
-    A/B benchmarks and parity tests.
+    """Fixed-shape slot tensors for one round + host-side metadata, in
+    the wire layout: ``unified`` bf16 and ``slot_masks`` bit-packed
+    uint32 words (see "Wire format" in the module docstring).
     """
     client_ids: List[int]            # actual clients, row order
     task_ids: List[List[int]]        # per client, slot order
-    unified: jax.Array               # (n_max, d) bf16 (wire) | fp32 (bool A/B)
-    slot_masks: jax.Array            # (n_max, k_max, ceil(d/32)) uint32 | (…, d) bool
+    unified: jax.Array               # (n_max, d) bf16
+    slot_masks: jax.Array            # (n_max, k_max, ceil(d/32)) uint32
     slot_lams: jax.Array             # (n_max, k_max) fp32
     slot_sizes: jax.Array            # (n_max, k_max) fp32
     slot_tasks: jax.Array            # (n_max, k_max) int32; T = invalid sentinel
@@ -373,67 +354,58 @@ class PackedRound:
     def padded_d(self) -> int:
         return self.d_pad or self.d
 
-    @property
-    def packed(self) -> bool:
-        """True when the slot tensors are in the wire layout."""
-        return self.slot_masks.dtype == jnp.uint32
-
     def wire_bits(self) -> int:
         """Measured uplink size of the real (non-padding) slots: the
         bits actually occupied by this round's wire buffers (bf16
-        unified + packed mask words + fp32 λ per slot).  For the bool
-        A/B layout this reports the paper's fp32+dense-bit accounting
-        (32d + k(d+32)) — the scheme those buffers implement."""
-        from repro.core.client import paper_link_bits
-        total = 0
-        for tasks in self.task_ids:
-            k = len(tasks)
-            if self.packed:
-                total += bitpack.wire_bits(
-                    self.d, k,
-                    vec_bytes_per_elem=self.unified.dtype.itemsize)
-            else:
-                total += paper_link_bits(self.d, k)
-        return total
+        unified + packed mask words + fp32 λ per slot)."""
+        return sum(bitpack.wire_bits(
+            self.d, len(tasks),
+            vec_bytes_per_elem=self.unified.dtype.itemsize)
+            for tasks in self.task_ids)
 
     def dense_tensors(self):
         """Scatter to the dense per-task layout ``matu_round`` consumes:
         (masks (N, T, d) bool, lams (N, T), member (N, T), sizes (N, T)).
-        Test/diagnostic helper — the hot path never materialises this
-        on CPU.  Delegates to the single slot→dense contract in
-        :func:`repro.kernels.ops.slots_to_dense` (packed masks go
-        through the one sanctioned ``ops.unpack_masks`` route)."""
-        masks = (ops.unpack_masks(self.slot_masks, self.d)
-                 if self.packed else self.slot_masks)
-        return ops.slots_to_dense(masks, self.slot_lams,
-                                  self.slot_sizes, self.slot_valid,
-                                  self.slot_tasks, self.n_tasks)
+        Test/diagnostic helper — the round never materialises this.
+        Sentinel task ids (== n_tasks) are scatter-dropped; masks go
+        through the one sanctioned ``ops.unpack_masks`` route."""
+        n = self.slot_masks.shape[0]
+        rows = jnp.arange(n)[:, None]
+        valid = self.slot_valid
+
+        def scatter(x, dtype):
+            return jnp.zeros((n, self.n_tasks) + x.shape[2:], dtype).at[
+                rows, self.slot_tasks].set(x, mode="drop")
+
+        masks = ops.unpack_masks(self.slot_masks, self.d)
+        return (scatter(jnp.where(valid[:, :, None], masks, False), bool),
+                scatter(jnp.where(valid, self.slot_lams, 0.0), jnp.float32),
+                scatter(valid, bool),
+                scatter(jnp.where(valid, self.slot_sizes, 0.0), jnp.float32))
 
 
 class EngineOutput(NamedTuple):
     """Round results.  Neither τ̃ nor m̂ is materialised on the hot
     path: τ̃ is (2·task_vectors − tau_hats) on rows with donors, and m̂
-    is re-derived from the exact byte-wide agreement numerator via the
-    ``m_hats`` property.  The packed path fills (alpha_num, n_held);
-    the bool A/B path fills ``m_hats_dense`` instead."""
+    is re-derived from the exact byte-wide agreement numerator
+    (alpha_num, n_held) via the ``m_hats`` property.  The downlink
+    fields are None on a chunked round's output (its downlinks exist
+    one chunk at a time)."""
     task_vectors: jax.Array          # (T, d) τ^{t,r+1} fp32
     tau_hats: jax.Array              # (T, d) fp32
     similarity: jax.Array            # (T, T), held-masked
-    down_unified: jax.Array          # (n_max, d) bf16 (wire) | fp32
-    down_masks: jax.Array            # (n_max, k_max, ceil(d/32)) uint32 | (…, d) bool
-    down_lams: jax.Array             # (n_max, k_max)
-    alpha_num: Optional[jax.Array] = None    # (T, d) uint8 — |Σ sgn(m⊙τ)|
-    n_held: Optional[jax.Array] = None       # (T,) fp32 member counts
+    down_unified: Optional[jax.Array]  # (n_max, d) bf16
+    down_masks: Optional[jax.Array]    # (n_max, k_max, ceil(d/32)) uint32
+    down_lams: Optional[jax.Array]     # (n_max, k_max)
+    alpha_num: jax.Array             # (T, d) uint8 — |Σ sgn(m⊙τ)|
+    n_held: jax.Array                # (T,) fp32 member counts
     rho: float = RHO_DEFAULT
-    m_hats_dense: Optional[jax.Array] = None  # (T, d) fp32 (bool path only)
 
     @property
     def m_hats(self) -> jax.Array:
         """Eq. 3 averaged task masks m̂ (T, d) fp32 — identical (bit for
         bit) to the value the round used internally: the same fp32
         division α = |Σ sgn| / max(N_t, 1) both passes performed."""
-        if self.m_hats_dense is not None:
-            return self.m_hats_dense
         alpha = (self.alpha_num.astype(jnp.float32)
                  / jnp.maximum(self.n_held, 1.0)[:, None])
         return jnp.where(alpha >= self.rho, 1.0, alpha)
@@ -495,7 +467,6 @@ class SlotStage:
 def pack_uploads(uploads: Sequence[ClientUpload], n_tasks: int, *,
                  n_max: Optional[int] = None,
                  k_max: Optional[int] = None,
-                 packed: bool = True,
                  mesh: Optional[Mesh] = None,
                  stage: Optional[SlotStage] = None,
                  phase_us: Optional[Dict[str, float]] = None) -> PackedRound:
@@ -503,10 +474,9 @@ def pack_uploads(uploads: Sequence[ClientUpload], n_tasks: int, *,
 
     Pure data movement (numpy fills + ``np.packbits`` of O(Σ k_n · d)
     *bits* for the masks, one host→device transfer per tensor); all
-    math stays inside the jitted round.  ``packed=False`` selects the
-    legacy bool/fp32 layout (A/B baseline).  A client's bool masks are
-    bit-packed and its unified vector rounded to bf16 here — this IS
-    the uplink quantisation, applied once at the wire boundary.
+    math stays inside the jitted round.  A client's dense bool masks
+    are bit-packed and its unified vector rounded to bf16 here — this
+    IS the uplink quantisation, applied once at the wire boundary.
 
     Entropy-coded (uint8 stream) uploads are decoded here at the host
     edge in ONE batched ``decode_mask_rows`` call across every coded
@@ -561,28 +531,20 @@ def pack_uploads(uploads: Sequence[ClientUpload], n_tasks: int, *,
         # mask/vector buffers twice for nothing.  With a stage the same
         # (possibly dirty) buffers come back each round — the explicit
         # padding writes below are exactly the re-zeroing reuse needs.
-        # host-side bf16 fill for the wire layout (ml_dtypes ships with
-        # jax): halves the host→device transfer and skips the device cast
-        vec_dtype = np.float32
-        if packed:
-            import ml_dtypes
-            vec_dtype = ml_dtypes.bfloat16
+        # host-side bf16 fill (ml_dtypes ships with jax): halves the
+        # host→device transfer and skips the device cast
+        import ml_dtypes
         alloc = stage.alloc if stage is not None else (
             lambda _name, shape, dtype: np.empty(shape, dtype))
-        unified = alloc("unified", (n_max, d_pad), vec_dtype)
+        unified = alloc("unified", (n_max, d_pad), ml_dtypes.bfloat16)
         unified[n:] = 0.0
         unified[:, d:] = 0.0
-        if packed:
-            dw = bitpack.packed_width(d)
-            wpad = bitpack.packed_width(d_pad)
-            slot_masks = alloc("slot_masks", (n_max, k_max, wpad), np.uint32)
-            slot_masks[n:] = 0
-            if wpad > dw:
-                slot_masks[:n, :, dw:] = 0
-        else:
-            slot_masks = alloc("slot_masks", (n_max, k_max, d_pad), bool)
-            slot_masks[n:] = False
-            slot_masks[:, :, d:] = False
+        dw = bitpack.packed_width(d)
+        wpad = bitpack.packed_width(d_pad)
+        slot_masks = alloc("slot_masks", (n_max, k_max, wpad), np.uint32)
+        slot_masks[n:] = 0
+        if wpad > dw:
+            slot_masks[:n, :, dw:] = 0
         slot_lams = np.zeros((n_max, k_max), np.float32)
         slot_sizes = np.zeros((n_max, k_max), np.float32)
         slot_tasks = np.full((n_max, k_max), n_tasks, np.int32)
@@ -592,16 +554,11 @@ def pack_uploads(uploads: Sequence[ClientUpload], n_tasks: int, *,
             k = ks[i]
             unified[i, :d] = np.asarray(up.unified)
             m = masks_np[i]
-            if packed:
-                # accept either bool masks (legacy clients — packed here at
-                # the wire boundary) or already-packed words
-                slot_masks[i, :k, :dw] = (m if m.dtype == np.uint32
-                                          else bitpack.pack_bits_np(m))
-                slot_masks[i, k:, :dw] = 0
-            else:
-                slot_masks[i, :k, :d] = (bitpack.unpack_bits_np(m, d)
-                                         if m.dtype == np.uint32 else m)
-                slot_masks[i, k:] = False
+            # a client's dense bool masks are packed here, at the wire
+            # boundary; packed words go in as they are
+            slot_masks[i, :k, :dw] = (m if m.dtype == np.uint32
+                                      else bitpack.pack_bits_np(m))
+            slot_masks[i, k:, :dw] = 0
             slot_lams[i, :k] = np.asarray(up.lams, np.float32)
             slot_sizes[i, :k] = np.asarray(up.data_sizes, np.float32)
             slot_tasks[i, :k] = up.task_ids
@@ -634,8 +591,8 @@ def pack_from_slots(client_ids: List[int], task_ids: List[List[int]],
                     slot_weights: Optional[jax.Array] = None) -> PackedRound:
     """Build a PackedRound from already-batched slot tensors (the
     strategy's pre-packed upload path) — zero copies, the slot layout
-    IS the engine's native layout.  ``slot_masks`` may be uint32 wire
-    words (``batched_client_unify`` output) or legacy dense bool.
+    IS the engine's native layout: ``slot_masks`` are the uint32 wire
+    words ``batched_client_unify`` emits.
 
     ``d`` is the true feature count when the d-axis tensors already
     carry the taskvec-shard padding (``batched_client_unify`` with a
@@ -644,7 +601,6 @@ def pack_from_slots(client_ids: List[int], task_ids: List[List[int]],
 
     ``slot_weights`` (optional (n, k_max) fp32) attaches the async
     staleness discount to the round (replicated under a mesh)."""
-    packed = slot_masks.dtype == jnp.uint32
     width = int(unified.shape[-1])
     d = d or width
     _, _, n_shards = _mesh_layout(mesh)
@@ -654,10 +610,8 @@ def pack_from_slots(client_ids: List[int], task_ids: List[List[int]],
                          f"neither d={d} nor the shard-padded {d_pad}")
     if n_shards > 1 and width != d_pad:
         unified = jnp.pad(unified, ((0, 0), (0, d_pad - width)))
-        w_pad = (d_pad // 32 - slot_masks.shape[-1] if packed
-                 else d_pad - slot_masks.shape[-1])
-        slot_masks = jnp.pad(slot_masks,
-                             ((0, 0), (0, 0), (0, w_pad)))
+        slot_masks = jnp.pad(slot_masks, (
+            (0, 0), (0, 0), (0, d_pad // 32 - slot_masks.shape[-1])))
     if n_shards > 1:
         rep = NamedSharding(mesh, P())
         unified = jax.device_put(unified, taskvec_sharding(mesh, 2))
@@ -682,27 +636,22 @@ def _round_impl(unified, slot_masks, slot_lams, slot_sizes, slot_valid,
                 mesh: Optional[Mesh] = None,
                 axes: Tuple[str, ...] = (),
                 axis_sizes: Tuple[int, ...] = ()):
-    """The whole server step, traced once per (shapes, mode, d, mesh).
-    The mask dtype selects the wire-format (uint32) or bool A/B path;
-    with a (mesh, taskvec axes) pair the op runs under ``shard_map``
-    per the engine's sharding contract.  ``slot_weights`` (async
-    staleness discount, replicated under a mesh) pre-scales λ and sizes
-    inside ``ops`` — omitted entirely from the trace when None, so the
-    synchronous jit programs are untouched."""
+    """The whole server step over the wire slot layout, traced once
+    per (shapes, mode, d, mesh).  With a (mesh, taskvec axes) pair the
+    op runs under ``shard_map`` per the engine's sharding contract.
+    ``slot_weights`` (async staleness discount, replicated under a
+    mesh) pre-scales λ and sizes inside ``ops`` — omitted entirely from
+    the trace when None, so the synchronous jit programs are
+    untouched.  Returns (tv, τ̂, α_num, n_held, sim, down_unified,
+    down_words, down_lams)."""
     kw = dict(rho=cfg.rho, eps=cfg.eps, kappa=cfg.kappa,
               cross_task=cfg.cross_task, uniform_cross=cfg.uniform_cross,
               mode=mode)
-    packed = slot_masks.dtype == jnp.uint32
     n_shards = int(np.prod(axis_sizes)) if axes else 1
     if mesh is None or n_shards == 1:
-        kw["slot_weights"] = slot_weights
-        if packed:
-            return ops.matu_round_slots_packed(
-                unified, slot_masks, slot_lams, slot_sizes, slot_valid,
-                slot_tasks, cfg.n_tasks, d, **kw)
-        return ops.matu_round_slots(
+        return ops.matu_round_slots_packed(
             unified, slot_masks, slot_lams, slot_sizes, slot_valid,
-            slot_tasks, cfg.n_tasks, **kw)
+            slot_tasks, cfg.n_tasks, d, slot_weights=slot_weights, **kw)
 
     d_pad = int(unified.shape[-1])
     d_local = d_pad // n_shards
@@ -710,21 +659,13 @@ def _round_impl(unified, slot_masks, slot_lams, slot_sizes, slot_valid,
     s2, s3, rep = P(None, ax), P(None, None, ax), P()
     kw.update(axis_name=axes, axis_sizes=axis_sizes, d_norm=d)
 
-    if packed:
-        def body(u, m, lam, sz, val, tid, *w):
-            return ops.matu_round_slots_packed(
-                u, m, lam, sz, val, tid, cfg.n_tasks, d_local,
-                slot_weights=w[0] if w else None, **kw)
-        # (tv, τ̂, α_num, n_held, sim, down_uni, down_words, down_lams)
-        out_specs = (s2, s2, s2, rep, rep, s2, s3, rep)
-    else:
-        def body(u, m, lam, sz, val, tid, *w):
-            return ops.matu_round_slots(
-                u, m, lam, sz, val, tid, cfg.n_tasks,
-                slot_weights=w[0] if w else None, **kw)
-        # (tv, τ̂, m̂, sim, down_uni, down_masks, down_lams)
-        out_specs = (s2, s2, s2, rep, s2, s3, rep)
+    def body(u, m, lam, sz, val, tid, *w):
+        return ops.matu_round_slots_packed(
+            u, m, lam, sz, val, tid, cfg.n_tasks, d_local,
+            slot_weights=w[0] if w else None, **kw)
 
+    # (tv, τ̂, α_num, n_held, sim, down_uni, down_words, down_lams)
+    out_specs = (s2, s2, s2, rep, rep, s2, s3, rep)
     in_specs = (s2, s3, rep, rep, rep, rep)
     operands = (unified, slot_masks, slot_lams, slot_sizes, slot_valid,
                 slot_tasks)
@@ -805,8 +746,6 @@ def _assemble_downlinks(client_ids: List[int], task_ids: List[List[int]],
             from repro.fed.compression import encode_mask_rows_with_sizes
             with span("round.encode", phase_us, "encode"):
                 dm = np.asarray(down_masks)
-                if dm.dtype != np.uint32:     # bool A/B layout
-                    dm = bitpack.pack_bits_np(dm)
                 rows = dm[np.repeat(np.arange(len(ks)), ks),
                           np.concatenate([np.arange(k, dtype=np.int64)
                                           for k in ks])]
@@ -846,17 +785,11 @@ def _merge_chunk_impl(unified, slot_masks, slot_lams, slot_sizes, slot_valid,
     chunk row of its local d-slice — the client fold is never split
     across devices (that would change the fp32 accumulation order), so
     the step has no collectives."""
-    packed = slot_masks.dtype == jnp.uint32
     n_shards = int(np.prod(axis_sizes)) if axes else 1
     if mesh is None or n_shards == 1:
-        if packed:
-            return ops.matu_merge_chunk_packed(
-                unified, slot_masks, slot_lams, slot_sizes, slot_valid,
-                slot_tasks, totals, a_acc, tau_acc, d,
-                slot_weights=slot_weights, mode=mode)
-        return ops.matu_merge_chunk(
+        return ops.matu_merge_chunk_packed(
             unified, slot_masks, slot_lams, slot_sizes, slot_valid,
-            slot_tasks, totals, a_acc, tau_acc,
+            slot_tasks, totals, a_acc, tau_acc, d,
             slot_weights=slot_weights, mode=mode)
 
     d_local = int(unified.shape[-1]) // n_shards
@@ -864,13 +797,10 @@ def _merge_chunk_impl(unified, slot_masks, slot_lams, slot_sizes, slot_valid,
     s2, s3, rep = P(None, ax), P(None, None, ax), P()
 
     def body(u, m, lam, sz, val, tid, tot, a, ta, *w):
-        w0 = w[0] if w else None
-        if packed:
-            return ops.matu_merge_chunk_packed(u, m, lam, sz, val, tid,
-                                               tot, a, ta, d_local,
-                                               slot_weights=w0, mode=mode)
-        return ops.matu_merge_chunk(u, m, lam, sz, val, tid, tot, a, ta,
-                                    slot_weights=w0, mode=mode)
+        return ops.matu_merge_chunk_packed(u, m, lam, sz, val, tid,
+                                           tot, a, ta, d_local,
+                                           slot_weights=w[0] if w else None,
+                                           mode=mode)
 
     in_specs = (s2, s3, rep, rep, rep, rep, rep, s2, s2)
     operands = (unified, slot_masks, slot_lams, slot_sizes, slot_valid,
@@ -883,7 +813,7 @@ def _merge_chunk_impl(unified, slot_masks, slot_lams, slot_sizes, slot_valid,
 
 
 def _finish_impl(a_acc, tau_acc, nt_acc, *, cfg: EngineConfig, mode: str,
-                 d: int, packed: bool, n_for_dtype: int,
+                 d: int, n_for_dtype: int,
                  mesh: Optional[Mesh] = None, axes: Tuple[str, ...] = (),
                  axis_sizes: Tuple[int, ...] = ()):
     """Chunked-round finish: Eq. 3 α/m̂ → Eq. 5 dots → Eq. 6/7 → λ
@@ -895,10 +825,8 @@ def _finish_impl(a_acc, tau_acc, nt_acc, *, cfg: EngineConfig, mode: str,
               uniform_cross=cfg.uniform_cross, mode=mode)
     n_shards = int(np.prod(axis_sizes)) if axes else 1
     if mesh is None or n_shards == 1:
-        if packed:
-            return ops.matu_finish_packed(a_acc, tau_acc, nt_acc,
-                                          n_for_dtype, d=d, **kw)
-        return ops.matu_finish(a_acc, tau_acc, nt_acc, d=d, **kw)
+        return ops.matu_finish_packed(a_acc, tau_acc, nt_acc,
+                                      n_for_dtype, d=d, **kw)
 
     d_local = int(a_acc.shape[-1]) // n_shards
     ax = axes[0] if len(axes) == 1 else axes
@@ -906,20 +834,17 @@ def _finish_impl(a_acc, tau_acc, nt_acc, *, cfg: EngineConfig, mode: str,
     kw.update(axis_name=axes, axis_sizes=axis_sizes, d_norm=d)
 
     def body(a, ta, nt):
-        if packed:
-            return ops.matu_finish_packed(a, ta, nt, n_for_dtype,
-                                          d=d_local, **kw)
-        return ops.matu_finish(a, ta, nt, d=d_local, **kw)
+        return ops.matu_finish_packed(a, ta, nt, n_for_dtype,
+                                      d=d_local, **kw)
 
-    # (tv, τ̂, α_num | m̂, n_t, sim, num_t)
+    # (tv, τ̂, α_num, n_t, sim, num_t)
     return jax.shard_map(body, mesh=mesh, in_specs=(s2, s2, rep),
                          out_specs=(s2, s2, s2, rep, rep, rep),
                          check_vma=False)(a_acc, tau_acc, nt_acc)
 
 
-def _downlink_chunk_impl(task_vectors, slot_valid, slot_tasks, num_t, *,
-                         cfg: EngineConfig, mode: str, d: int, packed: bool,
-                         mesh: Optional[Mesh] = None,
+def _downlink_chunk_impl(task_vectors, slot_tasks, num_t, *, mode: str,
+                         d: int, mesh: Optional[Mesh] = None,
                          axes: Tuple[str, ...] = (),
                          axis_sizes: Tuple[int, ...] = (),
                          row_axes: Tuple[str, ...] = ()):
@@ -927,14 +852,12 @@ def _downlink_chunk_impl(task_vectors, slot_valid, slot_tasks, num_t, *,
     This is where the 2-D (slots × taskvec) mesh composes: ``row_axes``
     (the fed_slots rule) shard the chunk's client rows, the taskvec
     axes shard d, and the λ-denominator roots psum over the taskvec
-    axes only (rows never mix)."""
+    axes only (rows never mix).  Invalid slots carry the sentinel task
+    id, which the op zeroes, so no validity mask goes in."""
     n_shards = int(np.prod(axis_sizes)) if axes else 1
     if mesh is None or (n_shards == 1 and not row_axes):
-        if packed:
-            return ops.matu_downlink_chunk_packed(task_vectors, slot_tasks,
-                                                  num_t, d, mode=mode)
-        return ops.matu_downlink_chunk(task_vectors, slot_valid, slot_tasks,
-                                       num_t, n_tasks=cfg.n_tasks, mode=mode)
+        return ops.matu_downlink_chunk_packed(task_vectors, slot_tasks,
+                                              num_t, d, mode=mode)
 
     d_local = (int(task_vectors.shape[-1]) // n_shards
                if n_shards > 1 else d)
@@ -946,17 +869,14 @@ def _downlink_chunk_impl(task_vectors, slot_valid, slot_tasks, num_t, *,
     if n_shards > 1:
         kw.update(axis_name=axes, axis_sizes=axis_sizes)
 
-    def body(tv, val, tid, nt):
-        if packed:
-            return ops.matu_downlink_chunk_packed(tv, tid, nt, d_local, **kw)
-        return ops.matu_downlink_chunk(tv, val, tid, nt,
-                                       n_tasks=cfg.n_tasks, **kw)
+    def body(tv, tid, nt):
+        return ops.matu_downlink_chunk_packed(tv, tid, nt, d_local, **kw)
 
-    in_specs = (P(None, ax), P(rx, None), P(rx, None), rep)
+    in_specs = (P(None, ax), P(rx, None), rep)
     out_specs = (P(rx, ax), P(rx, None, ax), P(rx, None))
     return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)(
-                             task_vectors, slot_valid, slot_tasks, num_t)
+                             task_vectors, slot_tasks, num_t)
 
 
 class RoundEngine:
@@ -1011,15 +931,10 @@ class RoundEngine:
             out = self._impl(mode, packed.d)(*args)
         if d_pad != packed.d:
             out = list(out)
-            _slice_outputs(out, packed.d, packed.packed)
-        if packed.packed:
-            (tv, tau, a_num, n_held, sim, du, dm, dl) = out
-            return EngineOutput(tv, tau, sim, du, dm, dl,
-                                alpha_num=a_num, n_held=n_held,
-                                rho=self.cfg.rho)
-        (tv, tau, m_hats, sim, du, dm, dl) = out
-        return EngineOutput(tv, tau, sim, du, dm, dl,
-                            rho=self.cfg.rho, m_hats_dense=m_hats)
+            _slice_outputs(out, packed.d)
+        (tv, tau, a_num, n_held, sim, du, dm, dl) = out
+        return EngineOutput(tv, tau, sim, du, dm, dl, a_num, n_held,
+                            rho=self.cfg.rho)
 
     def downlinks(self, packed: PackedRound, out: EngineOutput, *,
                   code_masks: bool = False,
@@ -1048,23 +963,21 @@ class RoundEngine:
                                    phase_us=phase_us)
 
     def round(self, uploads: Sequence[ClientUpload], *,
-              mode: Optional[str] = None, packed: bool = True,
+              mode: Optional[str] = None,
               code_masks: bool = False,
               staleness: Optional[Sequence[int]] = None,
               staleness_discount: float = STALENESS_DISCOUNT
               ) -> Tuple[Dict[int, ClientDownlink], EngineOutput]:
-        """Pack → run → unpack: the drop-in replacement for the legacy
-        per-task Python loop in ``MaTUServer.round``.  ``packed=False``
-        runs the bool/fp32 A/B layout; ``code_masks=True`` emits
-        entropy-coded downlink masks (coded uploads are accepted and
-        decoded by ``pack_uploads`` regardless of this flag).
+        """Pack → run → unpack: one server round over a list of uploads.
+        ``code_masks=True`` emits entropy-coded downlink masks (coded
+        uploads are accepted and decoded by ``pack_uploads`` regardless
+        of this flag).
 
         ``staleness`` (one int per upload, async buffered rounds)
         attaches the per-slot discount ``staleness_discount**s`` to the
         round — see "Async & fault model" in the module docstring."""
         with span("round"):
-            batch = pack_uploads(uploads, self.cfg.n_tasks, packed=packed,
-                                 mesh=self.mesh)
+            batch = pack_uploads(uploads, self.cfg.n_tasks, mesh=self.mesh)
             if staleness is not None:
                 n_max, k_max = batch.slot_valid.shape
                 w = np.ones((n_max, k_max), np.float32)
@@ -1079,13 +992,12 @@ class RoundEngine:
             out = self.run_packed(batch, mode=mode)
             return self.downlinks(batch, out, code_masks=code_masks), out
 
-    def _chunk_impls(self, mode: str, d: int, packed: bool,
-                     n_for_dtype: int):
+    def _chunk_impls(self, mode: str, d: int, n_for_dtype: int):
         """Jitted (scalars, merge, finish, downlink) chunk steps, cached
         like ``_impl`` — one static signature reused across every chunk
-        of every round with this (mode, layout, d).  The big carried
+        of every round with this (mode, d).  The big carried
         accumulators are donated so the fold updates in place."""
-        key = ("chunked", mode, d, packed, n_for_dtype)
+        key = ("chunked", mode, d, n_for_dtype)
         fns = self._impls.get(key)
         if fns is None:
             import repro.core.engine as _mod
@@ -1103,18 +1015,17 @@ class RoundEngine:
             # only raise "unusable donated buffer" noise
             finish = jax.jit(
                 functools.partial(_mod._finish_impl, cfg=self.cfg,
-                                  mode=mode, d=d, packed=packed,
+                                  mode=mode, d=d,
                                   n_for_dtype=n_for_dtype, **common))
             down = jax.jit(
-                functools.partial(_mod._downlink_chunk_impl, cfg=self.cfg,
-                                  mode=mode, d=d, packed=packed,
-                                  row_axes=self._slot_axes, **common))
+                functools.partial(_mod._downlink_chunk_impl, mode=mode,
+                                  d=d, row_axes=self._slot_axes, **common))
             fns = (scal, merge, finish, down)
             self._impls[key] = fns
         return fns
 
     def round_chunked(self, uploads, *, chunk_clients: int,
-                      mode: Optional[str] = None, packed: bool = True,
+                      mode: Optional[str] = None,
                       code_masks: bool = False,
                       staleness: Optional[Sequence[int]] = None,
                       staleness_discount: float = STALENESS_DISCOUNT,
@@ -1209,8 +1120,8 @@ class RoundEngine:
             # same α-numerator dtype decision as the monolithic round
             # (keyed on its default n_max = next pow2 ≥ N)
             n_for_dtype = _round_up_pow2(n_clients)
-            scal, merge, finish, down = self._chunk_impls(
-                mode, d, packed, n_for_dtype if packed else 0)
+            scal, merge, finish, down = self._chunk_impls(mode, d,
+                                                          n_for_dtype)
 
             def _scalar_chunk(ids_, tasks_, sizes_, stal_):
                 sz = np.zeros((c_pad, k_max), np.float32)
@@ -1243,8 +1154,7 @@ class RoundEngine:
                                       else scal(*args))
 
             # -- phase B: second pass over the stream, fold merge partials
-            a_acc = jnp.zeros((n_seg, dp),
-                              jnp.int32 if packed else jnp.float32)
+            a_acc = jnp.zeros((n_seg, dp), jnp.int32)
             tau_acc = jnp.zeros((n_seg, dp), jnp.float32)
             stage = SlotStage()
             stream = make_iter()
@@ -1257,8 +1167,7 @@ class RoundEngine:
                         "different round on the second pass — it must be "
                         "deterministic (same clients, same order)")
                 batch = pack_uploads(ups, self.cfg.n_tasks, n_max=c_pad,
-                                     k_max=k_max, packed=packed,
-                                     mesh=self.mesh,
+                                     k_max=k_max, mesh=self.mesh,
                                      stage=stage, phase_us=phase_us)
                 uplink_bits += batch.wire_bits()
                 args = (batch.unified, batch.slot_masks, batch.slot_lams,
@@ -1275,18 +1184,13 @@ class RoundEngine:
 
             # -- finish: Eq. 3/5/6/7 + λ numerator from the accumulators
             with span("round.dispatch"):
-                tv, tau_hats, third, n_t, sim, num_t = finish(a_acc, tau_acc,
+                tv, tau_hats, a_num, n_t, sim, num_t = finish(a_acc, tau_acc,
                                                               nt_acc)
             tv_run = tv                # keeps the shard padding for phase C
             if self.n_shards > 1 and d_pad != d:
-                tv, tau_hats, third = tv[:, :d], tau_hats[:, :d], third[:, :d]
-            if packed:
-                out = EngineOutput(tv, tau_hats, sim, None, None, None,
-                                   alpha_num=third, n_held=n_t,
-                                   rho=self.cfg.rho)
-            else:
-                out = EngineOutput(tv, tau_hats, sim, None, None, None,
-                                   rho=self.cfg.rho, m_hats_dense=third)
+                tv, tau_hats, a_num = tv[:, :d], tau_hats[:, :d], a_num[:, :d]
+            out = EngineOutput(tv, tau_hats, sim, None, None, None, a_num,
+                               n_t, rho=self.cfg.rho)
 
             # -- phase C: per-chunk downlink re-unification, streamed out
             dw = bitpack.packed_width(d)
@@ -1294,16 +1198,12 @@ class RoundEngine:
             downlink_bits = 0
             for ids_, tasks_, sizes_, stal_ in metas:
                 tk = np.full((c_pad, k_max), self.cfg.n_tasks, np.int32)
-                vd = np.zeros((c_pad, k_max), bool)
                 for i, tl in enumerate(tasks_):
                     tk[i, :len(tl)] = tl
-                    vd[i, :len(tl)] = True
                 with span("round.dispatch"):
-                    du, dm, dl = down(tv_run, jnp.asarray(vd), jnp.asarray(tk),
-                                      num_t)
+                    du, dm, dl = down(tv_run, jnp.asarray(tk), num_t)
                 if self.n_shards > 1 and d_pad != d:
-                    du = du[:, :d]
-                    dm = dm[:, :, :dw] if packed else dm[:, :, :d]
+                    du, dm = du[:, :d], dm[:, :, :dw]
                 links = _assemble_downlinks(ids_, tasks_, d, du, dm, dl,
                                             code_masks=code_masks,
                                             phase_us=phase_us)
@@ -1321,8 +1221,7 @@ class RoundEngine:
             return downlinks, out, stats
 
     def round_stream(self, rounds, *, mode: Optional[str] = None,
-                     packed: bool = True, code_masks: bool = False,
-                     pipeline: bool = True):
+                     code_masks: bool = False, pipeline: bool = True):
         """Run an iterable of upload rounds through the two-deep host
         pipeline (see "Host pipeline" in the module docstring): while
         the device executes round r, the host drains round r−1 (block
@@ -1340,8 +1239,8 @@ class RoundEngine:
         if not pipeline:
             for ups in rounds:
                 phase: Dict[str, float] = {}
-                batch = pack_uploads(ups, self.cfg.n_tasks, packed=packed,
-                                     mesh=self.mesh, phase_us=phase)
+                batch = pack_uploads(ups, self.cfg.n_tasks, mesh=self.mesh,
+                                     phase_us=phase)
                 t0 = time.perf_counter()
                 out = self.run_packed(batch, mode=mode)
                 jax.block_until_ready(out)
@@ -1357,9 +1256,8 @@ class RoundEngine:
             # host pack/decode of round r overlaps round r−1's device
             # step; stage r%2 was last consumed by round r−2, which was
             # drained (blocked) before this point — never in flight
-            batch = pack_uploads(ups, self.cfg.n_tasks, packed=packed,
-                                 mesh=self.mesh, stage=stages[r % 2],
-                                 phase_us=phase)
+            batch = pack_uploads(ups, self.cfg.n_tasks, mesh=self.mesh,
+                                 stage=stages[r % 2], phase_us=phase)
             out = self.run_packed(batch, mode=mode)      # async dispatch
             pend = (batch, out, phase, time.perf_counter())
             if prev is not None:
@@ -1409,7 +1307,7 @@ def _cut(x: jax.Array, *, d: int, rows: int, sharding) -> jax.Array:
     return jax.lax.fori_loop(0, -(-n // rows), step, out)
 
 
-def _slice_outputs(out: list, d: int, packed: bool) -> None:
+def _slice_outputs(out: list, d: int) -> None:
     """Slice a sharded round's padded d-axis outputs back to the true
     feature count, in place (mask words to ceil(d/32) — padded coords
     carry zero bits, so the wire tail-bit convention holds).
@@ -1421,13 +1319,12 @@ def _slice_outputs(out: list, d: int, packed: bool) -> None:
     chip holds one padded output and its slice besides the rest, never
     every padded output and every slice at once."""
     dw = bitpack.packed_width(d)
-    vectors = (0, 1, 2, 5) if packed else (0, 1, 2, 4)
-    words = 6 if packed else 5
-    for i in vectors + (words,):
+    # (tv, τ̂, α_num, down_unified) are cut to d, down_words to dw
+    for i in (0, 1, 2, 5, 6):
         x = out[i]
         n = x.shape[0]
         rows = max(1, min(n, SLICE_BLOCK_BYTES * n // x.nbytes))
-        out[i] = _cut(x, d=dw if i == words and packed else d, rows=rows,
+        out[i] = _cut(x, d=dw if i == 6 else d, rows=rows,
                       sharding=x.sharding)
         if x.nbytes > SLICE_BLOCK_BYTES:
             del x
@@ -1437,14 +1334,12 @@ def _slice_outputs(out: list, d: int, packed: bool) -> None:
 # -- batched client-side unification ----------------------------------------
 
 @functools.lru_cache(maxsize=None)
-def _client_unify_jit(mode: str, packed: bool):
-    fn = ops.fused_unify_packed if packed else ops.fused_unify
-    return jax.jit(functools.partial(fn, mode=mode))
+def _client_unify_jit(mode: str):
+    return jax.jit(functools.partial(ops.fused_unify_packed, mode=mode))
 
 
 @functools.lru_cache(maxsize=None)
-def _client_unify_sharded_jit(mode: str, packed: bool, mesh: Mesh,
-                              eps: float = 1e-12):
+def _client_unify_sharded_jit(mode: str, mesh: Mesh, eps: float = 1e-12):
     """shard_map'd fused unify: per-shard kernels on the local d-slice,
     one psum for the λ num/den partial sums (λ matches the unsharded
     call to fp32 accumulation tolerance; masks / bf16 vectors are
@@ -1454,8 +1349,7 @@ def _client_unify_sharded_jit(mode: str, packed: bool, mesh: Mesh,
     s2, s3, rep = P(None, ax), P(None, None, ax), P()
 
     def body(tv, valid):
-        uni, masks, num, den = ops.fused_unify_raw(tv, valid, packed=packed,
-                                                   mode=mode)
+        uni, masks, num, den = ops.fused_unify_raw(tv, valid, mode=mode)
         num, den = jax.lax.psum((num, den), axes)
         return uni, masks, num / jnp.maximum(den, eps)
 
@@ -1464,18 +1358,16 @@ def _client_unify_sharded_jit(mode: str, packed: bool, mesh: Mesh,
 
 
 def batched_client_unify(task_vectors: jax.Array, valid: jax.Array, *,
-                         mode: Optional[str] = None, packed: bool = True,
+                         mode: Optional[str] = None,
                          mesh: Optional[Mesh] = None):
     """All clients' upload construction in one fused call.
 
     task_vectors (N, k_max, d) zero-padded stacks; valid (N, k_max).
-    By default emits the uplink wire format:
-    (unified (N, d) **bf16**, mask_words (N, k_max, ceil(d/32))
-    **uint32**, lams (N, k_max) fp32) — row n equals
-    ``unify_with_modulators(task_vectors[n, valid[n]])`` with the
-    unified vector rounded to bf16 *after* the masks/λ were derived
-    from it in fp32.  ``packed=False`` returns the legacy
-    (fp32, bool, fp32) triple.
+    Emits the uplink wire format: (unified (N, d) **bf16**, mask_words
+    (N, k_max, ceil(d/32)) **uint32**, lams (N, k_max) fp32) — row n
+    equals ``unify_with_modulators(task_vectors[n, valid[n]])`` with
+    the unified vector rounded to bf16 *after* the masks/λ were derived
+    from it in fp32.
 
     With ``mesh``, d is zero-padded to ``pad_d_for_shards`` and the
     call runs under ``shard_map``; the returned d-axis tensors keep the
@@ -1485,7 +1377,7 @@ def batched_client_unify(task_vectors: jax.Array, valid: jax.Array, *,
     mode = mode or ops.resolve_mode()
     _, _, n_shards = _mesh_layout(mesh)
     if n_shards == 1:
-        return _client_unify_jit(mode, packed)(task_vectors, valid)
+        return _client_unify_jit(mode)(task_vectors, valid)
     d = int(task_vectors.shape[-1])
     d_pad = pad_d_for_shards(d, n_shards)
     if d_pad != d:
@@ -1493,4 +1385,4 @@ def batched_client_unify(task_vectors: jax.Array, valid: jax.Array, *,
                                ((0, 0), (0, 0), (0, d_pad - d)))
     task_vectors = jax.device_put(task_vectors, taskvec_sharding(mesh, 3))
     valid = jax.device_put(valid, NamedSharding(mesh, P()))
-    return _client_unify_sharded_jit(mode, packed, mesh)(task_vectors, valid)
+    return _client_unify_sharded_jit(mode, mesh)(task_vectors, valid)

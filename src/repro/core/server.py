@@ -5,13 +5,11 @@ round's uploads, runs Eq. 3–7, and emits per-client downlinks (unified
 vector + modulators for that client's tasks).  Task identity (the
 |T|-sized registry) is the only global it needs.
 
-Since the round-engine refactor, ``round`` is a thin wrapper over
-:class:`repro.core.engine.RoundEngine`: uploads are packed into padded
-batch tensors and the whole Eq. 3–7 pipeline runs as one jitted,
-kernel-dispatched call (see engine module docstring for the padding
-contract).  The original per-task Python loop is preserved verbatim as
-``round_legacy`` — it is the behavioural oracle for the engine's
-parity tests and the baseline of ``benchmarks/bench_round_engine.py``.
+``round`` is a thin wrapper over :class:`repro.core.engine.RoundEngine`:
+uploads are packed into padded batch tensors and the whole Eq. 3–7
+pipeline runs as one jitted, kernel-dispatched call (see engine module
+docstring for the padding contract).  The dense reference semantics
+the engine is tested against is :func:`repro.core.aggregation.matu_round`.
 """
 
 from __future__ import annotations
@@ -22,10 +20,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.aggregation import (EPS_DEFAULT, KAPPA_DEFAULT, RHO_DEFAULT,
-                                    combine_round, cross_task_aggregate,
-                                    sign_similarity, task_aggregate,
-                                    topk_similar)
+from repro.core.aggregation import EPS_DEFAULT, KAPPA_DEFAULT, RHO_DEFAULT
 from repro.core.client import ClientDownlink, ClientUpload
 from repro.core.engine import EngineConfig, EngineOutput, PackedRound, RoundEngine
 from repro.core.unify import unify_with_modulators
@@ -128,8 +123,7 @@ class MaTUServer:
         self.last_similarity = out.similarity
         self.last_task_vectors = out.task_vectors
 
-    def serving_downlink(self, *, packed: bool = True,
-                         code_masks: bool = False,
+    def serving_downlink(self, *, code_masks: bool = False,
                          fingerprint: Optional[str] = None
                          ) -> ClientDownlink:
         """Serving handoff: re-unify the LAST round's full task-vector
@@ -137,12 +131,12 @@ class MaTUServer:
         :class:`repro.serve.store.ModulatorStore` — row ``t`` of the
         modulators is task id ``t`` (the store keys on position).
 
-        ``packed`` ships the wire layout (bf16 unified + bit-packed
-        uint32 mask words); ``code_masks`` entropy-codes the rows into
-        a Golomb-Rice byte stream instead.  ``fingerprint`` stamps the
-        layout manifest the task vectors were flattened through
-        (``TaskVectorSpace.fingerprint``) so the store can verify the
-        handoff before serving anything.
+        Ships the wire layout: bf16 unified vector and bit-packed
+        uint32 mask words, or with ``code_masks`` the rows
+        entropy-coded into a Golomb-Rice byte stream.  ``fingerprint``
+        stamps the layout manifest the task vectors were flattened
+        through (``TaskVectorSpace.fingerprint``) so the store can
+        verify the handoff before serving anything.
         """
         if self.last_task_vectors is None:
             raise ValueError("serving_downlink needs a completed round "
@@ -158,76 +152,7 @@ class MaTUServer:
             return ClientDownlink(unified.astype(jnp.bfloat16),
                                   jnp.asarray(stream), lams,
                                   fingerprint=fingerprint)
-        if packed:
-            from repro.kernels.bitpack import pack_bits
-            return ClientDownlink(unified.astype(jnp.bfloat16),
-                                  pack_bits(masks), lams,
-                                  fingerprint=fingerprint)
-        return ClientDownlink(unified, masks, lams, fingerprint=fingerprint)
-
-    # ------------------------------------------------------------------
-    # Legacy reference path: the original host-bound per-task loop.
-    # Kept as the parity oracle for tests/test_round_engine.py and the
-    # baseline for benchmarks/bench_round_engine.py.  Matches the
-    # engine to fp tolerance (the engine's unheld-task m̂ differs — 0
-    # vs 1 here — which is unobservable in task vectors and downlinks).
-    # ------------------------------------------------------------------
-    def round_legacy(self, uploads: List[ClientUpload]
-                     ) -> Dict[int, ClientDownlink]:
-        cfg = self.cfg
-        d = int(uploads[0].unified.shape[0])
-
-        # ---- Eq. 3 + 4 per task, stacking only members -------------------
-        tau_hats = jnp.zeros((cfg.n_tasks, d), jnp.float32)
-        m_hats = jnp.ones((cfg.n_tasks, d), jnp.float32)
-        held = [False] * cfg.n_tasks
-        for t in range(cfg.n_tasks):
-            rows, row_masks, row_lams, row_sizes = [], [], [], []
-            for up in uploads:
-                if t in up.task_ids:
-                    i = up.task_ids.index(t)
-                    # accept wire-format uploads too: dense the packed
-                    # mask words and upcast a bf16 vector so the oracle
-                    # computes in fp32 like always
-                    rows.append(jnp.asarray(up.unified, jnp.float32))
-                    row_masks.append(up.masks_dense()[i])
-                    row_lams.append(up.lams[i])
-                    row_sizes.append(float(up.data_sizes[i]))
-            if not rows:
-                continue
-            held[t] = True
-            unified = jnp.stack(rows)
-            masks = jnp.stack(row_masks)
-            lams = jnp.asarray(row_lams, jnp.float32)
-            sizes = jnp.asarray(row_sizes, jnp.float32)
-            member = jnp.ones((len(rows),), bool)
-            th, mh = task_aggregate(unified, masks, lams, member, sizes, cfg.rho)
-            tau_hats = tau_hats.at[t].set(th)
-            m_hats = m_hats.at[t].set(mh)
-
-        # ---- Eq. 5 + 6 across tasks --------------------------------------
-        sim = sign_similarity(tau_hats)
-        held_arr = jnp.asarray(held)
-        # never transfer from/to tasks nobody held this round
-        sim = sim * held_arr[None, :] * held_arr[:, None]
-        if not cfg.cross_task:
-            weights = jnp.zeros_like(sim)
-        elif cfg.uniform_cross:
-            t = sim.shape[0]
-            weights = ((1.0 - jnp.eye(t)) * held_arr[None, :] * held_arr[:, None])
-            weights = weights / jnp.maximum(jnp.sum(weights, 1, keepdims=True), 1.0)
-        else:
-            weights = topk_similar(sim, cfg.eps, cfg.kappa)
-        tau_tildes = cross_task_aggregate(tau_hats, m_hats, weights)
-        task_vectors = combine_round(tau_hats, tau_tildes, weights)
-
-        self.last_similarity = sim
-        self.last_task_vectors = task_vectors
-
-        # ---- per-client re-unification + downlink ------------------------
-        out: Dict[int, ClientDownlink] = {}
-        for up in uploads:
-            tvs = jnp.stack([task_vectors[t] for t in up.task_ids])
-            unified, masks, lams = unify_with_modulators(tvs)
-            out[up.client_id] = ClientDownlink(unified, masks, lams)
-        return out
+        from repro.kernels.bitpack import pack_bits
+        return ClientDownlink(unified.astype(jnp.bfloat16),
+                              pack_bits(masks), lams,
+                              fingerprint=fingerprint)

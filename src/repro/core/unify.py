@@ -84,7 +84,7 @@ def unify_masked(task_vectors: jax.Array, valid: jax.Array) -> jax.Array:
     them (zeros change neither the sign sum nor the aligned max), so
     ``unify_masked(x, v) == unify(x[v])``.  This is the reference
     semantics of the fused batched kernel
-    (:func:`repro.kernels.ops.fused_unify`).
+    (:func:`repro.kernels.ops.fused_unify_packed`).
     """
     x = task_vectors * valid.astype(task_vectors.dtype)[:, None]
     sigma = jnp.sign(jnp.sum(x, axis=0))

@@ -43,8 +43,7 @@ rounds flatten each client's delta through its own manifest and
 zero-pad to the round's common d — a multiple of 256 coords
 (``8 × bitpack.WORD_BITS`` = one ``ref.LAMBDA_BLOCK``, the PR 3
 word-boundary rule), so the packed uint32 wire words and the λ
-reduction blocks of every backbone's prefix stay aligned and the
-packed/bool layouts remain bit-identical.
+reduction blocks of every backbone's prefix stay aligned.
 """
 
 from __future__ import annotations

@@ -1,24 +1,21 @@
-"""Pallas TPU kernel: fused unify + task-mask + λ-scaler (Eq. 2 + §3.2
-modulators), batched over clients.
+"""Pallas TPU kernels: fused unify + task-mask + λ-scaler (Eq. 2 + §3.2
+modulators), batched over clients, emitting the wire layout.
 
 Downlink construction re-unifies every client's task vectors each
 round.  Composed from the three reference ops this reads the (K, d)
 stack three times (unify, mask, scaler) and materialises the unified
 vector plus the mask stack in HBM between passes; per round that is
-O(N·K·d) extra traffic on the server's hottest loop.  This kernel
-streams each client's (K, BD) tile through VMEM once and emits the
-unified block, the mask block, and the partial λ numerator/denominator
-sums in a single pass.
+O(N·K·d) extra traffic on the server's hottest loop.  These kernels
+stream each client's (K, BD) tile through VMEM once and emit the bf16
+unified block, the mask block packed 32 bits a uint32 word, and the
+partial λ numerator/denominator sums in a single pass.
 
-Layout: grid (B, d/BD), d innermost so the per-(client, slot) scalar
-accumulators (num, den) are revisited across the d sweep (zeroed on the
-first step, accumulated after — same pattern as the sign_sim kernel).
-Slot validity handles ragged k_n: invalid slots are zeroed before the
-sign election and excluded from masks, so outputs match per-client
-``unify_with_modulators`` on the valid rows exactly.
-
-Masks are emitted as fp32 {0, 1} (bool outputs hit int8 tiling
-constraints for small K); the dispatch layer casts back to bool.
+:func:`fused_unify_packed_pallas` reads a (B, K, d) slot stack (the
+client side); :func:`fused_unify_packed_rows_pallas` reads each
+client's slot rows out of the (T, d) task vectors (the server's
+downlink).  Slot validity handles ragged k_n: invalid slots are zeroed
+before the sign election and excluded from masks, so outputs match
+per-client ``unify_with_modulators`` on the valid rows exactly.
 """
 
 from __future__ import annotations
@@ -32,12 +29,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import bitpack
 
-BLOCK_D = 2048
 BLOCK_D_PACKED = 4096       # 128 uint32 words per tile (one lane tile)
 
 
 def _unify_tile(x, v):
-    """Shared tile body over one client's (K, BD) task-vector tile and
+    """Tile body of both kernels over one client's (K, BD) task-vector tile and
     its (K, 1) fp32 {0,1} slot validity: returns (τ (1, BD), mask
     (K, BD) fp32 {0,1}, and this tile's λ partial sums num, den (K, 1)).
     Every operand is 2-D (K, ·) or (·, 1), the layouts Mosaic tiles
@@ -54,10 +50,14 @@ def _unify_tile(x, v):
     return tau, mask, num, den
 
 
-def _accumulate(num_ref, den_ref, num, den):
-    """Fold one tile's λ partial sums into the client's (1, K, 1)
-    num/den blocks, revisited across the d sweep (grid axis 1): zeroed
-    on the first step, accumulated after."""
+def _fused_unify_packed_kernel(tv_ref, valid_ref, uni_ref, mask_ref,
+                               num_ref, den_ref):
+    # mask bits decided on the fp32 tau BEFORE the bf16 rounding of the
+    # emitted unified vector
+    tau, mask, num, den = _unify_tile(tv_ref[0], valid_ref[0])
+
+    # the client's (1, K, 1) num/den blocks are revisited across the d
+    # sweep (grid axis 1): zeroed on the first step, accumulated after
     @pl.when(pl.program_id(1) == 0)
     def _init():
         num_ref[...] = jnp.zeros_like(num_ref)
@@ -65,21 +65,6 @@ def _accumulate(num_ref, den_ref, num, den):
 
     num_ref[0] += num
     den_ref[0] += den
-
-
-def _fused_unify_kernel(tv_ref, valid_ref, uni_ref, mask_ref, num_ref, den_ref):
-    tau, mask, num, den = _unify_tile(tv_ref[0], valid_ref[0])
-    _accumulate(num_ref, den_ref, num, den)
-    uni_ref[0] = tau.astype(uni_ref.dtype)
-    mask_ref[0] = mask.astype(mask_ref.dtype)
-
-
-def _fused_unify_packed_kernel(tv_ref, valid_ref, uni_ref, mask_ref,
-                               num_ref, den_ref):
-    # mask bits decided on the fp32 tau BEFORE the bf16 rounding of the
-    # emitted unified vector — bit-identical to the bool/fp32 kernel
-    tau, mask, num, den = _unify_tile(tv_ref[0], valid_ref[0])
-    _accumulate(num_ref, den_ref, num, den)
     uni_ref[0] = tau.astype(uni_ref.dtype)
     mask_ref[0] = bitpack.pack_tile(mask)
 
@@ -108,32 +93,20 @@ def _fused_unify_rows_kernel(tids_ref, tv_ref, valid_ref, uni_ref, mask_ref,
     mask_ref[0] = bitpack.pack_tile(mask)
 
 
-def _fused_unify_call(task_vectors, valid, *, block_d, interpret, packed):
-    """Grid (B, d/BD) launch shared by both variants.  Per-client rows
-    carry a unit axis — unified (B, 1, dp), valid/num/den (B, K, 1) — and
-    packed words are emitted along the lanes, (B, K, dp/32) (see
-    ``bitpack.pack_tile``; ``block_d`` a multiple of 4096), so the last
-    two block dims always equal the array's or tile (8, 128).  Returns
-    the padded-d outputs."""
+def _fused_unify_call(task_vectors, valid, *, block_d, interpret):
+    """Grid (B, d/BD) launch.  Per-client rows carry a unit axis —
+    unified (B, 1, dp), valid/num/den (B, K, 1) — and packed words are
+    emitted along the lanes, (B, K, dp/32) (see ``bitpack.pack_tile``;
+    ``block_d`` a multiple of 4096), so the last two block dims always
+    equal the array's or tile (8, 128).  Returns the padded-d outputs."""
     b, k, d = task_vectors.shape
     pad = (-d) % block_d
     if pad:
         task_vectors = jnp.pad(task_vectors, ((0, 0), (0, 0), (0, pad)))
     dp = d + pad
-    if packed:
-        kernel, uni_dtype = _fused_unify_packed_kernel, jnp.bfloat16
-        name = "fused_unify_packed_pallas"
-        mask_spec = pl.BlockSpec((1, k, block_d // 32),
-                                 lambda i, j: (i, 0, j))
-        mask_out = jax.ShapeDtypeStruct((b, k, dp // 32), jnp.uint32)
-    else:
-        kernel, uni_dtype = _fused_unify_kernel, jnp.float32
-        name = "fused_unify_pallas"
-        mask_spec = pl.BlockSpec((1, k, block_d), lambda i, j: (i, 0, j))
-        mask_out = jax.ShapeDtypeStruct((b, k, dp), jnp.float32)
     scalar_spec = pl.BlockSpec((1, k, 1), lambda i, j: (i, 0, 0))
     unified, masks, num, den = pl.pallas_call(
-        kernel,
+        _fused_unify_packed_kernel,
         grid=(b, dp // block_d),
         in_specs=[
             pl.BlockSpec((1, k, block_d), lambda i, j: (i, 0, j)),
@@ -141,62 +114,43 @@ def _fused_unify_call(task_vectors, valid, *, block_d, interpret, packed):
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_d), lambda i, j: (i, 0, j)),
-            mask_spec,
+            pl.BlockSpec((1, k, block_d // 32), lambda i, j: (i, 0, j)),
             scalar_spec,
             scalar_spec,
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, 1, dp), uni_dtype),
-            mask_out,
+            jax.ShapeDtypeStruct((b, 1, dp), jnp.bfloat16),
+            jax.ShapeDtypeStruct((b, k, dp // 32), jnp.uint32),
             jax.ShapeDtypeStruct((b, k, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, k, 1), jnp.float32),
         ],
         interpret=interpret,
-        name=name,
+        name="fused_unify_packed_pallas",
     )(task_vectors, valid.astype(jnp.float32).reshape(b, k, 1))
     return unified[:, 0], masks, num[:, :, 0], den[:, :, 0]
-
-
-@functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
-def fused_unify_pallas(task_vectors: jax.Array, valid: jax.Array, *,
-                       block_d: int = BLOCK_D, interpret: bool):
-    """task_vectors (B, K, d); valid (B, K) bool/{0,1}.
-
-    Returns (unified (B, d), masks (B, K, d) fp32 {0,1}, num (B, K),
-    den (B, K)); λ = num / max(den, eps) is computed by the caller so
-    eps policy stays in one place (invalid slots: num = den = 0).
-    Zero-padding d is safe: padded lanes contribute nothing to num/den
-    and are sliced off the streamed outputs.
-    """
-    d = task_vectors.shape[-1]
-    unified, masks, num, den = _fused_unify_call(
-        task_vectors, valid, block_d=block_d, interpret=interpret,
-        packed=False)
-    return unified[:, :d], masks[:, :, :d], num, den
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def fused_unify_packed_pallas(task_vectors: jax.Array, valid: jax.Array, *,
                               block_d: int = BLOCK_D_PACKED,
                               interpret: bool):
-    """Wire-format variant of :func:`fused_unify_pallas`: consumes bf16
-    (or fp32) slot stacks and emits the wire tensors directly — bf16
-    unified vectors and bit-packed uint32 mask words, packed 32 lanes
-    per word inside the kernel so the (B, K, d) mask never exists in
-    HBM at more than 1 bit per element.
+    """task_vectors (B, K, d) bf16 or fp32 slot stacks; valid (B, K)
+    bool/{0,1}.  Emits the wire tensors directly — bf16 unified vectors
+    and bit-packed uint32 mask words, packed 32 lanes per word inside
+    the kernel so the (B, K, d) mask never exists in HBM at more than 1
+    bit per element.
 
     Returns (unified (B, d) bf16, mask_words (B, K, ceil(d/32)) uint32,
     num (B, K), den (B, K)); λ = num / max(den, eps) is left to the
-    caller.  Compute is fp32 per tile; mask bits and num/den are derived
-    from the fp32 values before the bf16 rounding — masks are
-    bit-identical to the bool kernel's, while num/den accumulate over
-    4096-wide tiles (vs the bool kernel's 2048) so they match to fp32
-    accumulation tolerance, not bitwise, for d > 2048.
+    caller so eps policy stays in one place (invalid slots: num = den =
+    0).  Compute is fp32 per tile; mask bits and num/den are derived
+    from the fp32 values before the bf16 rounding.  Zero-padding d is
+    safe: padded lanes contribute nothing to num/den and are sliced off
+    the streamed outputs.
     """
     d = task_vectors.shape[-1]
     unified, words, num, den = _fused_unify_call(
-        task_vectors, valid, block_d=block_d, interpret=interpret,
-        packed=True)
+        task_vectors, valid, block_d=block_d, interpret=interpret)
     return unified[:, :d], words[:, :, :bitpack.packed_width(d)], num, den
 
 

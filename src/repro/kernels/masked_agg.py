@@ -1,4 +1,4 @@
-"""Pallas TPU kernels: fused Eq. 3 + Eq. 4 (agreement mask + task merge).
+"""Pallas TPU kernel: fused Eq. 3 + Eq. 4 (agreement mask + task merge).
 
 Per task t the server computes, over the N_t member clients,
   α_j  = |Σ_n sgn(m_n ⊙ τ_n)_j| / N_t
@@ -7,22 +7,20 @@ Per task t the server computes, over the N_t member clients,
 
 A naive composition reads the (N, d) stack three times (sign-sum,
 agreement compare, weighted sum) and materialises two (N, d)
-intermediates in HBM.  The kernels stream each (N, BD) block through
+intermediates in HBM.  The kernel streams each (N, BD) block through
 VMEM once, producing both outputs.
 
-The round's kernel, :func:`masked_agg_batched_packed_rows_pallas`,
-reads the wire slot layout as uploaded: (N, d) unified vectors and
-(N, K, d/32) mask words, K slots per client, each slot naming its task.
-Its grid runs over d alone.  A step unpacks the words of every slot of
-one d-block once — N·K rows, only the member work — and folds the
-slots into their tasks with a 0/1 slot → task matmul on the MXU, so
-HBM traffic is one read of the unified vectors and the slot words and
-one write of the two (T, d) outputs; no dense (N, T, ·) tensor is
-made.  The bool kernels below keep the dense (N, T, d) layout and
-serve as oracles.
+:func:`masked_agg_batched_packed_rows_pallas` reads the wire slot
+layout as uploaded: (N, d) unified vectors and (N, K, d/32) mask words,
+K slots per client, each slot naming its task.  Its grid runs over d
+alone.  A step unpacks the words of every slot of one d-block once —
+N·K rows, only the member work — and folds the slots into their tasks
+with a 0/1 slot → task matmul on the MXU, so HBM traffic is one read of
+the unified vectors and the slot words and one write of the two (T, d)
+outputs; no dense (N, T, ·) tensor is made.
 
-The per-client scalars (λ, γ) are small (N ≤ 64) and ride fully
-resident; ρ is compile-time static.
+The per-slot scalars (γ·λ) are small (N ≤ 64) and ride fully resident;
+ρ is compile-time static.
 """
 
 from __future__ import annotations
@@ -35,96 +33,9 @@ from jax.experimental import pallas as pl
 
 from repro.kernels import bitpack
 
-BLOCK_D = 2048
-# the packed kernel tiles 32 mask bits per word: 4096 elements = 128
-# words, exactly one uint32 lane tile
+# the kernel tiles 32 mask bits per word: 4096 elements = 128 words,
+# exactly one uint32 lane tile
 BLOCK_D_PACKED = 4096
-
-
-def _masked_agg_kernel(u_ref, m_ref, lam_ref, gam_ref, tau_ref, mhat_ref, *, rho):
-    u = u_ref[...].astype(jnp.float32)            # (N, BD)
-    m = m_ref[...].astype(jnp.float32)            # (N, BD)
-    lam = lam_ref[...].astype(jnp.float32)        # (N,)
-    gam = gam_ref[...].astype(jnp.float32)        # (N,)
-    member = (gam > 0).astype(jnp.float32)
-    n_t = jnp.maximum(jnp.sum(member), 1.0)
-    masked = u * m
-    signs = jnp.sign(masked)
-    alpha = jnp.abs(jnp.sum(member[:, None] * signs, axis=0)) / n_t
-    m_hat = jnp.where(alpha >= rho, 1.0, alpha)
-    weighted = jnp.sum((gam * lam)[:, None] * masked, axis=0)
-    tau_ref[...] = (weighted * m_hat).astype(tau_ref.dtype)
-    mhat_ref[...] = m_hat.astype(mhat_ref.dtype)
-
-
-def _masked_agg_batched_kernel(u_ref, m_ref, lam_ref, gam_ref, mem_ref,
-                               tau_ref, mhat_ref, *, rho):
-    u = u_ref[...].astype(jnp.float32)            # (N, BD)
-    m = m_ref[...].astype(jnp.float32)            # (N, BD)
-    lam = lam_ref[0]                              # (N, 1)
-    gam = gam_ref[0]
-    mem = mem_ref[0]
-    n_t = jnp.maximum(jnp.sum(mem), 1.0)
-    masked = u * m
-    alpha = jnp.abs(jnp.sum(mem * jnp.sign(masked), axis=0,
-                            keepdims=True)) / n_t
-    m_hat = jnp.where(alpha >= rho, 1.0, alpha)
-    weighted = jnp.sum((gam * lam) * masked, axis=0, keepdims=True)
-    tau_ref[0] = (weighted * m_hat).astype(tau_ref.dtype)
-    mhat_ref[0] = m_hat.astype(mhat_ref.dtype)
-
-
-def _task_major_scalars(*scalars):
-    """(N, T) per-(client, task) scalars -> (T, N, 1) fp32 columns, so
-    one task's block (1, N, 1) spans the whole trailing two dims and
-    broadcasts against an (N, BD) tile without a relayout."""
-    return [jnp.transpose(x.astype(jnp.float32))[:, :, None] for x in scalars]
-
-
-@functools.partial(jax.jit, static_argnames=("rho", "block_d", "interpret"))
-def masked_agg_batched_pallas(unified: jax.Array, masks: jax.Array,
-                              lams: jax.Array, gammas: jax.Array,
-                              members: jax.Array, *, rho: float = 0.4,
-                              block_d: int = BLOCK_D, interpret: bool):
-    """Whole-round Eq. 3 + Eq. 4: every task in one launch.
-
-    unified (N, d); masks (N, T, d) {0,1} (zero rows off-membership);
-    lams/gammas/members (N, T).  ``members`` is the explicit A(n, t)
-    allocation (the agreement denominator N_t counts members even when
-    their data weight is zero, matching ``matu_round``).
-
-    Grid is (T, d/BD): each program streams one (N, BD) lane block of
-    one task through VMEM, so the (N, T, d) mask tensor is read exactly
-    once and no (T, d) intermediate ever round-trips to HBM.
-    Returns (tau_hats (T, d), m_hats (T, d)) in fp32.
-    """
-    n, d = unified.shape
-    t = masks.shape[1]
-    pad = (-d) % block_d
-    if pad:
-        unified = jnp.pad(unified, ((0, 0), (0, pad)))
-        masks = jnp.pad(masks, ((0, 0), (0, 0), (0, pad)))
-    dp = d + pad
-    nblk = dp // block_d
-    kernel = functools.partial(_masked_agg_batched_kernel, rho=rho)
-    # the (N, T, d) mask tensor is viewed as (N, T·dp), a free row-major
-    # reshape, so task i's block j is column block i·nblk + j and the
-    # last two block dims are (N, lane tile), never (1, ·)
-    scalar = pl.BlockSpec((1, n, 1), lambda i, j: (i, 0, 0))
-    out = pl.BlockSpec((1, 1, block_d), lambda i, j: (i, 0, j))
-    tau, m_hat = pl.pallas_call(
-        kernel,
-        grid=(t, nblk),
-        in_specs=[pl.BlockSpec((n, block_d), lambda i, j: (0, j)),
-                  pl.BlockSpec((n, block_d), lambda i, j: (0, i * nblk + j)),
-                  scalar, scalar, scalar],
-        out_specs=[out, out],
-        out_shape=[jax.ShapeDtypeStruct((t, 1, dp), jnp.float32)] * 2,
-        interpret=interpret,
-        name="masked_agg_batched_pallas",
-    )(unified, masks.astype(unified.dtype).reshape(n, t * dp),
-      *_task_major_scalars(lams, gammas, members))
-    return tau[:, 0, :d], m_hat[:, 0, :d]
 
 
 def _split3(x):
@@ -236,43 +147,3 @@ def masked_agg_batched_packed_rows_pallas(unified: jax.Array,
         interpret=interpret,
         name="masked_agg_batched_packed_rows_pallas",
     )(unified, words, weights, sel, nt)
-
-
-@functools.partial(jax.jit, static_argnames=("rho", "block_d", "interpret"))
-def masked_agg_pallas(unified: jax.Array, masks: jax.Array, lams: jax.Array,
-                      gammas: jax.Array, *, rho: float = 0.4,
-                      block_d: int = BLOCK_D, interpret: bool):
-    """unified (N,d); masks (N,d) {0,1}; lams/gammas (N,).
-
-    gammas must be the normalised membership weights (0 for
-    non-members); N_t is inferred as the count of positive gammas.
-    Returns (tau_hat (d,), m_hat (d,)) in fp32.
-    """
-    n, d = unified.shape
-    pad = (-d) % block_d
-    if pad:
-        unified = jnp.pad(unified, ((0, 0), (0, pad)))
-        masks = jnp.pad(masks, ((0, 0), (0, pad)))
-    dp = d + pad
-    kernel = functools.partial(_masked_agg_kernel, rho=rho)
-    tau, m_hat = pl.pallas_call(
-        kernel,
-        grid=(dp // block_d,),
-        in_specs=[
-            pl.BlockSpec((n, block_d), lambda i: (0, i)),
-            pl.BlockSpec((n, block_d), lambda i: (0, i)),
-            pl.BlockSpec((n,), lambda i: (0,)),
-            pl.BlockSpec((n,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_d,), lambda i: (i,)),
-            pl.BlockSpec((block_d,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((dp,), jnp.float32),
-            jax.ShapeDtypeStruct((dp,), jnp.float32),
-        ],
-        interpret=interpret,
-        name="masked_agg_pallas",
-    )(unified, masks.astype(unified.dtype), lams, gammas)
-    return tau[:d], m_hat[:d]

@@ -7,8 +7,8 @@ Three dispatch modes:
   "pallas_interpret"  kernel bodies executed by the Pallas interpreter
                       (bit-identical to the TPU lowering; validation
                       path, far too slow for the CPU hot loop)
-  "ref"               pure-jnp oracles (repro.kernels.ref) — the fast
-                      XLA path on CPU/GPU
+  "ref"               pure-jnp streaming implementations
+                      (repro.kernels.ref) — the fast XLA path on CPU/GPU
 
 Resolution (``resolve_mode``): ``REPRO_DISABLE_PALLAS=1`` forces "ref"
 everywhere; on TPU the default is "pallas"; elsewhere the default is
@@ -17,10 +17,10 @@ validation.  Every op also takes an explicit ``mode=`` so jitted
 callers (the round engine) can resolve once per call and key their jit
 cache on it instead of re-reading the environment at trace time.
 
-The small (T, T)-sized Eq. 6–7 ops (top-κ filter, cross-task combine)
-have no Pallas kernel — a (T, T) top-k plus a (T, T)·(T, d) MXU matmul
-is already optimal under XLA — but are still routed through here so no
-jnp-only server path remains outside this module.
+Every op works on the wire layout (bf16 vectors, uint32 mask words;
+see ``repro.kernels.bitpack``).  The dense reference semantics the ops
+are tested against live in ``repro.core.aggregation`` and
+``repro.core.unify``.
 """
 
 from __future__ import annotations
@@ -33,15 +33,11 @@ import jax.numpy as jnp
 
 from repro.kernels import bitpack, ref
 from repro.kernels.fused_unify import (fused_unify_packed_pallas,
-                                       fused_unify_packed_rows_pallas,
-                                       fused_unify_pallas)
-from repro.kernels.masked_agg import (masked_agg_batched_packed_rows_pallas,
-                                      masked_agg_batched_pallas,
-                                      masked_agg_pallas)
+                                       fused_unify_packed_rows_pallas)
+from repro.kernels.masked_agg import masked_agg_batched_packed_rows_pallas
 from repro.kernels.modulated_matmul import (modulated_matmul_pallas,
                                             routed_matmul_pallas)
-from repro.kernels.sign_sim import sign_sim_packed_pallas, sign_sim_pallas
-from repro.kernels.unify import unify_pallas
+from repro.kernels.sign_sim import sign_sim_packed_pallas
 
 MODES = ("pallas", "pallas_interpret", "ref")
 
@@ -65,77 +61,17 @@ def _norm(mode: Optional[str]) -> str:
     return mode
 
 
-def unify(task_vectors: jax.Array, *, mode: Optional[str] = None) -> jax.Array:
-    """(K, d) -> (d,) task unification (Eq. 2)."""
-    mode = _norm(mode)
-    if mode == "ref":
-        return ref.unify_ref(task_vectors)
-    return unify_pallas(task_vectors, interpret=(mode == "pallas_interpret"))
-
-
-def masked_agg(unified, masks, lams, gammas, *, rho: float = 0.4,
-               mode: Optional[str] = None):
-    """Single-task Eq. 3 + Eq. 4 (membership inferred from gammas>0)."""
-    mode = _norm(mode)
-    if mode == "ref":
-        return ref.masked_agg_ref(unified, masks, lams, gammas, rho)
-    return masked_agg_pallas(unified, masks, lams, gammas, rho=rho,
-                             interpret=(mode == "pallas_interpret"))
-
-
-def masked_agg_batched(unified, masks, lams, gammas, members, *,
-                       rho: float = 0.4, mode: Optional[str] = None):
-    """Whole-round Eq. 3 + Eq. 4 over packed (N, T, d) tensors."""
-    mode = _norm(mode)
-    if mode == "ref":
-        return ref.masked_agg_batched_ref(unified, masks, lams, gammas,
-                                          members, rho)
-    return masked_agg_batched_pallas(unified, masks, lams, gammas, members,
-                                     rho=rho,
-                                     interpret=(mode == "pallas_interpret"))
-
-
-def sign_sim(tau_hats: jax.Array, *, mode: Optional[str] = None) -> jax.Array:
-    """Eq. 5 sign-conflict similarity (T, d) -> (T, T)."""
-    mode = _norm(mode)
-    if mode == "ref":
-        return ref.sign_sim_ref(tau_hats)
-    return sign_sim_pallas(tau_hats, interpret=(mode == "pallas_interpret"))
-
-
 def fused_unify_raw(task_vectors: jax.Array, valid: jax.Array, *,
-                    packed: bool = True, mode: Optional[str] = None):
-    """Division-free core of :func:`fused_unify` /
-    :func:`fused_unify_packed`: returns (unified, masks-or-words,
-    num, den) with the λ division left to the caller — the hook the
-    sharded engine needs to ``psum`` the per-shard λ partial sums
-    before dividing."""
+                    mode: Optional[str] = None):
+    """Division-free core of :func:`fused_unify_packed`: returns
+    (unified, mask words, num, den) with the λ division left to the
+    caller — the hook the sharded engine needs to ``psum`` the
+    per-shard λ partial sums before dividing."""
     mode = _norm(mode)
-    if packed:
-        if mode == "ref":
-            return ref.fused_unify_packed_ref(task_vectors, valid)
-        return fused_unify_packed_pallas(
-            task_vectors, valid, interpret=(mode == "pallas_interpret"))
     if mode == "ref":
-        return ref.fused_unify_ref(task_vectors, valid)
-    unified, masks, num, den = fused_unify_pallas(
+        return ref.fused_unify_packed_ref(task_vectors, valid)
+    return fused_unify_packed_pallas(
         task_vectors, valid, interpret=(mode == "pallas_interpret"))
-    return unified, masks > 0.5, num, den
-
-
-def fused_unify(task_vectors: jax.Array, valid: jax.Array, *,
-                eps: float = 1e-12, mode: Optional[str] = None):
-    """Batched unify + task-mask + λ-scaler over slot-packed clients.
-
-    task_vectors (B, K, d); valid (B, K) bool.  Returns
-    (unified (B, d), masks (B, K, d) bool, lams (B, K)) — row b equals
-    ``unify_with_modulators(task_vectors[b, valid[b]])`` on the valid
-    slots; invalid slots give zero mask rows and λ = 0.
-    """
-    unified, masks, num, den = fused_unify_raw(task_vectors, valid,
-                                               packed=False, mode=mode)
-    lams = num / jnp.maximum(den, eps)
-    return unified, masks, lams
 
 
 def pack_masks(masks: jax.Array, *, mode: Optional[str] = None) -> jax.Array:
@@ -158,20 +94,20 @@ def unpack_masks(words: jax.Array, d: int, *,
 
 def fused_unify_packed(task_vectors: jax.Array, valid: jax.Array, *,
                        eps: float = 1e-12, mode: Optional[str] = None):
-    """Wire-format :func:`fused_unify`: same math, but emits the uplink
-    tensors — bf16 unified vectors and bit-packed mask words.
+    """Batched unify + task-mask + λ-scaler over slot-packed clients,
+    emitting the uplink wire tensors.
 
     task_vectors (B, K, d) fp32/bf16; valid (B, K) bool.  Returns
     (unified (B, d) bf16, mask_words (B, K, ceil(d/32)) uint32,
-    lams (B, K) fp32).  Mask bits and λ are decided on fp32 values
-    before the bf16 rounding; masks are bit-identical to
-    :func:`fused_unify` on the same inputs in every mode, λ is
-    bit-identical on the "ref" path (same chunking) and matches to
-    fp32 accumulation tolerance on the Pallas paths (different tile
-    width).
+    lams (B, K) fp32) — row b equals
+    ``unify_with_modulators(task_vectors[b, valid[b]])`` on the valid
+    slots, the unified vector rounded to bf16 after the mask bits and λ
+    were decided on its fp32 value; invalid slots give zero mask rows
+    and λ = 0.  λ accumulates over the Pallas kernels' 4096-wide tiles
+    and the ref path's ``CHUNK_D`` chunks, so the modes agree on it to
+    fp32 accumulation tolerance.
     """
-    uni, words, num, den = fused_unify_raw(task_vectors, valid,
-                                           packed=True, mode=mode)
+    uni, words, num, den = fused_unify_raw(task_vectors, valid, mode=mode)
     lams = num / jnp.maximum(den, eps)
     return uni, words, lams
 
@@ -182,27 +118,16 @@ def masked_agg_batched_packed(unified, slot_mask_words, slot_lams, slot_sizes,
                               mode: Optional[str] = None):
     """Whole-round Eq. 3 + Eq. 4 over the wire slot layout: unified
     (N, d) bf16 (fp32 tolerated), slot_mask_words (N, K, ceil(d/32))
-    uint32, per-slot scalars (N, K) as :func:`matu_round_slots`.
+    uint32, per-slot scalars (N, K) as :func:`matu_round_slots_packed`.
     Returns (tau_hats (T, d), alpha_num (T, d)) — m̂ is derivable as
-    ``where(alpha_num/max(N_t,1) >= rho, 1, ·)``.  The "ref" dispatch
-    unpacks, scatters to the dense (N, T, d) layout and delegates to
-    the bool oracle (validation path); the Pallas modes fold each
-    member slot into its task straight from the slot words."""
+    ``where(alpha_num/max(N_t,1) >= rho, 1, ·)``.  The kernel folds each
+    member slot into its task straight from the slot words.  Pallas
+    modes only: the "ref" round streams Eq. 3–4 inside
+    ``ref.matu_round_slots_packed_ref``."""
     mode = _norm(mode)
     if mode == "ref":
-        masks, lams, member, sizes = slots_to_dense(
-            bitpack.unpack_bits(slot_mask_words, d), slot_lams, slot_sizes,
-            slot_valid, slot_tasks, n_tasks)
-        masks = masks.astype(jnp.float32)
-        memf = member.astype(jnp.float32)
-        gam = sizes * memf
-        gam = gam / jnp.maximum(jnp.sum(gam, axis=0, keepdims=True), 1e-12)
-        tau, _ = ref.masked_agg_batched_ref(
-            unified.astype(jnp.float32), masks, lams, gam, member, rho)
-        sign_u = jnp.sign(unified.astype(jnp.float32))
-        a_num = jnp.abs(jnp.einsum("nt,ntd->td", memf,
-                                   masks * sign_u[:, None, :]))
-        return tau, a_num
+        raise ValueError("masked_agg_batched_packed has no 'ref' dispatch; "
+                         "the ref round streams Eq. 3-4 itself")
     return masked_agg_batched_packed_rows_pallas(
         unified, slot_mask_words,
         _slot_gammas(slot_sizes, slot_valid, slot_tasks, n_tasks) * slot_lams,
@@ -221,20 +146,6 @@ def sign_sim_packed(pos: jax.Array, nz: jax.Array, d: int, *,
         dots = sign_sim_packed_pallas(
             pos, nz, interpret=(mode == "pallas_interpret"))
     return 0.5 * (dots / d + 1.0)
-
-
-def topk_weights(sim: jax.Array, *, eps: float = 0.5, kappa: int = 3,
-                 mode: Optional[str] = None) -> jax.Array:
-    """Eq. 6 top-κ neighbourhood weights (XLA-optimal at (T, T) scale)."""
-    _norm(mode)
-    return ref.topk_weights_ref(sim, eps, kappa)
-
-
-def cross_task_combine(tau_hats: jax.Array, m_hats: jax.Array,
-                       sim_weights: jax.Array, *, mode: Optional[str] = None):
-    """Eq. 6 + Eq. 7: returns (task_vectors, tau_tildes)."""
-    _norm(mode)
-    return ref.cross_task_combine_ref(tau_hats, m_hats, sim_weights)
 
 
 def modulated_matmul(x: jax.Array, base: jax.Array, tau: jax.Array,
@@ -278,28 +189,6 @@ def routed_matmul(x: jax.Array, w: jax.Array, *,
     return routed_matmul_pallas(x, w, interpret=(mode == "pallas_interpret"))
 
 
-def slots_to_dense(slot_masks, slot_lams, slot_sizes, slot_valid, slot_tasks,
-                   n_tasks: int):
-    """Scatter slot-packed round tensors to the dense per-task layout
-    ((N, T, d) masks, (N, T) lams/member/sizes).  Sentinel task ids
-    (== n_tasks) are scatter-dropped.  The single definition of the
-    slot→dense contract — used by the bool kernel round path, the "ref"
-    dispatch of :func:`masked_agg_batched_packed` and
-    ``PackedRound.dense_tensors``."""
-    n = slot_masks.shape[0]
-    rows = jnp.arange(n)[:, None]
-
-    def scatter(x, dtype):
-        return jnp.zeros((n, n_tasks) + x.shape[2:], dtype).at[
-            rows, slot_tasks].set(x, mode="drop")
-
-    valid3 = slot_valid[:, :, None]
-    return (scatter(jnp.where(valid3, slot_masks, False), bool),
-            scatter(jnp.where(slot_valid, slot_lams, 0.0), jnp.float32),
-            scatter(slot_valid, bool),
-            scatter(jnp.where(slot_valid, slot_sizes, 0.0), jnp.float32))
-
-
 def _slot_gammas(slot_sizes, slot_valid, slot_tasks, n_tasks: int):
     """Per-slot γ: a valid slot's size over its task's total (0 for
     invalid slots; the sentinel task id gathers its own total)."""
@@ -309,55 +198,6 @@ def _slot_gammas(slot_sizes, slot_valid, slot_tasks, n_tasks: int):
         n * k)
     totals = jax.ops.segment_sum(sizes, ids, num_segments=n_tasks + 1)
     return (sizes / jnp.maximum(totals[ids], 1e-12)).reshape(n, k)
-
-
-def _round_slots_dense(unified, slot_masks, slot_lams, slot_sizes, slot_valid,
-                       slot_tasks, n_tasks, *, rho, eps, kappa, cross_task,
-                       uniform_cross, mode, axis_name=None, d_norm=0):
-    """Kernel-path round: scatter the slot tensors to the dense
-    (N, T, d) layout the Pallas kernels consume, then compose the
-    batched masked-agg, sign-sim, and fused-unify kernels.  On TPU the
-    dense read is a single HBM stream per kernel; on CPU this path is
-    validation-only (interpret mode).
-
-    With ``axis_name`` set the function is a ``shard_map`` body on the
-    local d-slice: the Eq. 5 dots go through the popcount kernel (raw
-    integers — the fused normalised kernel cannot be un-normalised
-    exactly) plus one psum, and the λ num/den partial sums one more —
-    λ agrees with the single-device kernels to fp32 accumulation
-    tolerance (tile grouping differs), the PR 2 Pallas caveat."""
-    masks_d, lams_d, member_d, sizes_d = slots_to_dense(
-        slot_masks, slot_lams, slot_sizes, slot_valid, slot_tasks, n_tasks)
-
-    memf = member_d.astype(jnp.float32)
-    gam = sizes_d * memf
-    gam = gam / jnp.maximum(jnp.sum(gam, axis=0, keepdims=True), 1e-12)
-    tau_hats, m_hats = masked_agg_batched(unified, masks_d, lams_d, gam,
-                                          member_d, rho=rho, mode=mode)
-    held = jnp.any(member_d, axis=0)
-    heldf = held.astype(jnp.float32)
-    if axis_name is None:
-        sim = sign_sim(tau_hats, mode=mode) * heldf[None, :] * heldf[:, None]
-    else:
-        pos, nz = bitpack.sign_planes(tau_hats)
-        dots = sign_sim_packed_pallas(
-            pos, nz, interpret=(mode == "pallas_interpret"))
-        dots = jax.lax.psum(dots, axis_name)
-        sim = (0.5 * (dots.astype(jnp.float32) / d_norm + 1.0)
-               * heldf[None, :] * heldf[:, None])
-    weights = ref.cross_weights_ref(sim, held, eps=eps, kappa=kappa,
-                                    cross_task=cross_task,
-                                    uniform_cross=uniform_cross)
-    task_vectors, _tau_tildes = ref.cross_task_combine_ref(tau_hats, m_hats,
-                                                           weights)
-    # sentinel slot ids are clamped; the valid mask zeroes their output
-    tvs_slots = jnp.take(task_vectors, slot_tasks, axis=0, mode="clip")
-    uni, dmasks, num, den = fused_unify_pallas(
-        tvs_slots, slot_valid, interpret=(mode == "pallas_interpret"))
-    if axis_name is not None:
-        num, den = jax.lax.psum((num, den), axis_name)
-    return (task_vectors, tau_hats, m_hats, sim,
-            uni, dmasks > 0.5, num, den)
 
 
 def _apply_slot_weights(slot_lams, slot_sizes, slot_weights):
@@ -376,65 +216,16 @@ def _apply_slot_weights(slot_lams, slot_sizes, slot_weights):
     return lams, slot_sizes * w
 
 
-def matu_round_slots(unified, slot_masks, slot_lams, slot_sizes, slot_valid,
-                     slot_tasks, n_tasks: int, *, rho: float = 0.4,
-                     eps: float = 0.5, kappa: int = 3,
-                     cross_task: bool = True, uniform_cross: bool = False,
-                     lam_eps: float = 1e-12, mode: Optional[str] = None,
-                     slot_weights=None,
-                     axis_name=None, axis_sizes=(), d_norm: int = 0):
-    """The full MaTU server round over slot-packed uploads — the single
-    entry point of :class:`repro.core.engine.RoundEngine`.
-
-    "ref" runs the two-pass cache-blocked streaming round
-    (O(Σk_n · d) work, d-chunked so accumulators stay cache-resident);
-    the Pallas modes scatter to the dense layout and compose the
-    batched kernels.  Returns (task_vectors, tau_hats, m_hats,
-    similarity, down_unified, down_masks, down_lams).  τ̃ is not
-    materialised (derivable as (2τ − τ̂) on rows with donors).
-
-    ``axis_name`` / ``axis_sizes`` / ``d_norm`` make the op a
-    ``shard_map`` body over the taskvec axis (see the engine's sharding
-    contract): inputs are the local d-slice, ``d_norm`` is the global
-    feature count, and the Eq. 5 dots + λ num/den totals are the only
-    cross-shard collectives.
-
-    ``slot_weights`` (optional, (N, K) fp32) is the async staleness
-    discount — see :func:`_apply_slot_weights`.
-    """
-    mode = _norm(mode)
-    slot_lams, slot_sizes = _apply_slot_weights(slot_lams, slot_sizes,
-                                                slot_weights)
-    kw = dict(rho=rho, eps=eps, kappa=kappa, cross_task=cross_task,
-              uniform_cross=uniform_cross)
-    if mode == "ref":
-        out = ref.matu_round_slots_ref(unified, slot_masks, slot_lams,
-                                       slot_sizes, slot_valid, slot_tasks,
-                                       n_tasks, axis_name=axis_name,
-                                       axis_sizes=axis_sizes, d_norm=d_norm,
-                                       **kw)
-    else:
-        out = _round_slots_dense(unified, slot_masks, slot_lams, slot_sizes,
-                                 slot_valid, slot_tasks, n_tasks,
-                                 mode=mode, axis_name=axis_name,
-                                 d_norm=d_norm, **kw)
-    (task_vectors, tau_hats, m_hats, sim,
-     down_unified, down_masks, num, den) = out
-    down_lams = num / jnp.maximum(den, lam_eps)
-    return (task_vectors, tau_hats, m_hats, sim,
-            down_unified, down_masks, down_lams)
-
-
-def _round_slots_dense_packed(unified, slot_mask_words, slot_lams, slot_sizes,
-                              slot_valid, slot_tasks, n_tasks, d, *, rho, eps,
-                              kappa, cross_task, uniform_cross, mode,
-                              axis_name=None, d_norm=0):
-    """Packed kernel-path round: compose the packed Eq. 3–4 kernel,
-    the popcount sign-sim and the row-reading fused-unify kernels over
-    the wire slot layout.  The Eq. 3–4 kernel reads each d-block of the
-    (N, d) unified vectors and of the (N, K, d/32) slot words once and
-    folds every member slot into its task on the MXU, so no dense
-    per-task mask tensor is made.  The mask stays 1 bit/element in HBM
+def _round_slots_pallas(unified, slot_mask_words, slot_lams, slot_sizes,
+                        slot_valid, slot_tasks, n_tasks, d, *, rho, eps,
+                        kappa, cross_task, uniform_cross, mode,
+                        axis_name=None, d_norm=0):
+    """Kernel-path round: compose the Eq. 3–4 kernel, the popcount
+    sign-sim and the row-reading fused-unify kernels over the wire slot
+    layout.  The Eq. 3–4 kernel reads each d-block of the (N, d)
+    unified vectors and of the (N, K, d/32) slot words once and folds
+    every member slot into its task on the MXU, so no dense per-task
+    mask tensor is made.  The mask stays 1 bit/element in HBM
     end to end; words are expanded to lanes only inside VMEM tiles.
     With ``axis_name`` set this is a ``shard_map`` body on the local
     d-slice: the popcount dots (exact integers) and the λ num/den
@@ -481,18 +272,21 @@ def matu_round_slots_packed(unified, slot_mask_words, slot_lams, slot_sizes,
                             mode: Optional[str] = None,
                             slot_weights=None,
                             axis_name=None, axis_sizes=(), d_norm: int = 0):
-    """The full MaTU server round over wire-format slot uploads — the
-    default entry point of :class:`repro.core.engine.RoundEngine`.
+    """The full MaTU server round (Eq. 3–7 + downlink re-unification)
+    over wire-format slot uploads — the single entry point of
+    :class:`repro.core.engine.RoundEngine`'s monolithic round.
 
     Layout: ``unified`` (N, d) bf16 (fp32 tolerated), ``slot_mask_words``
     (N, K, ceil(d/32)) uint32 bit-packed masks (LSB-first, zero tail
-    bits — see ``repro.kernels.bitpack``); scalars as in
-    :func:`matu_round_slots`.  ``d`` is static (the word axis cannot
-    express it).
+    bits — see ``repro.kernels.bitpack``); ``slot_lams`` /
+    ``slot_sizes`` / ``slot_valid`` (N, K); ``slot_tasks`` (N, K) int32
+    with the sentinel ``n_tasks`` in invalid slots.  ``d`` is static
+    (the word axis cannot express it).
 
-    "ref" runs the two-pass cache-blocked packed streaming round; the
-    Pallas modes compose the packed kernels over the slot layout as it
-    is (:func:`_round_slots_dense_packed`).  Returns (task_vectors fp32,
+    "ref" runs the two-pass cache-blocked streaming round (O(Σk_n · d)
+    work, d-chunked so accumulators stay cache-resident); the Pallas
+    modes compose the kernels over the slot layout as it is
+    (:func:`_round_slots_pallas`).  Returns (task_vectors fp32,
     tau_hats fp32, alpha_num uint8, n_held, similarity, down_unified bf16,
     down_mask_words uint32, down_lams) — m̂ is re-derivable from
     (alpha_num, n_held, ρ) and never materialised in fp32 on the hot
@@ -517,7 +311,7 @@ def matu_round_slots_packed(unified, slot_mask_words, slot_lams, slot_sizes,
             slot_tasks, n_tasks, d, axis_name=axis_name,
             axis_sizes=axis_sizes, d_norm=d_norm, **kw)
     else:
-        out = _round_slots_dense_packed(
+        out = _round_slots_pallas(
             unified, slot_mask_words, slot_lams, slot_sizes, slot_valid,
             slot_tasks, n_tasks, d, mode=mode, axis_name=axis_name,
             d_norm=d_norm, **kw)
@@ -554,25 +348,13 @@ def matu_chunk_scalars(slot_sizes, slot_valid, slot_tasks, totals_acc,
                                       totals_acc, nt_acc)
 
 
-def matu_merge_chunk(unified, slot_masks, slot_lams, slot_sizes, slot_valid,
-                     slot_tasks, totals, a_acc, tau_acc, *,
-                     slot_weights=None, mode: Optional[str] = None):
-    """Phase B of the chunked round, bool/fp32 layout: fold one client
-    chunk's Eq. 3 sign votes and Eq. 4 merge partials into the carried
-    (T+1, dp) fp32 accumulators (``totals`` from phase A)."""
-    _norm(mode)
-    slot_lams, slot_sizes = _apply_slot_weights(slot_lams, slot_sizes,
-                                                slot_weights)
-    return ref.matu_merge_chunk_ref(unified, slot_masks, slot_lams,
-                                    slot_sizes, slot_valid, slot_tasks,
-                                    totals, a_acc, tau_acc)
-
-
 def matu_merge_chunk_packed(unified, slot_mask_words, slot_lams, slot_sizes,
                             slot_valid, slot_tasks, totals, a_acc, tau_acc,
                             d: int, *, slot_weights=None,
                             mode: Optional[str] = None):
-    """Phase B, wire layout: ``a_acc`` is (T+1, dp) int32 (exact sign
+    """Phase B of the chunked round: fold one client chunk's Eq. 3 sign
+    votes and Eq. 4 merge partials into the carried accumulators
+    (``totals`` from phase A): ``a_acc`` is (T+1, dp) int32 (exact sign
     votes), ``tau_acc`` (T+1, dp) fp32; ``d`` is static (local count
     under ``shard_map``)."""
     _norm(mode)
@@ -584,31 +366,16 @@ def matu_merge_chunk_packed(unified, slot_mask_words, slot_lams, slot_sizes,
                                            tau_acc, d=d)
 
 
-def matu_finish(a_acc, tau_acc, nt_acc, *, n_tasks: int, d: int,
-                rho: float = 0.4, eps: float = 0.5, kappa: int = 3,
-                cross_task: bool = True, uniform_cross: bool = False,
-                mode: Optional[str] = None,
-                axis_name=None, axis_sizes=(), d_norm: int = 0):
-    """Finish the chunked bool-layout round from the accumulators:
-    returns (task_vectors, tau_hats, m_hats, n_t, similarity, num_t)."""
-    _norm(mode)
-    return ref.matu_finish_ref(a_acc, tau_acc, nt_acc, n_tasks=n_tasks, d=d,
-                               rho=rho, eps=eps, kappa=kappa,
-                               cross_task=cross_task,
-                               uniform_cross=uniform_cross,
-                               axis_name=axis_name, axis_sizes=axis_sizes,
-                               d_norm=d_norm)
-
-
 def matu_finish_packed(a_acc, tau_acc, nt_acc, n_clients: int, *,
                        n_tasks: int, d: int, rho: float = 0.4,
                        eps: float = 0.5, kappa: int = 3,
                        cross_task: bool = True, uniform_cross: bool = False,
                        mode: Optional[str] = None,
                        axis_name=None, axis_sizes=(), d_norm: int = 0):
-    """Finish the chunked packed round: returns (task_vectors, tau_hats,
-    alpha_num, n_t, similarity, num_t).  ``n_clients`` is the round's
-    total client count (it picks the monolithic ``alpha_dtype``)."""
+    """Finish the chunked round from the accumulators: returns
+    (task_vectors, tau_hats, alpha_num, n_t, similarity, num_t).
+    ``n_clients`` is the round's total client count (it picks the
+    monolithic ``alpha_dtype``)."""
     _norm(mode)
     return ref.matu_finish_packed_ref(a_acc, tau_acc, nt_acc, n_clients,
                                       n_tasks=n_tasks, d=d, rho=rho, eps=eps,
@@ -618,28 +385,14 @@ def matu_finish_packed(a_acc, tau_acc, nt_acc, n_clients: int, *,
                                       axis_sizes=axis_sizes, d_norm=d_norm)
 
 
-def matu_downlink_chunk(task_vectors, slot_valid, slot_tasks, num_t, *,
-                        n_tasks: int, lam_eps: float = 1e-12,
-                        mode: Optional[str] = None,
-                        axis_name=None, axis_sizes=()):
-    """Phase C, bool layout: downlink re-unification of one client chunk
-    from the finished task vectors.  Returns (down_unified (C, d) fp32,
-    down_masks (C, K, d) bool, down_lams (C, K)) — the λ division is
-    the monolithic round's ``num / max(den, lam_eps)``."""
-    _norm(mode)
-    uni, dmasks, num, den = ref.matu_downlink_chunk_ref(
-        task_vectors, slot_valid, slot_tasks, num_t, n_tasks=n_tasks,
-        axis_name=axis_name, axis_sizes=axis_sizes)
-    down_lams = num / jnp.maximum(den, lam_eps)
-    return uni, dmasks, down_lams
-
-
 def matu_downlink_chunk_packed(task_vectors, slot_tasks, num_t, d: int, *,
                                lam_eps: float = 1e-12,
                                mode: Optional[str] = None,
                                axis_name=None, axis_sizes=()):
-    """Phase C, wire layout: returns (down_unified (C, d) bf16,
-    down_mask_words (C, K, ceil(d/32)) uint32, down_lams (C, K))."""
+    """Phase C: downlink re-unification of one client chunk from the
+    finished task vectors.  Returns (down_unified (C, d) bf16,
+    down_mask_words (C, K, ceil(d/32)) uint32, down_lams (C, K)) — the
+    λ division is the monolithic round's ``num / max(den, lam_eps)``."""
     _norm(mode)
     uni, dwords, num, den = ref.matu_downlink_chunk_packed_ref(
         task_vectors, slot_tasks, num_t, d=d,

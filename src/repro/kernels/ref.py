@@ -1,16 +1,17 @@
-"""Pure-jnp oracles for the Pallas kernels (the reference semantics).
+"""Pure-jnp streaming implementations of the kernel ops — the "ref"
+dispatch of ``repro.kernels.ops`` and the round XLA runs on CPU/GPU.
 
-These mirror ``repro.core`` math exactly; kernel tests sweep shapes and
-dtypes asserting allclose against these.
+The dense reference semantics they are tested against live in
+``repro.core.aggregation`` and ``repro.core.unify``.
 
-The two streaming round functions (``matu_round_slots_ref`` /
-``matu_round_slots_packed_ref``) are also the bodies the sharded engine
-runs per shard under ``shard_map``: with ``axis_name`` set they receive
-the local d-slice of every d-axis tensor and reconstruct the few
-genuinely global quantities with explicit collectives — the Eq. 5
-(T, T) sign dots by one ``psum`` (integer-exact under any reduction
-order) and the λ numerator/denominator totals by the shard-invariant
-block-tree reduction below (bit-identical to the single-device round).
+The streaming round function (``matu_round_slots_packed_ref``) is also
+the body the sharded engine runs per shard under ``shard_map``: with
+``axis_name`` set it receives the local d-slice of every d-axis tensor
+and reconstructs the few genuinely global quantities with explicit
+collectives — the Eq. 5 (T, T) sign dots by one ``psum``
+(integer-exact under any reduction order) and the λ
+numerator/denominator totals by the shard-invariant block-tree
+reduction below (bit-identical to the single-device round).
 """
 
 from __future__ import annotations
@@ -22,74 +23,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.kernels import bitpack
-
-
-def unify_ref(task_vectors: jax.Array) -> jax.Array:
-    """(K, d) -> (d,): sign election + max-|.| magnitude (Eq. 2)."""
-    x = task_vectors.astype(jnp.float32)
-    sigma = jnp.sign(jnp.sum(x, axis=0))
-    aligned = (x * sigma[None, :]) > 0
-    mu = jnp.max(jnp.abs(x) * aligned, axis=0)
-    return sigma * mu
-
-
-def masked_agg_ref(unified: jax.Array, masks: jax.Array, lams: jax.Array,
-                   gammas: jax.Array, rho: float):
-    """Eq. 3 + Eq. 4 fused for one task.
-
-    unified (N, d); masks (N, d) {0,1}; lams (N,); gammas (N,) already
-    normalised membership·|D| weights (zero rows = non-members).
-    Returns (tau_hat (d,), m_hat (d,)).
-    """
-    u = unified.astype(jnp.float32)
-    m = masks.astype(jnp.float32)
-    member = (gammas > 0).astype(jnp.float32)
-    n_t = jnp.maximum(jnp.sum(member), 1.0)
-    signs = jnp.sign(u * m)
-    alpha = jnp.abs(jnp.einsum("n,nd->d", member, signs)) / n_t
-    m_hat = jnp.where(alpha >= rho, 1.0, alpha)
-    recon = lams[:, None].astype(jnp.float32) * (u * m)
-    tau_hat = jnp.einsum("n,nd->d", gammas.astype(jnp.float32), recon) * m_hat
-    return tau_hat, m_hat
-
-
-def sign_sim_ref(tau_hats: jax.Array) -> jax.Array:
-    """Eq. 5: S = ½(sgn(T)·sgn(T)ᵀ/d + 1) over (T, d) -> (T, T)."""
-    x = tau_hats.astype(jnp.float32)
-    d = x.shape[-1]
-    s = jnp.sign(x)
-    return 0.5 * (s @ s.T / d + 1.0)
-
-
-def masked_agg_batched_ref(unified: jax.Array, masks: jax.Array,
-                           lams: jax.Array, gammas: jax.Array,
-                           members: jax.Array, rho: float):
-    """Eq. 3 + Eq. 4 fused over ALL tasks of a packed round.
-
-    unified (N, d); masks (N, T, d) {0,1} (zero rows for non-members);
-    lams/gammas/members (N, T).  ``members`` is the explicit A(n, t)
-    allocation so a member with zero data weight still counts toward
-    the agreement denominator N_t (matching ``matu_round``).
-
-    Implemented as a sequential ``lax.map`` over the task axis so peak
-    memory stays at O(N·d) regardless of T — the packed (N, T, d) mask
-    tensor is only ever sliced, never materialised in fp32.
-    Returns (tau_hats (T, d), m_hats (T, d)).
-    """
-    u = unified.astype(jnp.float32)
-    sign_u = jnp.sign(u)
-
-    def one_task(t):
-        m = masks[:, t, :].astype(jnp.float32)         # (N, d)
-        mem = members[:, t].astype(jnp.float32)        # (N,)
-        gl = (gammas[:, t] * lams[:, t]).astype(jnp.float32)
-        n_t = jnp.maximum(jnp.sum(mem), 1.0)
-        alpha = jnp.abs(jnp.einsum("n,nd->d", mem, m * sign_u)) / n_t
-        m_hat = jnp.where(alpha >= rho, 1.0, alpha)
-        tau_hat = jnp.einsum("n,nd->d", gl, m * u) * m_hat
-        return tau_hat, m_hat
-
-    return jax.lax.map(one_task, jnp.arange(masks.shape[1]))
 
 
 # d-axis streaming chunk for the CPU reference path: the per-chunk
@@ -206,58 +139,25 @@ def _unify_block(x, vf):
     return tau, mask, num, den
 
 
-def fused_unify_ref(task_vectors: jax.Array, valid: jax.Array, *,
-                    chunk: int = CHUNK_D):
-    """Fused unify + task-mask + λ-scaler, batched over clients.
-
-    task_vectors (B, K, d) slot-packed per-task vectors (garbage/zero in
-    invalid slots); valid (B, K) bool.  Invalid slots are zeroed before
-    the sign election, so the result equals per-client
-    ``unify_with_modulators(task_vectors[b, valid[b]])`` row-for-row.
-
-    Streams the d axis in cache-sized chunks (one fori_loop writing
-    into pre-allocated buffers in place), so every input byte is read
-    once and every output byte written once.  Returns
-    (unified (B, d), masks (B, K, d) bool, num (B, K), den (B, K))
-    with λ = num / max(den, eps) left to the caller (invalid slots
-    give num = den = 0 → λ = 0).
-    """
-    b, k, d = task_vectors.shape
-    chunk, dp = _chunked(d, chunk)
-    x_p = task_vectors.astype(jnp.float32)
-    if dp != d:                      # aligned d never pays the pad copy
-        x_p = jnp.pad(x_p, ((0, 0), (0, 0), (0, dp - d)))
-    vf = valid.astype(jnp.float32)
-
-    def step(c, carry):
-        uni, msk, num, den = carry
-        off = c * chunk
-        x = jax.lax.dynamic_slice_in_dim(x_p, off, chunk, axis=2)
-        tau, mask, num_c, den_c = _unify_block(x, vf)
-        uni = jax.lax.dynamic_update_slice_in_dim(uni, tau, off, axis=1)
-        msk = jax.lax.dynamic_update_slice_in_dim(msk, mask, off, axis=2)
-        return uni, msk, num + num_c, den + den_c
-
-    uni, msk, num, den = jax.lax.fori_loop(
-        0, dp // chunk, step,
-        (jnp.zeros((b, dp), jnp.float32), jnp.zeros((b, k, dp), bool),
-         jnp.zeros((b, k), jnp.float32), jnp.zeros((b, k), jnp.float32)))
-    return uni[:, :d], msk[:, :, :d], num, den
-
-
 def fused_unify_packed_ref(task_vectors: jax.Array, valid: jax.Array, *,
                            chunk: int = CHUNK_D):
-    """Wire-format variant of :func:`fused_unify_ref`: consumes bf16 (or
-    fp32) slot-packed task vectors and emits the uplink wire tensors —
-    bf16 unified vectors and bit-packed uint32 mask words.
+    """Fused unify + task-mask + λ-scaler, batched over clients:
+    consumes bf16 (or fp32) slot-packed task vectors and emits the
+    uplink wire tensors — bf16 unified vectors and bit-packed uint32
+    mask words.
 
-    task_vectors (B, K, d) bf16/fp32; valid (B, K) bool.  All compute is
-    fp32 per cache-sized d-chunk (inputs are upcast tile-by-tile, never
-    as a whole), mask bits are decided on the fp32 values BEFORE the
-    unified vector is rounded to bf16, and λ num/den stay fp32 — so the
-    modulators are bit-identical to the bool/fp32 path on the same
-    inputs.  Returns (unified (B, d) bf16, mask_words (B, K, ceil(d/32))
-    uint32, num (B, K), den (B, K)).
+    task_vectors (B, K, d) bf16/fp32 (garbage/zero in invalid slots);
+    valid (B, K) bool.  Invalid slots are zeroed before the sign
+    election, so row b equals per-client
+    ``unify_with_modulators(task_vectors[b, valid[b]])``.  Streams the
+    d axis in cache-sized chunks (one fori_loop writing into
+    pre-allocated buffers in place).  All compute is fp32 per chunk
+    (inputs are upcast chunk by chunk, never as a whole), mask bits are
+    decided on the fp32 values BEFORE the unified vector is rounded to
+    bf16, and λ num/den stay fp32.  Returns (unified (B, d) bf16,
+    mask_words (B, K, ceil(d/32)) uint32, num (B, K), den (B, K)) with
+    λ = num / max(den, eps) left to the caller (invalid slots give
+    num = den = 0 → λ = 0).
     """
     b, k, d = task_vectors.shape
     chunk, dp = _chunked(d, chunk)
@@ -306,9 +206,23 @@ def matu_round_slots_packed_ref(unified: jax.Array, slot_mask_words: jax.Array,
                                 chunk: int = CHUNK_D,
                                 axis_name=None, axis_sizes=(),
                                 d_norm: int = 0):
-    """Wire-format twin of :func:`matu_round_slots_ref`: the same
-    two-pass cache-blocked streaming round, but every big tensor stays
-    in its transport layout end to end —
+    """The full MaTU server round (Eq. 3–7 + downlink re-unification)
+    over slot-packed uploads, streamed in two cache-blocked passes.
+
+    Layout: unified (N, d); slot_mask_words (N, K, ceil(d/32)); slot_lams
+    / slot_sizes / slot_valid (N, K); slot_tasks (N, K) int32 with the
+    sentinel ``n_tasks`` in invalid slots.  Work is O(Σ_n k_n · d), not
+    the dense O(N·T·d), because per-task reductions are segment-sums
+    over slot rows rather than masked sums over all clients.
+
+    Pass 1 streams each d-chunk once: Eq. 3 agreement + Eq. 4 merge via
+    segment-sum into a cache-resident (T+1, dc) accumulator (sentinel
+    bucket swallows invalid slots), Eq. 5 sign dots accumulated on the
+    fly.  The (T, T) weight logic runs between passes.  Pass 2 streams
+    chunks again: Eq. 6 mix + Eq. 7 combine, then gathers each chunk's
+    fresh task vectors straight into the fused downlink re-unification
+    while they are still cache-hot.  Every big tensor stays in its
+    transport layout end to end —
 
     * ``unified`` (N, d) arrives bf16 and is upcast fp32 one chunk at a
       time (never materialised dense);
@@ -326,10 +240,6 @@ def matu_round_slots_packed_ref(unified: jax.Array, slot_mask_words: jax.Array,
     * the downlink re-unification emits bf16 unified vectors and packed
       mask words — the downlink wire format — with mask bits and λ
       num/den decided on fp32 values before the bf16 rounding.
-
-    Apart from transport rounding of the *inputs/outputs*, every fp32
-    op runs in the same order as the bool/fp32 round, so on identical
-    (already-quantised) inputs the masks and λs match bit for bit.
 
     Under ``shard_map`` (``axis_name`` set, with the mesh axis sizes in
     ``axis_sizes``) every d-axis tensor is the executing shard's slice,
@@ -376,9 +286,9 @@ def matu_round_slots_packed_ref(unified: jax.Array, slot_mask_words: jax.Array,
     # one unpack per chunk (to int8 — the sign election is pure small-
     # integer algebra: int8 bits × int8 signs, exact) feeds both the
     # Eq. 3 election and the Eq. 4 merge; the packed words never exist
-    # in fp32 outside this cache-resident block.  The fp32 merge keeps
-    # the single whole-round segment-sum so its accumulation order is
-    # identical to the bool layout's (bit-parity); the sign sum is
+    # in fp32 outside this cache-resident block.  The fp32 merge is one
+    # whole-round segment-sum in global row order (the order the
+    # chunked round's scatter fold repeats); the sign sum is
     # integer-exact under any order.
     def pass1(c, carry):
         tau_buf, anum_buf, dots = carry
@@ -436,8 +346,8 @@ def matu_round_slots_packed_ref(unified: jax.Array, slot_mask_words: jax.Array,
     # m̂ is re-derived from the byte-wide agreement numerator with the
     # same fp32 division pass 1 used — bit-identical, 4x less traffic.
     # Invalid slots gather the appended all-zero sentinel row (ids ==
-    # n_tasks), which zeroes them exactly as the bool path's validity
-    # multiplies did — no per-element vf masking anywhere in the block.
+    # n_tasks), which zeroes them exactly — no per-element validity
+    # masking anywhere in the block.
     def pass2(c, carry):
         tv_buf, uni_buf, dmask_buf, num_p, den_p = carry
         off = c * chunk
@@ -518,157 +428,6 @@ def cross_weights_ref(sim: jax.Array, held: jax.Array, *, eps: float,
     return topk_weights_ref(sim, eps, kappa)
 
 
-def matu_round_slots_ref(unified: jax.Array, slot_masks: jax.Array,
-                         slot_lams: jax.Array, slot_sizes: jax.Array,
-                         slot_valid: jax.Array, slot_tasks: jax.Array,
-                         n_tasks: int, *, rho: float, eps: float, kappa: int,
-                         cross_task: bool = True, uniform_cross: bool = False,
-                         chunk: int = CHUNK_D,
-                         axis_name=None, axis_sizes=(), d_norm: int = 0):
-    """The full MaTU server round (Eq. 3–7 + downlink re-unification)
-    over slot-packed uploads, streamed in two cache-blocked passes.
-
-    Layout: unified (N, d); slot_masks (N, K, d) bool; slot_lams /
-    slot_sizes / slot_valid (N, K); slot_tasks (N, K) int32 with the
-    sentinel ``n_tasks`` in invalid slots.  Work is O(Σ_n k_n · d) —
-    the same asymptotics as the legacy ragged loop, NOT the dense
-    O(N·T·d) — because per-task reductions are segment-sums over slot
-    rows rather than masked sums over all clients.
-
-    Pass 1 streams each d-chunk once: Eq. 3 agreement + Eq. 4 merge via
-    segment-sum into a cache-resident (T+1, dc) accumulator (sentinel
-    bucket swallows invalid slots), Eq. 5 sign-dot accumulated on the
-    fly.  The (T, T) weight logic runs between passes.  Pass 2 streams
-    chunks again: Eq. 6 mix + Eq. 7 combine, then gathers each chunk's
-    fresh task vectors straight into the fused downlink re-unification
-    while they are still cache-hot.
-
-    Returns (task_vectors, tau_hats, m_hats, similarity, down_unified,
-    down_masks, down_num, down_den).  τ̃ is not materialised on the hot
-    path — consumers can derive it as (2τ − τ̂) on rows with donors.
-
-    ``axis_name`` / ``axis_sizes`` / ``d_norm``: per-shard execution
-    under ``shard_map`` — see :func:`matu_round_slots_packed_ref` (here
-    the Eq. 5 dots are integer-valued fp32, still exact under any psum
-    order for d < 2²⁴).
-    """
-    n, k, d = slot_masks.shape
-    m_rows = n * k
-    chunk, dp = _chunked(d, chunk)
-    n_blk, blkc = dp // LAMBDA_BLOCK, chunk // LAMBDA_BLOCK
-    n_seg = n_tasks + 1
-    d_norm = d_norm or d
-
-    ids = slot_tasks.reshape(m_rows)
-    vf = slot_valid.reshape(m_rows).astype(jnp.float32)
-    sizes = slot_sizes.reshape(m_rows).astype(jnp.float32) * vf
-    totals = jax.ops.segment_sum(sizes, ids, num_segments=n_seg)
-    gam = sizes / jnp.maximum(totals[ids], 1e-12)
-    glv = gam * slot_lams.reshape(m_rows).astype(jnp.float32) * vf
-    n_t = jax.ops.segment_sum(vf, ids, num_segments=n_seg)[:n_tasks]
-    held = n_t > 0
-
-    u_p = unified.astype(jnp.float32)
-    m_p = slot_masks
-    if dp != d:                      # aligned d never pays the pad copies
-        u_p = jnp.pad(u_p, ((0, 0), (0, dp - d)))
-        m_p = jnp.pad(m_p, ((0, 0), (0, 0), (0, dp - d)))
-
-    glv_nk = glv.reshape(n, k)
-
-    # ---- pass 1: Eq. 3 + 4 per chunk, Eq. 5 dots accumulated -------------
-    # sgn(m ⊙ τ_n) is factored as m ⊙ sgn(τ_n) (m binary), so the sign
-    # is taken once per client row, not once per slot.
-    def pass1(c, carry):
-        tau_buf, mhat_buf, dots = carry
-        off = c * chunk
-        uc = jax.lax.dynamic_slice_in_dim(u_p, off, chunk, axis=1)
-        mc = jax.lax.dynamic_slice_in_dim(m_p, off, chunk, axis=2)
-        signs = jnp.where(mc, jnp.sign(uc)[:, None, :], 0.0)
-        a_num = jax.ops.segment_sum(signs.reshape(m_rows, chunk), ids,
-                                    num_segments=n_seg)[:n_tasks]
-        recon = jnp.where(mc, (glv_nk[:, :, None] * uc[:, None, :]), 0.0)
-        tau_pre = jax.ops.segment_sum(recon.reshape(m_rows, chunk), ids,
-                                      num_segments=n_seg)[:n_tasks]
-        alpha = jnp.abs(a_num) / jnp.maximum(n_t, 1.0)[:, None]
-        m_hat = jnp.where(alpha >= rho, 1.0, alpha)
-        tau = tau_pre * m_hat
-        s = jnp.sign(tau)
-        dots = dots + s @ s.T
-        tau_buf = jax.lax.dynamic_update_slice_in_dim(tau_buf, tau, off, axis=1)
-        mhat_buf = jax.lax.dynamic_update_slice_in_dim(mhat_buf, m_hat, off,
-                                                       axis=1)
-        return tau_buf, mhat_buf, dots
-
-    tau_hats, m_hats, dots = jax.lax.fori_loop(
-        0, dp // chunk, pass1,
-        (jnp.zeros((n_tasks, dp), jnp.float32),
-         jnp.zeros((n_tasks, dp), jnp.float32),
-         jnp.zeros((n_tasks, n_tasks), jnp.float32)))
-
-    if axis_name is not None:
-        dots = lax.psum(dots, axis_name)     # integer-valued: exact
-
-    heldf = held.astype(jnp.float32)
-    sim = 0.5 * (dots / d_norm + 1.0) * heldf[None, :] * heldf[:, None]
-    weights = cross_weights_ref(sim, held, eps=eps, kappa=kappa,
-                                cross_task=cross_task,
-                                uniform_cross=uniform_cross)
-    total_w = jnp.sum(weights, axis=1, keepdims=True)
-    norm_w = weights / jnp.maximum(total_w, 1e-12)
-    has = (total_w > 0).astype(jnp.float32)
-
-    ids_c = jnp.minimum(ids, n_tasks - 1)       # clamp sentinel for gather
-    vf_nk = vf.reshape(n, k)
-    # Eq. 7 as two precomputed row scales: τ = c1·τ̂ + c2·(m̂ ⊙ mixed)
-    c1 = (1.0 / (1.0 + has))
-    c2 = (has / (1.0 + has))
-
-    # ---- pass 2: Eq. 6 + 7 per chunk, downlink re-unify while hot --------
-    # The λ numerator Σ|τ^t| is shared by every client holding task t,
-    # so it is accumulated once per task ((T, dc) work) and gathered per
-    # slot after the loop — not recomputed per (client, slot).
-    def pass2(c, carry):
-        tv_buf, uni_buf, dmask_buf, num_p, den_p = carry
-        off = c * chunk
-        tau = jax.lax.dynamic_slice_in_dim(tau_hats, off, chunk, axis=1)
-        m_hat = jax.lax.dynamic_slice_in_dim(m_hats, off, chunk, axis=1)
-        tv = c1 * tau + c2 * (m_hat * (norm_w @ tau))
-        num_p = jax.lax.dynamic_update_slice_in_dim(
-            num_p, _block_partials(jnp.abs(tv)), c * blkc, axis=1)
-        x = jnp.take(tv, ids_c, axis=0).reshape(n, k, chunk)
-        xm = x * vf_nk[:, :, None]
-        sigma = jnp.sign(jnp.sum(xm, axis=1))                  # (N, dc)
-        # aligned max via relu(xm·σ): σ ∈ {-1,0,1} ⇒ relu(xm·σ) equals
-        # |xm| exactly on sign-aligned entries and 0 elsewhere
-        mu = jnp.max(jax.nn.relu(xm * sigma[:, None, :]), axis=1)
-        tau_n = sigma * mu
-        dmask = (x * tau_n[:, None, :] > 0) & (vf_nk[:, :, None] > 0)
-        den_c = _block_partials(
-            jnp.where(dmask, jnp.abs(tau_n)[:, None, :], 0.0))
-        tv_buf = jax.lax.dynamic_update_slice_in_dim(tv_buf, tv, off, axis=1)
-        uni_buf = jax.lax.dynamic_update_slice_in_dim(uni_buf, tau_n, off,
-                                                      axis=1)
-        dmask_buf = jax.lax.dynamic_update_slice_in_dim(dmask_buf, dmask, off,
-                                                        axis=2)
-        den_p = jax.lax.dynamic_update_slice_in_dim(den_p, den_c, c * blkc,
-                                                    axis=2)
-        return tv_buf, uni_buf, dmask_buf, num_p, den_p
-
-    tv_buf, uni_buf, dmask_buf, num_p, den_p = jax.lax.fori_loop(
-        0, dp // chunk, pass2,
-        (jnp.zeros((n_tasks, dp), jnp.float32),
-         jnp.zeros((n, dp), jnp.float32),
-         jnp.zeros((n, k, dp), bool),
-         jnp.zeros((n_tasks, n_blk), jnp.float32),
-         jnp.zeros((n, k, n_blk), jnp.float32)))
-    num_t, den = _lam_totals((num_p, den_p), axis_name, axis_sizes)
-    num = num_t[ids_c].reshape(n, k) * vf_nk
-
-    return (tv_buf[:, :d], tau_hats[:, :d], m_hats[:, :d],
-            sim, uni_buf[:, :d], dmask_buf[:, :, :d], num, den)
-
-
 def topk_weights_ref(sim: jax.Array, eps: float, kappa: int) -> jax.Array:
     """Eq. 6 neighbourhood Z^t as a (T, T) weight matrix (mirror of
     ``repro.core.aggregation.topk_similar``)."""
@@ -700,22 +459,22 @@ def cross_task_combine_ref(tau_hats: jax.Array, m_hats: jax.Array,
 # ---------------------------------------------------------------------------
 # Chunked-slot hierarchical aggregation: the client-axis streaming round.
 #
-# The monolithic rounds above materialise every slot tensor for the
+# The monolithic round above materialises every slot tensor for the
 # whole round — O(N·K·d) — which caps the client axis.  The four
-# functions per layout below split the identical math into per-chunk
-# folds over carried accumulators so a round's memory is O(chunk + T·d)
+# functions below split the identical math into per-chunk folds over
+# carried accumulators so a round's memory is O(chunk + T·d)
 # regardless of N:
 #
 #   phase A  ``matu_chunk_scalars_ref``    per chunk: fold sizes / valid
 #            counts into (T+1,) totals (the Eq. 4 γ normaliser needs
 #            global per-task size totals before any merge work).
-#   phase B  ``matu_merge_chunk[_packed]_ref``  per chunk: fold the
+#   phase B  ``matu_merge_chunk_packed_ref``  per chunk: fold the
 #            Eq. 3 sign votes and Eq. 4 merge partials into carried
 #            (T+1, dp) accumulators.
-#   finish   ``matu_finish[_packed]_ref``  once: Eq. 3 α/m̂, Eq. 5 sign
+#   finish   ``matu_finish_packed_ref``  once: Eq. 3 α/m̂, Eq. 5 sign
 #            dots, Eq. 6 weights, Eq. 7 combine and the λ numerator
 #            from the accumulators — no slot tensors involved.
-#   phase C  ``matu_downlink_chunk[_packed]_ref``  per chunk: downlink
+#   phase C  ``matu_downlink_chunk_packed_ref``  per chunk: downlink
 #            re-unification of one client chunk from the finished task
 #            vectors (each slot row lives in exactly one chunk, so this
 #            phase is embarrassingly parallel over rows).
@@ -764,9 +523,9 @@ def matu_merge_chunk_packed_ref(unified: jax.Array, slot_mask_words: jax.Array,
                                 totals: jax.Array, a_acc: jax.Array,
                                 tau_acc: jax.Array, *, d: int,
                                 chunk: int = CHUNK_D):
-    """Phase-B chunk step, wire layout: fold one client chunk's Eq. 3
-    sign votes (int32, exact) and Eq. 4 merge partials (fp32, global
-    row order) into the carried (T+1, dp) accumulators.
+    """Phase-B chunk step: fold one client chunk's Eq. 3 sign votes
+    (int32, exact) and Eq. 4 merge partials (fp32, global row order)
+    into the carried (T+1, dp) accumulators.
 
     ``totals`` is the phase-A global size total (T+1,) — the γ weights
     need it before any merge work, which is why the chunked round makes
@@ -817,50 +576,6 @@ def matu_merge_chunk_packed_ref(unified: jax.Array, slot_mask_words: jax.Array,
     return lax.fori_loop(0, dp // chunk, fold, (a_acc, tau_acc))
 
 
-def matu_merge_chunk_ref(unified: jax.Array, slot_masks: jax.Array,
-                         slot_lams: jax.Array, slot_sizes: jax.Array,
-                         slot_valid: jax.Array, slot_tasks: jax.Array,
-                         totals: jax.Array, a_acc: jax.Array,
-                         tau_acc: jax.Array, *, chunk: int = CHUNK_D):
-    """Phase-B chunk step, bool/fp32 layout twin of
-    :func:`matu_merge_chunk_packed_ref` (here both accumulators are
-    fp32 — the sign votes are small exact integers in fp32, matching
-    the monolithic bool round's accumulation dtype)."""
-    n, k, d = slot_masks.shape
-    m_rows = n * k
-    chunk, dp = _chunked(d, chunk)
-
-    ids = slot_tasks.reshape(m_rows)
-    vf = slot_valid.reshape(m_rows).astype(jnp.float32)
-    sizes = slot_sizes.reshape(m_rows).astype(jnp.float32) * vf
-    gam = sizes / jnp.maximum(totals[ids], 1e-12)
-    glv = gam * slot_lams.reshape(m_rows).astype(jnp.float32) * vf
-    glv_nk = glv.reshape(n, k)
-
-    u_p = unified.astype(jnp.float32)
-    m_p = slot_masks
-    if dp != d:
-        u_p = jnp.pad(u_p, ((0, 0), (0, dp - d)))
-        m_p = jnp.pad(m_p, ((0, 0), (0, 0), (0, dp - d)))
-
-    def fold(c, carry):
-        a_acc, tau_acc = carry
-        off = c * chunk
-        uc = lax.dynamic_slice_in_dim(u_p, off, chunk, axis=1)
-        mc = lax.dynamic_slice_in_dim(m_p, off, chunk, axis=2)
-        signs = jnp.where(mc, jnp.sign(uc)[:, None, :], 0.0)
-        a_blk = lax.dynamic_slice_in_dim(a_acc, off, chunk, axis=1)
-        a_blk = a_blk.at[ids].add(signs.reshape(m_rows, chunk))
-        a_acc = lax.dynamic_update_slice_in_dim(a_acc, a_blk, off, axis=1)
-        recon = jnp.where(mc, (glv_nk[:, :, None] * uc[:, None, :]), 0.0)
-        t_blk = lax.dynamic_slice_in_dim(tau_acc, off, chunk, axis=1)
-        t_blk = t_blk.at[ids].add(recon.reshape(m_rows, chunk))
-        tau_acc = lax.dynamic_update_slice_in_dim(tau_acc, t_blk, off, axis=1)
-        return a_acc, tau_acc
-
-    return lax.fori_loop(0, dp // chunk, fold, (a_acc, tau_acc))
-
-
 def matu_finish_packed_ref(a_acc: jax.Array, tau_acc: jax.Array,
                            nt_acc: jax.Array, n_clients: int, *, n_tasks: int,
                            d: int, rho: float, eps: float, kappa: int,
@@ -868,7 +583,7 @@ def matu_finish_packed_ref(a_acc: jax.Array, tau_acc: jax.Array,
                            uniform_cross: bool = False,
                            chunk: int = CHUNK_D,
                            axis_name=None, axis_sizes=(), d_norm: int = 0):
-    """Finish the chunked packed round from the accumulated partials:
+    """Finish the chunked round from the accumulated partials:
     Eq. 3 α/m̂ from the integer vote accumulator (same fp32 division as
     the monolithic round), Eq. 5 popcount dots, Eq. 6 weights, Eq. 7
     combine, and the λ numerator totals on the shard-invariant block
@@ -947,85 +662,13 @@ def matu_finish_packed_ref(a_acc: jax.Array, tau_acc: jax.Array,
     return (tv_buf[:, :d], tau_hats[:, :d], anum_buf[:, :d], n_t, sim, num_t)
 
 
-def matu_finish_ref(a_acc: jax.Array, tau_acc: jax.Array, nt_acc: jax.Array,
-                    *, n_tasks: int, d: int, rho: float, eps: float,
-                    kappa: int, cross_task: bool = True,
-                    uniform_cross: bool = False, chunk: int = CHUNK_D,
-                    axis_name=None, axis_sizes=(), d_norm: int = 0):
-    """Bool/fp32-layout finish of the chunked round — same structure as
-    :func:`matu_finish_packed_ref` but m̂ is buffered dense and the
-    Eq. 5 dots use the fp32 sign matmul, matching the monolithic bool
-    round op for op.  Returns (task_vectors, tau_hats, m_hats (T, d),
-    n_t, similarity, num_t)."""
-    chunk, dp = _chunked(d, chunk)
-    n_blk, blkc = dp // LAMBDA_BLOCK, chunk // LAMBDA_BLOCK
-    d_norm = d_norm or d
-    n_t = nt_acc[:n_tasks]
-    held = n_t > 0
-
-    def pass1(c, carry):
-        tau_buf, mhat_buf, dots = carry
-        off = c * chunk
-        a_num = lax.dynamic_slice_in_dim(a_acc, off, chunk, axis=1)[:n_tasks]
-        tau_pre = lax.dynamic_slice_in_dim(tau_acc, off, chunk,
-                                           axis=1)[:n_tasks]
-        alpha = jnp.abs(a_num) / jnp.maximum(n_t, 1.0)[:, None]
-        m_hat = jnp.where(alpha >= rho, 1.0, alpha)
-        tau = tau_pre * m_hat
-        s = jnp.sign(tau)
-        dots = dots + s @ s.T
-        tau_buf = jax.lax.dynamic_update_slice_in_dim(tau_buf, tau, off,
-                                                      axis=1)
-        mhat_buf = jax.lax.dynamic_update_slice_in_dim(mhat_buf, m_hat, off,
-                                                       axis=1)
-        return tau_buf, mhat_buf, dots
-
-    tau_hats, m_hats, dots = jax.lax.fori_loop(
-        0, dp // chunk, pass1,
-        (jnp.zeros((n_tasks, dp), jnp.float32),
-         jnp.zeros((n_tasks, dp), jnp.float32),
-         jnp.zeros((n_tasks, n_tasks), jnp.float32)))
-
-    if axis_name is not None:
-        dots = lax.psum(dots, axis_name)     # integer-valued: exact
-
-    heldf = held.astype(jnp.float32)
-    sim = 0.5 * (dots / d_norm + 1.0) * heldf[None, :] * heldf[:, None]
-    weights = cross_weights_ref(sim, held, eps=eps, kappa=kappa,
-                                cross_task=cross_task,
-                                uniform_cross=uniform_cross)
-    total_w = jnp.sum(weights, axis=1, keepdims=True)
-    norm_w = weights / jnp.maximum(total_w, 1e-12)
-    has = (total_w > 0).astype(jnp.float32)
-    c1 = (1.0 / (1.0 + has))
-    c2 = (has / (1.0 + has))
-
-    def pass2(c, carry):
-        tv_buf, num_p = carry
-        off = c * chunk
-        tau = jax.lax.dynamic_slice_in_dim(tau_hats, off, chunk, axis=1)
-        m_hat = jax.lax.dynamic_slice_in_dim(m_hats, off, chunk, axis=1)
-        tv = c1 * tau + c2 * (m_hat * (norm_w @ tau))
-        num_p = jax.lax.dynamic_update_slice_in_dim(
-            num_p, _block_partials(jnp.abs(tv)), c * blkc, axis=1)
-        tv_buf = jax.lax.dynamic_update_slice_in_dim(tv_buf, tv, off, axis=1)
-        return tv_buf, num_p
-
-    tv_buf, num_p = jax.lax.fori_loop(
-        0, dp // chunk, pass2,
-        (jnp.zeros((n_tasks, dp), jnp.float32),
-         jnp.zeros((n_tasks, n_blk), jnp.float32)))
-    num_t, = _lam_totals((num_p,), axis_name, axis_sizes)
-    return (tv_buf[:, :d], tau_hats[:, :d], m_hats[:, :d], n_t, sim, num_t)
-
-
 def matu_downlink_chunk_packed_ref(task_vectors: jax.Array,
                                    slot_tasks: jax.Array, num_t: jax.Array,
                                    *, d: int, chunk: int = CHUNK_D,
                                    axis_name=None, axis_sizes=()):
-    """Phase-C chunk step, wire layout: downlink re-unification of one
-    client chunk from the finished task vectors — the monolithic packed
-    pass 2's per-slot sweep, restricted to this chunk's rows (each slot
+    """Phase-C chunk step: downlink re-unification of one client chunk
+    from the finished task vectors — the monolithic round's pass 2
+    per-slot sweep, restricted to this chunk's rows (each slot
     row lives in exactly one chunk, so per-row results are trivially
     chunk-invariant; the λ denominator rides the same shard-invariant
     block tree, one psum per chunk when sharded).  Invalid slots gather
@@ -1081,57 +724,6 @@ def matu_downlink_chunk_packed_ref(task_vectors: jax.Array,
     dw = bitpack.packed_width(d)
     return (uni_buf[:, :d].astype(jnp.bfloat16), dmask_buf[:, :, :dw],
             num, den)
-
-
-def matu_downlink_chunk_ref(task_vectors: jax.Array, slot_valid: jax.Array,
-                            slot_tasks: jax.Array, num_t: jax.Array, *,
-                            n_tasks: int, chunk: int = CHUNK_D,
-                            axis_name=None, axis_sizes=()):
-    """Phase-C chunk step, bool/fp32 layout twin of
-    :func:`matu_downlink_chunk_packed_ref` (sentinel ids clamped for
-    the gather, validity handled by explicit vf multiplies — the
-    monolithic bool pass 2's conventions).  Returns (down_unified
-    (C, d) fp32, down_masks (C, K, d) bool, down_num, down_den)."""
-    n, k = slot_tasks.shape
-    m_rows = n * k
-    d = task_vectors.shape[-1]
-    chunk, dp = _chunked(d, chunk)
-    n_blk, blkc = dp // LAMBDA_BLOCK, chunk // LAMBDA_BLOCK
-    ids = slot_tasks.reshape(m_rows)
-    ids_c = jnp.minimum(ids, n_tasks - 1)       # clamp sentinel for gather
-    vf_nk = slot_valid.reshape(m_rows).astype(jnp.float32).reshape(n, k)
-    tv_p = task_vectors
-    if dp != d:
-        tv_p = jnp.pad(tv_p, ((0, 0), (0, dp - d)))
-
-    def step(c, carry):
-        uni_buf, dmask_buf, den_p = carry
-        off = c * chunk
-        tv = lax.dynamic_slice_in_dim(tv_p, off, chunk, axis=1)
-        x = jnp.take(tv, ids_c, axis=0).reshape(n, k, chunk)
-        xm = x * vf_nk[:, :, None]
-        sigma = jnp.sign(jnp.sum(xm, axis=1))                  # (C, dc)
-        mu = jnp.max(jax.nn.relu(xm * sigma[:, None, :]), axis=1)
-        tau_n = sigma * mu
-        dmask = (x * tau_n[:, None, :] > 0) & (vf_nk[:, :, None] > 0)
-        den_c = _block_partials(
-            jnp.where(dmask, jnp.abs(tau_n)[:, None, :], 0.0))
-        uni_buf = jax.lax.dynamic_update_slice_in_dim(uni_buf, tau_n, off,
-                                                      axis=1)
-        dmask_buf = jax.lax.dynamic_update_slice_in_dim(dmask_buf, dmask, off,
-                                                        axis=2)
-        den_p = jax.lax.dynamic_update_slice_in_dim(den_p, den_c, c * blkc,
-                                                    axis=2)
-        return uni_buf, dmask_buf, den_p
-
-    uni_buf, dmask_buf, den_p = jax.lax.fori_loop(
-        0, dp // chunk, step,
-        (jnp.zeros((n, dp), jnp.float32),
-         jnp.zeros((n, k, dp), bool),
-         jnp.zeros((n, k, n_blk), jnp.float32)))
-    den, = _lam_totals((den_p,), axis_name, axis_sizes)
-    num = num_t[ids_c].reshape(n, k) * vf_nk
-    return (uni_buf[:, :d], dmask_buf[:, :, :d], num, den)
 
 
 # ---------------------------------------------------------------------------

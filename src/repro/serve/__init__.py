@@ -9,8 +9,9 @@ Serving contract
    :class:`~repro.core.client.ClientDownlink` (row t ↔ task id t) and
    :meth:`ModulatorStore.ingest` makes it resident: the unified vector
    ONCE in its wire dtype, per task a bit-packed uint32 mask row + an
-   fp32 λ.  Masks stay packed until point of use — bool rows are
-   packed on ingest, entropy-coded streams decode straight to words.
+   fp32 λ.  Masks stay packed until point of use — the downlink
+   carries packed words, or with ``code_masks`` an entropy-coded
+   stream that decodes straight to words.
    The store verifies the downlink's ``TaskVectorSpace`` fingerprint
    against its own manifest before serving anything and refuses an
    unstamped downlink unless explicitly overridden — the same

@@ -5,7 +5,8 @@ Contract under test:
 * **bit identity** — every client's downlink is exactly its rows of the
   batched tensors (``down_unified[i]``, ``down_masks[i, :k]``,
   ``down_lams[i, :k]``) for uniform and ragged task counts, rounds
-  smaller than ``n_max``, the bool A/B layout and the coded branch;
+  smaller than ``n_max``, uploads whose masks arrive as uint32 words
+  instead of dense bool, and the coded branch;
   padded rows are never handed out;
 * **dispatches** — one jitted split per distinct task count: a uniform-K
   round is one call, every field is one of its outputs (no per-client
@@ -35,18 +36,22 @@ from repro.core.client import ClientUpload
 from repro.core.engine import EngineConfig, RoundEngine
 from repro.core.unify import unify_with_modulators
 from repro.fed.compression import decode_mask_rows, quantize_bf16_transport
+from repro.kernels import bitpack
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 T = 6
 
 
-def _uploads(ks, d, seed=0):
+def _uploads(ks, d, seed=0, words=False):
+    """Uploads with dense bool masks, or (``words``) the uint32 words."""
     rng = np.random.default_rng(seed)
     ups = []
     for cid, k in enumerate(ks):
         tasks = sorted(rng.choice(T, size=k, replace=False).tolist())
         tvs = jnp.asarray(rng.standard_normal((k, d)), jnp.float32)
         uni, masks, lams = unify_with_modulators(tvs)
+        if words:
+            masks = bitpack.pack_bits(masks)
         ups.append(ClientUpload(cid, tasks, quantize_bf16_transport(uni),
                                 masks, lams,
                                 rng.integers(10, 200, size=k).tolist()))
@@ -77,21 +82,21 @@ def counting_split(monkeypatch):
 
 
 CASES = {
-    "uniform": dict(ks=[2] * 8, packed=True, coded=False),
-    "ragged": dict(ks=[1, 3, 2, 4, 1, 4, 2, 3], packed=True, coded=False),
-    "n_lt_n_max": dict(ks=[2, 1, 2, 2, 1], packed=True, coded=False),
-    "bool": dict(ks=[1, 3, 2, 3, 1], packed=False, coded=False),
-    "coded": dict(ks=[1, 3, 2, 3, 2, 2], packed=True, coded=True),
+    "uniform": dict(ks=[2] * 8, words=False, coded=False),
+    "ragged": dict(ks=[1, 3, 2, 4, 1, 4, 2, 3], words=False, coded=False),
+    "n_lt_n_max": dict(ks=[2, 1, 2, 2, 1], words=False, coded=False),
+    "words": dict(ks=[1, 3, 2, 3, 1], words=True, coded=False),
+    "coded": dict(ks=[1, 3, 2, 3, 2, 2], words=False, coded=True),
 }
 
 
 @pytest.mark.parametrize("case", list(CASES))
 def test_downlinks_bit_identical_to_row_slices(case, counting_split):
-    ks, packed, coded = (CASES[case][k] for k in ("ks", "packed", "coded"))
+    ks, words, coded = (CASES[case][k] for k in ("ks", "words", "coded"))
     d = 1000
-    ups = _uploads(ks, d, seed=len(case))
+    ups = _uploads(ks, d, seed=len(case), words=words)
     eng = RoundEngine(EngineConfig(n_tasks=T))
-    downs, out = eng.round(ups, packed=packed, code_masks=coded)
+    downs, out = eng.round(ups, code_masks=coded)
     n_max = out.down_unified.shape[0]
 
     assert list(downs) == [u.client_id for u in ups]

@@ -3,12 +3,13 @@
 The two-deep ``RoundEngine.round_stream`` pipeline (pack/decode round
 r+1 and encode round r−1's downlinks while round r's jitted step runs)
 must be a pure reordering — every downlink byte and every engine output
-identical to the ``pipeline=False`` escape hatch, across both slot
-layouts (packed wire / bool A/B), raw and entropy-coded wires, and the
-ref / pallas_interpret dispatch modes.  The simulator-level deferred
-drain (``MaTUStrategy(pipeline=True)`` via ``FedConfig.pipeline``) gets
-the same multi-round A/B, plus the per-phase timing plumbing the
-pipeline makes observable (History.phase_us).
+identical to the ``pipeline=False`` escape hatch, for uploads whose
+masks arrive as uint32 words or as dense bool rows, raw and
+entropy-coded wires, and the ref / pallas_interpret dispatch modes.
+The simulator-level deferred drain (``MaTUStrategy(pipeline=True)``
+via ``FedConfig.pipeline``) gets the same multi-round A/B, plus the
+per-phase timing plumbing the pipeline makes observable
+(History.phase_us).
 """
 
 import jax
@@ -28,9 +29,12 @@ N_TASKS = 5
 D = 512
 
 
-def _make_rounds(seed, n_rounds, *, coded=False, packed=True, n_clients=4):
-    """n_rounds of ragged uploads (different clients/masks per round) in
-    the requested wire layout."""
+def _make_rounds(seed, n_rounds, *, coded=False, as_words=True,
+                 n_clients=4):
+    """n_rounds of ragged uploads (different clients/masks per round):
+    masks entropy-coded, as uint32 words with a bf16 vector
+    (``as_words``), or as the dense bool rows and fp32 vector a client
+    makes, which ``pack_uploads`` packs and rounds."""
     rng = np.random.default_rng(seed)
     rounds = []
     for _ in range(n_rounds):
@@ -43,11 +47,11 @@ def _make_rounds(seed, n_rounds, *, coded=False, packed=True, n_clients=4):
             words = bitpack.pack_bits_np(np.asarray(masks))
             if coded:
                 m = jnp.asarray(encode_mask_rows(words, D))
-            elif packed:
+            elif as_words:
                 m = jnp.asarray(words)
             else:
                 m = masks
-            vec = unified.astype(jnp.bfloat16) if packed else unified
+            vec = unified.astype(jnp.bfloat16) if as_words else unified
             ups.append(ClientUpload(cid, tasks, vec, m, lams,
                                     rng.integers(32, 256, size=k).tolist()))
         rounds.append(ups)
@@ -70,16 +74,15 @@ def _assert_rounds_equal(seq, pipe):
 
 
 @pytest.mark.parametrize("mode", ["ref", "pallas_interpret"])
-@pytest.mark.parametrize("layout", ["packed", "bool"])
+@pytest.mark.parametrize("layout", ["words", "dense"])
 @pytest.mark.parametrize("coded", [False, True])
 def test_round_stream_pipelined_matches_sequential(mode, layout, coded):
-    packed = layout == "packed"
-    rounds = _make_rounds(0, 3, coded=coded, packed=packed)
+    rounds = _make_rounds(0, 3, coded=coded, as_words=layout == "words")
     eng = RoundEngine(EngineConfig(n_tasks=N_TASKS))
-    seq = list(eng.round_stream(rounds, mode=mode, packed=packed,
-                                code_masks=coded, pipeline=False))
-    pipe = list(eng.round_stream(rounds, mode=mode, packed=packed,
-                                 code_masks=coded, pipeline=True))
+    seq = list(eng.round_stream(rounds, mode=mode, code_masks=coded,
+                                pipeline=False))
+    pipe = list(eng.round_stream(rounds, mode=mode, code_masks=coded,
+                                 pipeline=True))
     _assert_rounds_equal(seq, pipe)
     for _, _, phase in pipe:
         assert {"pack", "decode", "device"} <= set(phase)

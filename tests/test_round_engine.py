@@ -1,17 +1,15 @@
 """Round-engine parity tests: the batched, kernel-dispatched engine
-(repro.core.engine) against the legacy per-task Python-loop server, the
-dense matu_round reference, and across kernel dispatch modes — plus the
+(repro.core.engine) against the dense ``matu_round`` reference of
+``repro.core.aggregation`` (with ``core.unify`` re-unifying each
+client's downlink), and across kernel dispatch modes — plus the
 wire-format guarantees of the bit-packed / bf16 slot layout.
 
-The wire contract under test (see the engine docstring):
-
-* uploads are quantised ONCE at the wire boundary — unified vectors to
-  bf16, masks to uint32 words — and every path (legacy loop, bool A/B
-  engine, packed engine) then consumes the identical values;
-* on those identical inputs the packed engine's masks and λs are
-  **bit-identical** to the bool/fp32 layout's (sign decisions are made
-  on fp32 values before any bf16 rounding), and its bf16 vector
-  outputs are exactly the bf16 rounding of the bool engine's fp32 ones.
+The wire contract under test (see the engine docstring): uploads are
+quantised ONCE at the wire boundary — unified vectors to bf16, masks to
+uint32 words — and the oracle consumes the identical values; mask bits
+are decided on fp32 values before any bf16 rounding, so the engine's
+downlink masks equal the oracle's bit for bit and its bf16 vectors are
+the rounding of the oracle's fp32 ones.
 """
 
 import numpy as np
@@ -24,12 +22,13 @@ from repro.core.aggregation import matu_round, matu_round_packed
 from repro.core.client import ClientUpload
 from repro.core.engine import (EngineConfig, RoundEngine,
                                batched_client_unify, pack_uploads)
-from repro.core.server import MaTUServer, MaTUServerConfig
 from repro.core.unify import unify_with_modulators
 from repro.fed.compression import quantize_bf16_transport
 from repro.kernels import bitpack, ops
 
 jax.config.update("jax_platform_name", "cpu")
+
+MODES = ["ref", "pallas_interpret"]
 
 
 def bf16(x):
@@ -54,29 +53,53 @@ def random_uploads(rng, n, n_tasks, d, k_max, *, skew_sizes=True):
     return ups
 
 
-def assert_round_equal(server_a, server_b, downs_a, downs_b, uploads,
-                       rtol=1e-5, atol=1e-6):
-    """a = fp32 reference (legacy), b = engine (wire outputs)."""
-    np.testing.assert_allclose(server_a.last_task_vectors,
-                               server_b.last_task_vectors, rtol=rtol, atol=atol)
-    np.testing.assert_allclose(server_a.last_similarity,
-                               server_b.last_similarity, rtol=rtol, atol=atol)
+def dense_oracle(uploads, n_tasks, **kw):
+    """The round by the dense reference: stack the uploads into
+    (N, T, d) tensors, run ``core.aggregation.matu_round`` and
+    re-unify each client's task vectors with ``core.unify``.  Returns
+    (RoundOutput, {client_id: (unified, masks, lams)})."""
+    n, d = len(uploads), int(uploads[0].unified.shape[0])
+    unified = np.stack([np.asarray(u.unified, np.float32) for u in uploads])
+    masks = np.zeros((n, n_tasks, d), bool)
+    lams = np.zeros((n, n_tasks), np.float32)
+    member = np.zeros((n, n_tasks), bool)
+    sizes = np.zeros((n, n_tasks), np.float32)
+    for i, up in enumerate(uploads):
+        dense = np.asarray(up.masks_dense())
+        for s, t in enumerate(up.task_ids):
+            masks[i, t], lams[i, t] = dense[s], up.lams[s]
+            member[i, t], sizes[i, t] = True, up.data_sizes[s]
+    out = matu_round(*map(jnp.asarray, (unified, masks, lams, member, sizes)),
+                     **kw)
+    downs = {up.client_id: unify_with_modulators(
+        out.task_vectors[jnp.asarray(up.task_ids)]) for up in uploads}
+    return out, downs
+
+
+def assert_round_equal(oracle, out, downs, uploads, rtol=1e-5, atol=1e-6):
+    """oracle = ``dense_oracle``'s pair (fp32 reference), out/downs =
+    the engine's round (wire outputs)."""
+    ref_out, ref_downs = oracle
+    for name in ("task_vectors", "similarity", "tau_hats", "m_hats"):
+        np.testing.assert_allclose(getattr(out, name), getattr(ref_out, name),
+                                   rtol=rtol, atol=atol, err_msg=name)
     for up in uploads:
-        a, b = downs_a[up.client_id], downs_b[up.client_id]
+        (uni, masks, lams), b = ref_downs[up.client_id], downs[up.client_id]
         d = int(up.unified.shape[0])
         assert b.masks.dtype == jnp.uint32           # wire layout
         assert b.masks.shape == (len(up.task_ids), bitpack.packed_width(d))
         assert b.unified.dtype == jnp.bfloat16
         # mask bits are decided on fp32 values pre-rounding: bit-identical
-        np.testing.assert_array_equal(np.asarray(a.masks),
+        np.testing.assert_array_equal(np.asarray(masks),
                                       np.asarray(b.masks_dense()))
         # the bf16 wire vector is the rounding of the fp32 reference
-        np.testing.assert_allclose(np.asarray(a.unified),
+        np.testing.assert_allclose(np.asarray(uni),
                                    np.asarray(b.unified, np.float32),
                                    rtol=1e-2, atol=1e-5)
-        np.testing.assert_allclose(a.lams, b.lams, rtol=1e-4, atol=atol)
+        np.testing.assert_allclose(lams, b.lams, rtol=1e-4, atol=atol)
 
 
+@pytest.mark.parametrize("mode", MODES)
 @pytest.mark.parametrize("seed,n,n_tasks,d,k_max", [
     (0, 4, 5, 128, 3),       # partial participation likely
     (1, 7, 6, 300, 3),       # d not divisible by 32 (ragged tail words)
@@ -84,70 +107,31 @@ def assert_round_equal(server_a, server_b, downs_a, downs_b, uploads,
     (3, 12, 5, 200, 4),      # more clients than tasks
     (4, 1, 4, 96, 2),        # single-client round
 ])
-def test_engine_matches_legacy_server(seed, n, n_tasks, d, k_max):
-    """(a) wire-format engine ≡ legacy MaTUServer.round on randomized
-    ragged uploads: task vectors, similarity, every client's downlink."""
+def test_engine_matches_legacy_server(seed, n, n_tasks, d, k_max, mode):
+    """(a) wire-format engine ≡ the dense per-task reference
+    (``dense_oracle``) on randomized ragged uploads, in both dispatch
+    modes: task vectors, similarity, τ̂, m̂, every client's downlink."""
     rng = np.random.default_rng(seed)
     ups = random_uploads(rng, n, n_tasks, d, k_max)
-    legacy = MaTUServer(MaTUServerConfig(n_tasks=n_tasks))
-    batched = MaTUServer(MaTUServerConfig(n_tasks=n_tasks))
-    downs_legacy = legacy.round_legacy(ups)
-    downs_engine = batched.round(ups)
-    assert_round_equal(legacy, batched, downs_legacy, downs_engine, ups)
+    downs, out = RoundEngine(EngineConfig(n_tasks=n_tasks)).round(ups,
+                                                                  mode=mode)
+    assert_round_equal(dense_oracle(ups, n_tasks), out, downs, ups)
 
 
+@pytest.mark.parametrize("mode", MODES)
 @pytest.mark.parametrize("cross_task,uniform_cross", [
     (True, False), (False, False), (True, True),
 ])
-def test_engine_matches_legacy_ablations(cross_task, uniform_cross):
-    """Ablation variants (Fig. 6b) agree too."""
+def test_engine_matches_legacy_ablations(cross_task, uniform_cross, mode):
+    """Ablation variants (Fig. 6b) agree with the dense reference too."""
     rng = np.random.default_rng(9)
     ups = random_uploads(rng, 6, 5, 160, 3)
-    cfg = MaTUServerConfig(n_tasks=5, cross_task=cross_task,
-                           uniform_cross=uniform_cross)
-    legacy, batched = MaTUServer(cfg), MaTUServer(cfg)
-    downs_l = legacy.round_legacy(ups)
-    downs_e = batched.round(ups)
-    assert_round_equal(legacy, batched, downs_l, downs_e, ups)
-
-
-def test_packed_engine_bit_identical_to_bool_engine():
-    """THE wire-format parity guarantee (streaming ref round — the CPU
-    default): on identical (bf16-quantised) inputs the packed engine's
-    masks are bit-identical to the bool/fp32 engine's, fp32 outputs
-    match exactly, and each bf16 output is exactly the bf16 rounding of
-    the bool engine's fp32 value.  (On the Pallas paths masks/m̂/sim
-    stay bit-identical but λ matches only to fp32 accumulation
-    tolerance — the packed kernels tile d at 4096 vs 2048; see the
-    engine docstring.)"""
-    for seed, (n, n_tasks, d, k_max) in enumerate(
-            [(5, 4, 300, 3), (8, 6, 1000, 3), (3, 5, 97, 2)]):
-        ups = random_uploads(np.random.default_rng(seed), n, n_tasks, d, k_max)
-        eng = RoundEngine(EngineConfig(n_tasks=n_tasks))
-        downs_p, out_p = eng.round(ups)                      # wire layout
-        downs_b, out_b = eng.round(ups, packed=False)        # bool A/B layout
-        np.testing.assert_array_equal(np.asarray(out_b.task_vectors),
-                                      np.asarray(out_p.task_vectors))
-        np.testing.assert_array_equal(np.asarray(out_b.tau_hats),
-                                      np.asarray(out_p.tau_hats))
-        np.testing.assert_array_equal(np.asarray(out_b.similarity),
-                                      np.asarray(out_p.similarity))
-        # m̂ re-derived from the byte-wide agreement numerator is the
-        # bit-identical value the bool path materialised in fp32
-        np.testing.assert_array_equal(np.asarray(out_b.m_hats),
-                                      np.asarray(out_p.m_hats))
-        np.testing.assert_array_equal(np.asarray(out_b.down_lams),
-                                      np.asarray(out_p.down_lams))
-        np.testing.assert_array_equal(
-            np.asarray(out_b.down_masks),
-            np.asarray(ops.unpack_masks(out_p.down_masks, d)))
-        np.testing.assert_array_equal(
-            np.asarray(bf16(out_b.down_unified)),
-            np.asarray(out_p.down_unified))
-        for cid in downs_p:
-            np.testing.assert_array_equal(
-                np.asarray(downs_b[cid].masks),
-                np.asarray(downs_p[cid].masks_dense()))
+    eng = RoundEngine(EngineConfig(n_tasks=5, cross_task=cross_task,
+                                   uniform_cross=uniform_cross))
+    downs, out = eng.round(ups, mode=mode)
+    oracle = dense_oracle(ups, 5, cross_task=cross_task,
+                          uniform_cross=uniform_cross)
+    assert_round_equal(oracle, out, downs, ups)
 
 
 def test_pack_unpack_roundtrip_ragged():
@@ -191,35 +175,6 @@ if HAVE_HYPOTHESIS:
         np.testing.assert_array_equal(np.asarray(w), bitpack.pack_bits_np(mask))
 
 
-def test_legacy_oracle_accepts_wire_uploads():
-    """round_legacy (the parity oracle) must treat wire-format uploads
-    (uint32 mask words + bf16 vectors) identically to their dense
-    twins, not silently stack raw words as masks."""
-    rng = np.random.default_rng(12)
-    n_tasks, d = 5, 200
-    dense_ups, wire_ups = [], []
-    for cid in range(4):
-        k = int(rng.integers(1, 4))
-        tasks = sorted(rng.choice(n_tasks, size=k, replace=False).tolist())
-        tvs = jnp.asarray(rng.standard_normal((k, d)), jnp.float32)
-        unified, masks, lams = unify_with_modulators(tvs)
-        sizes = [100] * k
-        dense_ups.append(ClientUpload(
-            cid, tasks, quantize_bf16_transport(unified), masks, lams, sizes))
-        wire_ups.append(ClientUpload(
-            cid, tasks, unified.astype(jnp.bfloat16),
-            ops.pack_masks(masks), lams, sizes))
-    a = MaTUServer(MaTUServerConfig(n_tasks=n_tasks))
-    b = MaTUServer(MaTUServerConfig(n_tasks=n_tasks))
-    downs_a = a.round_legacy(dense_ups)
-    downs_b = b.round_legacy(wire_ups)
-    np.testing.assert_allclose(a.last_task_vectors, b.last_task_vectors,
-                               rtol=1e-6, atol=1e-7)
-    for cid in downs_a:
-        np.testing.assert_array_equal(np.asarray(downs_a[cid].masks),
-                                      np.asarray(downs_b[cid].masks))
-
-
 def test_pack_uploads_empty_round_raises():
     """Satellite fix: an empty round used to die with IndexError on
     uploads[0]; it must raise a clear ValueError instead."""
@@ -236,7 +191,7 @@ def test_engine_matches_matu_round_dense():
     rng = np.random.default_rng(5)
     ups = random_uploads(rng, 6, 5, 200, 3)
     packed = pack_uploads(ups, 5)
-    assert packed.packed and packed.slot_masks.dtype == jnp.uint32
+    assert packed.slot_masks.dtype == jnp.uint32
     masks, lams, member, sizes = packed.dense_tensors()
     dense = matu_round(packed.unified.astype(jnp.float32), masks, lams,
                        member, sizes)
@@ -397,9 +352,8 @@ def test_wire_bits_measured_from_buffers():
 
 
 def test_strategy_batched_aggregate_matches_legacy_loop():
-    """MaTUStrategy's pre-packed wire path ≡ the legacy per-client
-    unify + server.round_legacy composition on the same bf16 wire
-    values."""
+    """MaTUStrategy's pre-packed wire path ≡ per-client unify + the
+    dense reference round on the same bf16 wire values."""
     from repro.fed.strategies import MaTUStrategy, RoundBatch, Upload
 
     rng = np.random.default_rng(11)
@@ -414,24 +368,23 @@ def test_strategy_batched_aggregate_matches_legacy_loop():
     strat = MaTUStrategy(n_tasks, d)
     strat.aggregate_batch(RoundBatch.from_uploads(uploads, n_tasks))
 
-    legacy_server = MaTUServer(MaTUServerConfig(n_tasks=n_tasks))
-    legacy_ups = []
+    wire_ups = []
     for u in uploads:
         unified, masks, lams = unify_with_modulators(u.task_vectors)
-        legacy_ups.append(ClientUpload(u.client_id, u.task_ids,
-                                       quantize_bf16_transport(unified),
-                                       masks, lams, u.data_sizes))
-    legacy_downs = legacy_server.round_legacy(legacy_ups)
+        wire_ups.append(ClientUpload(u.client_id, u.task_ids,
+                                     quantize_bf16_transport(unified),
+                                     masks, lams, u.data_sizes))
+    ref_out, ref_downs = dense_oracle(wire_ups, n_tasks)
 
     np.testing.assert_allclose(strat.server.last_task_vectors,
-                               legacy_server.last_task_vectors,
-                               rtol=1e-5, atol=1e-6)
+                               ref_out.task_vectors, rtol=1e-5, atol=1e-6)
     for u in uploads:
-        a, b = legacy_downs[u.client_id], strat.downlinks[u.client_id]
+        (uni, masks, lams) = ref_downs[u.client_id]
+        b = strat.downlinks[u.client_id]
         assert b.packed
-        np.testing.assert_allclose(np.asarray(a.unified),
+        np.testing.assert_allclose(np.asarray(uni),
                                    np.asarray(b.unified, np.float32),
                                    rtol=1e-2, atol=1e-5)
-        np.testing.assert_array_equal(np.asarray(a.masks),
+        np.testing.assert_array_equal(np.asarray(masks),
                                       np.asarray(b.masks_dense()))
-        np.testing.assert_allclose(a.lams, b.lams, rtol=1e-4, atol=1e-6)
+        np.testing.assert_allclose(lams, b.lams, rtol=1e-4, atol=1e-6)

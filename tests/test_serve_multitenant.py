@@ -74,8 +74,8 @@ def _rig():
     return cfg, model, params, lora0, space, server, prompts
 
 
-def _store_from(server, space, lora0, *, packed, capacity=8):
-    dl = server.serving_downlink(packed=packed,
+def _store_from(server, space, lora0, *, code_masks=False, capacity=8):
+    dl = server.serving_downlink(code_masks=code_masks,
                                  fingerprint=space.fingerprint)
     store = ModulatorStore(space, lora0, capacity=capacity)
     store.ingest(dl)
@@ -84,7 +84,7 @@ def _store_from(server, space, lora0, *, packed, capacity=8):
 
 def _oracle_adapter(dl, space, lora0, t):
     """The dense unpacked modulator path, independent of the store."""
-    delta = modulate(dl.unified, dl.masks[t], dl.lams[t])
+    delta = modulate(dl.unified, dl.mask_row(t), dl.lams[t])
     return tree_add(lora0, space.unflatten(delta))
 
 
@@ -171,10 +171,10 @@ def test_modulated_matmul_rejects_misaligned():
 
 def test_store_ingest_all_layouts_agree():
     _, _, _, lora0, space, server, _ = _rig()
-    packed_dl = server.serving_downlink(packed=True,
-                                        fingerprint=space.fingerprint)
-    bool_dl = server.serving_downlink(packed=False,
-                                      fingerprint=space.fingerprint)
+    packed_dl = server.serving_downlink(fingerprint=space.fingerprint)
+    # a downlink built with dense bool rows is packed on ingest
+    bool_dl = ClientDownlink(packed_dl.unified, packed_dl.masks_dense(),
+                             packed_dl.lams, fingerprint=space.fingerprint)
     coded_dl = server.serving_downlink(code_masks=True,
                                        fingerprint=space.fingerprint)
     stores = []
@@ -187,9 +187,10 @@ def test_store_ingest_all_layouts_agree():
         for s in stores[1:]:
             np.testing.assert_array_equal(np.asarray(s.mask_words(t)),
                                           ref_words)
-        # packed + coded share the bf16 wire vector -> identical deltas
-        np.testing.assert_array_equal(np.asarray(stores[0].delta(t)),
-                                      np.asarray(stores[2].delta(t)))
+        # all three share the bf16 wire vector -> identical deltas
+        for s in stores[1:]:
+            np.testing.assert_array_equal(np.asarray(stores[0].delta(t)),
+                                          np.asarray(s.delta(t)))
     # masks stay packed in residence whatever the ingest layout
     for s in stores:
         assert all(s.mask_words(t).dtype == jnp.uint32
@@ -210,7 +211,7 @@ def test_store_fingerprint_handshake():
 
 def test_store_lru_eviction_and_rebuild():
     _, _, _, lora0, space, server, _ = _rig()
-    store, _ = _store_from(server, space, lora0, packed=True, capacity=2)
+    store, _ = _store_from(server, space, lora0, capacity=2)
     a0 = store.adapter(0)
     store.adapter(1)
     assert store.cached_task_ids() == [0, 1]
@@ -242,14 +243,15 @@ def test_store_capacity_validation():
 # routing parity: the acceptance contract
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("packed", [True, False],
-                         ids=["packed-wire", "bool-wire"])
-def test_mixed_batch_bitwise_equals_single_tenant(packed):
+@pytest.mark.parametrize("code_masks", [False, True],
+                         ids=["packed-wire", "coded-wire"])
+def test_mixed_batch_bitwise_equals_single_tenant(code_masks):
     """A mixed decode batch over >=4 tasks through the ModulatorStore
     is bit-identical to decoding each request single-tenant with the
-    dense unpacked modulator — for both downlink mask layouts."""
+    dense unpacked modulator — for the packed and the entropy-coded
+    serving downlink."""
     _, model, params, lora0, space, server, prompts = _rig()
-    store, dl = _store_from(server, space, lora0, packed=packed)
+    store, dl = _store_from(server, space, lora0, code_masks=code_masks)
     dec = MultiTenantDecoder(model, params, store, cfg=GEN_CFG)
     ids = list(range(N_TASKS))
     mixed = dec.generate(prompts, ids)
@@ -267,7 +269,7 @@ def test_uniform_mix_equals_classic_batch():
     """All-rows-one-task routed decode == the classic (2-D lora)
     uniform batch, bitwise."""
     _, model, params, lora0, space, server, prompts = _rig()
-    store, dl = _store_from(server, space, lora0, packed=True)
+    store, dl = _store_from(server, space, lora0)
     dec = MultiTenantDecoder(model, params, store, cfg=GEN_CFG)
     routed = dec.generate(prompts, [2] * N_TASKS)
     classic = generate(model, params, _oracle_adapter(dl, space, lora0, 2),
@@ -281,7 +283,7 @@ def test_fused_routing_matches_dense_routed():
     """Fused (packed-mask, in-kernel modulation) decode: identical
     tokens to dense-routed; word-aligned sites carry packed words."""
     _, model, params, lora0, space, server, prompts = _rig()
-    store, _ = _store_from(server, space, lora0, packed=True)
+    store, _ = _store_from(server, space, lora0)
     ids = [0, 3, 1, 2]
     dense = MultiTenantDecoder(model, params, store, cfg=GEN_CFG)
     fused = MultiTenantDecoder(model, params, store, fused=True, cfg=GEN_CFG)
@@ -317,7 +319,7 @@ def test_fused_weight_build_within_one_product_rounding():
     logits of the two routed forms stay within the amplified tolerance
     through the full depth."""
     _, model, params, lora0, space, server, prompts = _rig()
-    store, _ = _store_from(server, space, lora0, packed=True)
+    store, _ = _store_from(server, space, lora0)
     ids = [0, 1, 2, 3]
     dense_lora = route_batch(store, ids, fused=False)
     fused_lora = route_batch(store, ids, fused=True)
@@ -367,7 +369,7 @@ def test_fused_weight_build_within_one_product_rounding():
 def test_one_compiled_program_across_mixes():
     """Task ids are data: one jitted decode program serves every mix."""
     _, model, params, lora0, space, server, prompts = _rig()
-    store, _ = _store_from(server, space, lora0, packed=True)
+    store, _ = _store_from(server, space, lora0)
     for fused in (False, True):
         dec = MultiTenantDecoder(model, params, store, fused=fused,
                                  cfg=GEN_CFG)
@@ -379,7 +381,7 @@ def test_one_compiled_program_across_mixes():
 
 def test_decoder_validates_batch():
     _, model, params, lora0, space, server, prompts = _rig()
-    store, _ = _store_from(server, space, lora0, packed=True)
+    store, _ = _store_from(server, space, lora0)
     dec = MultiTenantDecoder(model, params, store, cfg=GEN_CFG)
     with pytest.raises(ValueError, match="task ids"):
         dec.generate(prompts, [0, 1])

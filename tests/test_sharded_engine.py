@@ -8,8 +8,8 @@ shards 8 ways over ("data", "model").
 Contract under test:
 
 * **bit parity** — in "ref" mode the sharded round is bit-identical to
-  the single-device round for BOTH slot layouts (packed wire + bool
-  A/B), on ragged rounds and on d not divisible by devices·32: the λ
+  the single-device round, on ragged rounds and on d not divisible by
+  devices·32: the λ
   reductions run on the fixed 256-coord block grid with the
   shard-invariant tree, the Eq. 5 dots psum is integer-exact, and all
   other math is per-coordinate.
@@ -118,21 +118,17 @@ _PARITY = textwrap.dedent("""
         ups = uploads(np.random.default_rng(seed), n, T, d, km)
         single = RoundEngine(EngineConfig(n_tasks=T))
         shard = RoundEngine(EngineConfig(n_tasks=T), mesh=mesh)
-        for packed in (True, False):
-            _, out_s = single.round(ups, packed=packed)
-            downs_h, out_h = shard.round(ups, packed=packed)
-            for f in FIELDS:
-                a = np.asarray(getattr(out_s, f))
-                b = np.asarray(getattr(out_h, f))
-                key = f"{d}/{'packed' if packed else 'bool'}/{f}"
-                report[key] = bool(a.shape == b.shape
-                                   and np.array_equal(a, b))
-            # downlink slicing keeps the wire dtypes per client
-            dl = downs_h[ups[0].client_id]
-            if packed:
-                report[f"{d}/packed/dl_dtype"] = (
-                    str(dl.masks.dtype) == "uint32"
-                    and str(dl.unified.dtype) == "bfloat16")
+        _, out_s = single.round(ups)
+        downs_h, out_h = shard.round(ups)
+        for f in FIELDS:
+            a = np.asarray(getattr(out_s, f))
+            b = np.asarray(getattr(out_h, f))
+            report[f"{d}/{f}"] = bool(a.shape == b.shape
+                                      and np.array_equal(a, b))
+        # downlink slicing keeps the wire dtypes per client
+        dl = downs_h[ups[0].client_id]
+        report[f"{d}/dl_dtype"] = (str(dl.masks.dtype) == "uint32"
+                                   and str(dl.unified.dtype) == "bfloat16")
     print(json.dumps(report))
 """)
 
@@ -195,8 +191,8 @@ def test_sharded_pallas_round_matches_single_device():
 
 
 def test_sharded_round_bit_identical_ref():
-    """8-way sharded round ≡ single-device round, bit for bit, packed
-    and bool layouts, ragged rounds, d % (devices·32) != 0."""
+    """8-way sharded round ≡ single-device round, bit for bit, ragged
+    rounds, d % (devices·32) != 0."""
     report = _run_sub(_PARITY)
     assert report.pop("devices") == 8
     bad = [k for k, v in report.items() if v is not True]

@@ -9,10 +9,9 @@ Interpret-mode tests cannot see any of that.
 Shapes are the real ones: the server round at N = 32 clients, T = 30
 tasks, K = 2 tasks per client, at d = 2^20 and at the task-vector d of
 qwen2-0.5b at its published widths (rank-16 LoRA on mixer/wq,
-mixer/wo, ffn/down: d = 3,588,168), and for the slot-reading Eq. 3–4
-and downlink kernels also at one chip's shard of qwen2.5-32b's round
-over four chips (16,777,216 coordinates); the serving kernel on
-qwen2-0.5b's LoRA leaves, fused and dense-routed.
+mixer/wo, ffn/down: d = 3,588,168), and at one chip's shard of
+qwen2.5-32b's round over four chips (16,777,216 coordinates); the
+serving kernel on qwen2-0.5b's LoRA leaves, fused and dense-routed.
 
 The topology is described inside a module fixture, never at import:
 only one process at a time may load the TPU library, and under a
@@ -31,10 +30,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import bitpack
 from repro.kernels.fused_unify import (fused_unify_packed_pallas,
-                                       fused_unify_packed_rows_pallas,
-                                       fused_unify_pallas)
-from repro.kernels.masked_agg import (masked_agg_batched_packed_rows_pallas,
-                                      masked_agg_batched_pallas)
+                                       fused_unify_packed_rows_pallas)
+from repro.kernels.masked_agg import masked_agg_batched_packed_rows_pallas
 from repro.kernels.modulated_matmul import (modulated_matmul_pallas,
                                             routed_matmul_pallas)
 from repro.kernels.sign_sim import sign_sim_packed_pallas
@@ -93,16 +90,16 @@ def compile_for_chip(fn, one_chip, *shapes):
     return compiled
 
 
-@pytest.mark.parametrize("d", D_CASES.values(), ids=D_CASES.keys())
+# one chip's taskvec shard of qwen2.5-32b's round over four chips
+# (d = 54,526,144 padded to 4 x 16,777,216)
+ROWS_D_CASES = dict(D_CASES, **{"d=qwen2.5-32b/4": 1 << 24})
+
+
+@pytest.mark.parametrize("d", ROWS_D_CASES.values(), ids=ROWS_D_CASES.keys())
 def test_fused_unify_packed_compiles(one_chip, d):
     compile_for_chip(
         lambda tv, v: fused_unify_packed_pallas(tv, v, interpret=False),
         one_chip, ((N, K, d), jnp.float32), ((N, K), jnp.bool_))
-
-
-# one chip's taskvec shard of qwen2.5-32b's round over four chips
-# (d = 54,526,144 padded to 4 x 16,777,216)
-ROWS_D_CASES = dict(D_CASES, **{"d=qwen2.5-32b/4": 1 << 24})
 
 
 @pytest.mark.parametrize("d", ROWS_D_CASES.values(), ids=ROWS_D_CASES.keys())
@@ -112,13 +109,6 @@ def test_fused_unify_packed_rows_compiles(one_chip, d):
                                                           interpret=False),
         one_chip, ((T, d), jnp.float32), ((N, K), jnp.int32),
         ((N, K), jnp.bool_))
-
-
-@pytest.mark.parametrize("d", D_CASES.values(), ids=D_CASES.keys())
-def test_fused_unify_compiles(one_chip, d):
-    compile_for_chip(
-        lambda tv, v: fused_unify_pallas(tv, v, interpret=False),
-        one_chip, ((N, K, d), jnp.float32), ((N, K), jnp.bool_))
 
 
 @pytest.mark.parametrize("d", ROWS_D_CASES.values(), ids=ROWS_D_CASES.keys())
@@ -131,20 +121,7 @@ def test_masked_agg_batched_packed_rows_compiles(one_chip, d):
         ((N, K), jnp.float32), ((N, K), jnp.int32), ((N, K), jnp.bool_))
 
 
-@pytest.mark.parametrize("d", D_CASES.values(), ids=D_CASES.keys())
-def test_masked_agg_batched_compiles(one_chip, d):
-    # the dense bool layout holds (N, T, d) masks as fp32 in the kernel's
-    # operand; at the qwen2-0.5b d that is 13.8 GB, so that case runs
-    # with T = 8 tasks to fit one chip
-    t = T if d <= 1 << 20 else 8
-    compile_for_chip(
-        lambda u, m, lam, gam, mem: masked_agg_batched_pallas(
-            u, m, lam, gam, mem, interpret=False),
-        one_chip, ((N, d), jnp.float32), ((N, t, d), jnp.bool_),
-        ((N, t), jnp.float32), ((N, t), jnp.float32), ((N, t), jnp.bool_))
-
-
-@pytest.mark.parametrize("d", D_CASES.values(), ids=D_CASES.keys())
+@pytest.mark.parametrize("d", ROWS_D_CASES.values(), ids=ROWS_D_CASES.keys())
 def test_sign_sim_packed_compiles(one_chip, d):
     w = bitpack.packed_width(d)
     compile_for_chip(

@@ -8,10 +8,10 @@ The task-vector layout contract end to end (see ``repro.fed.testbed``):
 * a manifest-fingerprint mismatch between client and server aborts
   BEFORE aggregation (both at the strategy and at simulator
   construction);
-* a mixed-architecture round over REAL per-task fine-tune deltas is
-  bit-identical between the packed uint32 wire and the bool/fp32
-  reference layout — zero-padding each family to the common d (the
-  256-coord word boundary) never perturbs the engine;
+* a mixed-architecture round over REAL per-task fine-tune deltas,
+  each family zero-padded to the common d (the 256-coord word
+  boundary), matches the dense reference round — downlink masks bit
+  for bit;
 * a 30-task round over >= 4 distinct families completes end-to-end
   through ``MaTUStrategy`` with measured wire bits.
 """
@@ -140,21 +140,21 @@ def real_finetune_uploads(zoo, families, n_tasks, d):
             tvs.append(pad_vector(tv, d))
         unified, masks, lams = unify_with_modulators(jnp.stack(tvs))
         # bf16-quantise ONCE at the wire boundary (as the uplink does)
-        # so the packed and bool layouts consume identical values
+        # so the engine and the dense reference consume identical values
         ups.append(ClientUpload(cid, tasks, quantize_bf16_transport(unified),
                                 masks, lams, [64, 64]))
     return ups
 
 
 def test_cross_arch_round_packed_bool_bit_parity(zoo, monkeypatch):
-    """Packed uint32 wire == bool/fp32 layout, bit for bit, on a round
-    of real fine-tune deltas from different architectures padded to one
-    common d (the acceptance-criteria parity check).  Pinned to the
-    streaming ref round: full bitwise parity (incl. λ) is the REF
-    contract — on the Pallas paths the packed kernels tile d at 4096
-    vs the bool kernels' 2048, and this round's d spans multiple
-    tiles, so λ there matches only to fp32 accumulation tolerance
-    (see the engine docstring)."""
+    """The engine's round over the packed uint32 wire ≡ the dense
+    reference round (``core.aggregation.matu_round`` + ``core.unify``)
+    on a round of real fine-tune deltas from different architectures
+    padded to one common d: task vectors, similarity, τ̂, m̂ and every
+    client's downlink (masks bit for bit).  Pinned to the streaming ref
+    round, the CPU path."""
+    from test_round_engine import assert_round_equal, dense_oracle
+
     monkeypatch.setenv("REPRO_DISABLE_PALLAS", "1")
     monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
     families = ["lm", "vit", "ssm", "moe"]
@@ -162,21 +162,8 @@ def test_cross_arch_round_packed_bool_bit_parity(zoo, monkeypatch):
     assert d % D_BOUNDARY == 0
     n_tasks = 4
     ups = real_finetune_uploads(zoo, families, n_tasks, d)
-    eng = RoundEngine(EngineConfig(n_tasks=n_tasks))
-    downs_p, out_p = eng.round(ups)                 # packed wire
-    downs_b, out_b = eng.round(ups, packed=False)   # bool A/B reference
-    np.testing.assert_array_equal(np.asarray(out_b.task_vectors),
-                                  np.asarray(out_p.task_vectors))
-    np.testing.assert_array_equal(np.asarray(out_b.m_hats),
-                                  np.asarray(out_p.m_hats))
-    np.testing.assert_array_equal(np.asarray(out_b.similarity),
-                                  np.asarray(out_p.similarity))
-    np.testing.assert_array_equal(np.asarray(out_b.down_lams),
-                                  np.asarray(out_p.down_lams))
-    for cid in downs_p:
-        np.testing.assert_array_equal(
-            np.asarray(downs_p[cid].masks_dense()),
-            np.asarray(downs_b[cid].masks_dense()))
+    downs, out = RoundEngine(EngineConfig(n_tasks=n_tasks)).round(ups)
+    assert_round_equal(dense_oracle(ups, n_tasks), out, downs, ups)
     # wire accounting is measured off the packed buffers
     bits = sum(u.uplink_bits() for u in ups)
     assert bits > 0
